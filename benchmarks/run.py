@@ -89,6 +89,9 @@ def main():
     only = set(args.only.split(",")) if args.only else None
 
     from repro import kernels, obs
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     failures = []
     total: dict = {}

@@ -1,8 +1,2 @@
 """repro: TPU reproduction of "UCCL-Zip: Lossless Compression Supercharged
-GPU Communication" on the jax/Pallas stack.
-
-Importing any ``repro.*`` module applies :mod:`repro.jax_compat`, which
-backfills newer jax public APIs (``jax.shard_map``, ``jax.lax.axis_size``,
-``jax.sharding.AxisType``) on the 0.4.x runtime the container ships.
-"""
-from repro import jax_compat as _jax_compat  # noqa: F401  (side-effect import)
+GPU Communication" on the jax/Pallas stack."""

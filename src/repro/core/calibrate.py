@@ -33,6 +33,11 @@ def block_range_stats(x: jax.Array, block: int = 512) -> jax.Array:
     ``max_nz - min_nz + 1`` over nonzero exponents (0 for all-zero blocks).
     A block packs losslessly at width W iff its stat < 2**W."""
     exp, _ = codec.split_planes(x)
+    return _exp_range_stats(exp, block)
+
+
+def _exp_range_stats(exp: jax.Array, block: int) -> jax.Array:
+    """:func:`block_range_stats` of a uint8 exponent plane."""
     exp = packing._pad_to(exp, block)
     b = exp.reshape(-1, block)
     nz = b != 0
@@ -52,9 +57,15 @@ def width_cost_curve(
     width).  :func:`choose_width` picks from this curve; the regret
     analytics (``obs/regret.py``) score achieved-vs-optimal widths with it.
     """
-    lay = codec.layout_of(x.dtype)
-    rngs = np.asarray(block_range_stats(x, block=block))
     exp, _ = codec.split_planes(x)
+    return _exp_cost_curve(exp, codec.layout_of(x.dtype), block,
+                           max_exc_frac)
+
+
+def _exp_cost_curve(exp: jax.Array, lay: codec.FloatLayout, block: int,
+                    max_exc_frac: float) -> tuple:
+    """:func:`width_cost_curve` of a uint8 exponent plane."""
+    rngs = np.asarray(_exp_range_stats(exp, block))
     ent = float(codec.exponent_entropy_bits(exp, lay.exp_bits))
     n_blocks = len(rngs)
     cap = packing.exception_capacity(n_blocks, max_exc_frac)
@@ -91,6 +102,11 @@ def choose_width(
     on it for correctness, only for speed).
     """
     curve = width_cost_curve(x, block=block, max_exc_frac=max_exc_frac)
+    return _pick_width(curve, target_exc_rate, margin_bits)
+
+
+def _pick_width(curve: tuple, target_exc_rate: float,
+                margin_bits: int = 0) -> WidthChoice:
     for c in curve:
         if c.est_exc_rate <= target_exc_rate or c.width == curve[-1].width:
             return curve[min(c.width + margin_bits, curve[-1].width) - 1]
@@ -112,10 +128,11 @@ def choose_delta_widths(
     ``CompressionProfile.widths["delta"/"delta_lo"]`` to drive
     ``CompressionPolicy.delta_widths``."""
     lay = codec.layout_of(x.dtype)
-    d = codec.xor_delta(x.reshape(-1), base.reshape(-1))
-    w_exp = choose_width(d, block=block, target_exc_rate=target_exc_rate,
-                         max_exc_frac=max_exc_frac).width
-    _, lo = codec.split_planes(d)
+    # the delta stays a bit pattern: as a float it is mostly subnormal
+    exp, lo = codec.split_bits(
+        codec.xor_bits(x.reshape(-1), base.reshape(-1)), lay)
+    w_exp = _pick_width(_exp_cost_curve(exp, lay, block, max_exc_frac),
+                        target_exc_rate).width
     lo = np.asarray(lo.astype(jnp.uint32))
     budget = max_exc_frac / 2  # leave half the capacity as drift headroom
     w_lo = lay.lo_bits

@@ -67,8 +67,12 @@ def split_planes(x: jax.Array) -> tuple[jax.Array, jax.Array]:
                wire layer can bit-pack it densely (one memory pass — Step 1).
     """
     lay = layout_of(x.dtype)
-    flat = x.reshape(-1)
-    bits = jax.lax.bitcast_convert_type(flat, lay.uint_dtype)
+    return split_bits(
+        jax.lax.bitcast_convert_type(x.reshape(-1), lay.uint_dtype), lay)
+
+
+def split_bits(bits: jax.Array, lay: FloatLayout):
+    """:func:`split_planes` of a flat ``lay.uint_dtype`` bit pattern."""
     u = lay.uint_dtype
     mant_mask = u((1 << lay.mant_bits) - 1)
     exp = (
@@ -85,13 +89,19 @@ def merge_planes(
     """Exact inverse of :func:`split_planes`."""
     lay = layout_of(dtype)
     n = int(np.prod(shape)) if shape else 1
+    bits = merge_bits(exp, lo, lay, n)
+    return jax.lax.bitcast_convert_type(bits, lay.dtype).reshape(shape)
+
+
+def merge_bits(exp: jax.Array, lo: jax.Array, lay: FloatLayout,
+               n: int) -> jax.Array:
+    """Exact inverse of :func:`split_bits`: the flat (n,) bit pattern."""
     u = lay.uint_dtype
     lo = lo.reshape(-1)[:n].astype(u)
     exp = exp.reshape(-1)[:n].astype(u)
     sign = lo >> u(lay.mant_bits)
     mant = lo & u((1 << lay.mant_bits) - 1)
-    bits = (sign << u(lay.total_bits - 1)) | (exp << u(lay.mant_bits)) | mant
-    return jax.lax.bitcast_convert_type(bits, lay.dtype).reshape(shape)
+    return (sign << u(lay.total_bits - 1)) | (exp << u(lay.mant_bits)) | mant
 
 
 # ---------------------------------------------------------------------------
@@ -101,32 +111,32 @@ def merge_planes(
 # bitwise XOR of a version against the receiver's base version concentrates
 # its nonzero bits in the low mantissa positions (and is EXACTLY zero for
 # weights the step didn't move — ubiquitous for bf16, where sub-ULP updates
-# round away).  The delta is itself a valid bit pattern of the same float
-# format, so the existing split+pack wire applies to it unchanged; the
+# round away).  The delta is a bit pattern of the same width, so the
+# existing split (``split_bits``) and pack apply to it unchanged; the
 # transform is a pure involution on the raw bits — NaN payloads, infinities
 # and subnormals round-trip exactly.
 # ---------------------------------------------------------------------------
 
 
-def xor_delta(x: jax.Array, base: jax.Array) -> jax.Array:
-    """Bitwise XOR of two same-shape, same-dtype float tensors.
+def xor_bits(x: jax.Array, base: jax.Array) -> jax.Array:
+    """Bitwise XOR of two same-shape, same-dtype float tensors, as their
+    uint bit pattern.
 
-    Returns the delta reinterpreted as the input float dtype (so the
-    split+pack codec applies to it directly).  Self-inverse:
-    ``xor_delta(xor_delta(x, base), base)`` is bit-identical to ``x`` —
-    the receiver reconstructs by XORing the decoded delta against its own
-    copy of ``base``.  Pure bit movement (bitcast + xor): no float
-    arithmetic touches the values, so every NaN payload / Inf / subnormal
-    bit survives."""
+    Self-inverse: ``xor_bits(x, base) ^ bits(base)`` is bit-identical to
+    ``bits(x)`` — the receiver reconstructs by XORing the decoded delta
+    against its own copy of ``base``.  Pure bit movement (bitcast + xor):
+    no float arithmetic touches the values, so every NaN payload / Inf /
+    subnormal bit survives.  The delta stays in the uint domain: as a
+    float it is mostly subnormal, and a TPU flushes subnormals to zero
+    wherever a program unpacks them."""
     lay = layout_of(x.dtype)
     if jnp.dtype(base.dtype) != jnp.dtype(x.dtype) or base.shape != x.shape:
         raise ValueError(
-            f"xor_delta needs matching operands, got {x.shape}/{x.dtype} "
+            f"xor_bits needs matching operands, got {x.shape}/{x.dtype} "
             f"vs {base.shape}/{base.dtype}")
     u = lay.uint_dtype
-    bits = (jax.lax.bitcast_convert_type(x, u)
+    return (jax.lax.bitcast_convert_type(x, u)
             ^ jax.lax.bitcast_convert_type(base, u))
-    return jax.lax.bitcast_convert_type(bits, lay.dtype)
 
 
 def concat_bits(parts: list) -> jax.Array:

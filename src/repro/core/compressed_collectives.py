@@ -233,7 +233,9 @@ def _decode_chunks(wire: dict, *, dtype, n: int, width: int, block: int):
         lo = packing.bitplane_unpack(w["lo"], lay.lo_bits)[:n].astype(lay.uint_dtype)
         return codec.merge_planes(exp, lo, lay.dtype, (n,))
 
-    vals = jax.vmap(dec)(wire)
+    # one chunk at a time: a vmapped decode keeps every chunk's per-plane
+    # temporaries live at once, which overflows a chip's HBM at real sizes
+    vals = jax.lax.map(dec, wire)
     flag = jnp.max(wire["overflow"])
     return vals, flag
 

@@ -344,7 +344,7 @@ class DeltaMessage:
     """Encoded XOR delta of one tensor against a shared base version.
 
     The existing split applies to the delta's raw bit pattern
-    (``codec.xor_delta`` keeps it in the float dtype): the exponent-delta
+    (``codec.xor_bits``, never a float array): the exponent-delta
     plane rides the standard block packer, the lo-delta plane the width
     packer above.  Static shapes throughout — the wire size depends only on
     (n, widths), so plans can record it via ``eval_shape``."""
@@ -386,8 +386,8 @@ def encode_delta(
     Bit-exact through :func:`decode_delta` whenever ``overflow == 0`` —
     including NaN payloads, infinities and subnormals in either operand."""
     lay = codec.layout_of(x.dtype)
-    d = codec.xor_delta(x, base)
-    exp, lo = codec.split_planes(d)
+    exp, lo = codec.split_bits(codec.xor_bits(x.reshape(-1),
+                                              base.reshape(-1)), lay)
     packed = pack_exponents(exp, width=width, block=block, exc_frac=exc_frac)
     lo_plane = pack_delta_plane(lo.astype(jnp.uint32), lo_width,
                                 exc_frac=exc_frac)
@@ -402,5 +402,6 @@ def decode_delta(m: DeltaMessage, base: jax.Array) -> jax.Array:
     n = int(np.prod(m.shape)) if m.shape else 1
     lo = unpack_delta_plane(m.lo)[:n].astype(lay.uint_dtype)
     exp = unpack_exponents(m.exp)
-    delta = codec.merge_planes(exp, lo, lay.dtype, m.shape)
-    return codec.xor_delta(delta, base.reshape(m.shape))
+    bits = codec.merge_bits(exp, lo, lay, n) ^ jax.lax.bitcast_convert_type(
+        base.reshape(-1), lay.uint_dtype)
+    return jax.lax.bitcast_convert_type(bits, lay.dtype).reshape(m.shape)

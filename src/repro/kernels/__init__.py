@@ -1,19 +1,21 @@
 """Pallas kernel package + accelerator backend probe.
 
-The kernels are OPTIONAL hot-spot implementations of the compute the paper
+The kernels are the hot-spot implementations of the compute the paper
 itself optimizes with custom kernels (bitplane pack/unpack, plane split,
-fused decode+reduce, rANS).  ``ops.py`` is the public dispatch layer; every
-entry point takes ``use_pallas``/``interpret`` knobs.
+fused encode, fused decode+reduce, rANS).  ``ops.py`` is the public
+dispatch layer; every entry point takes ``use_pallas``/``interpret`` knobs,
+and every kernel module resolves ``interpret=None`` through
+:func:`resolve_interpret`.
 
-Backend probe (ROADMAP "Compiled Pallas on real TPU"): interpret-mode
-Pallas is CPU-slow, so the collectives historically defaulted to the
-pure-jnp reference everywhere.  :func:`default_use_pallas` turns the
-compiled Pallas path on automatically when a REAL TPU backend is present
-(and only there); callers pass ``use_pallas=None`` to opt into the probe.
-``REPRO_USE_PALLAS=0|1`` overrides the probe either way (escape hatch for
-benchmarking interpret mode or disabling kernels on a misbehaving
-toolchain).  The sched plan compiler records the probed backend in every
-``CommPlan`` so a compiled plan documents which dispatch it drives.
+Backend probe: interpret-mode Pallas is CPU-slow, so off the TPU the
+collectives use the pure-jnp reference.  :func:`default_use_pallas` turns
+the compiled Pallas path on when the TPU backend is present (and only
+there); callers pass ``use_pallas=None`` to opt into the probe.  A backend
+that fails to start raises: it is never reported as the CPU.
+``REPRO_USE_PALLAS=1`` forces the kernels on off-TPU (interpret mode, for
+tests); on the TPU a false value is an error, so a chip run never silently
+runs the reference.  The sched plan compiler records the probed backend in
+every ``CommPlan`` so a compiled plan documents which dispatch it drives.
 """
 from __future__ import annotations
 
@@ -69,10 +71,7 @@ def clear_fallbacks() -> None:
 @functools.lru_cache(maxsize=None)
 def backend() -> str:
     """The active jax backend platform name ("cpu" | "gpu" | "tpu")."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - backend init failure
-        return "cpu"
+    return jax.default_backend()
 
 
 def has_tpu() -> bool:
@@ -83,13 +82,17 @@ def has_tpu() -> bool:
 def default_use_pallas() -> bool:
     """True iff Pallas kernels should be used by default on this backend.
 
-    Real TPU: compiled Pallas is the hot-spot implementation — on.
+    TPU: compiled Pallas is the hot-spot implementation — always on.
     CPU/GPU: only interpret mode exists here — off (pure-jnp reference,
-    which XLA fuses well).  ``REPRO_USE_PALLAS`` overrides the probe."""
+    which XLA fuses well) unless ``REPRO_USE_PALLAS`` is truthy."""
     env = os.environ.get("REPRO_USE_PALLAS")
-    if env is not None:
-        return env in _TRUTHY
-    return has_tpu()
+    if has_tpu():
+        if env is not None and env not in _TRUTHY:
+            raise RuntimeError(
+                f"REPRO_USE_PALLAS={env!r} on the TPU backend: the chip path "
+                "always runs the compiled kernels; unset it")
+        return True
+    return env is not None and env in _TRUTHY
 
 
 def default_interpret() -> bool:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.packing import GROUP
+from repro.kernels import resolve_interpret
 
 TILE_G = 256
 
@@ -47,7 +48,7 @@ def _unpack_kernel(width: int, p_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
-def pack(vals: jax.Array, width: int, interpret: bool = True) -> jax.Array:
+def pack(vals: jax.Array, width: int, interpret: bool | None = None) -> jax.Array:
     """vals uint32 (n,), n % (32*TILE_G) == 0 -> uint32 (n//32, width)."""
     g = vals.reshape(-1, GROUP)
     n_g = g.shape[0]
@@ -58,12 +59,12 @@ def pack(vals: jax.Array, width: int, interpret: bool = True) -> jax.Array:
         grid=(n_g // TILE_G,),
         in_specs=[pl.BlockSpec((TILE_G, GROUP), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((TILE_G, width), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
-def unpack(packed: jax.Array, width: int, interpret: bool = True) -> jax.Array:
+def unpack(packed: jax.Array, width: int, interpret: bool | None = None) -> jax.Array:
     """packed uint32 (n_g, width) -> uint32 (n_g*32,)."""
     n_g = packed.shape[0]
     assert n_g % TILE_G == 0, (n_g, TILE_G)
@@ -73,6 +74,6 @@ def unpack(packed: jax.Array, width: int, interpret: bool = True) -> jax.Array:
         grid=(n_g // TILE_G,),
         in_specs=[pl.BlockSpec((TILE_G, width), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((TILE_G, GROUP), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(packed)
     return out.reshape(-1)

@@ -15,7 +15,8 @@ blocks (whose payload is clamped garbage by construction) are patched up by
 the caller AFTER the fused pass from the raw ``exc_idx``/``exc_raw`` wire —
 see ``compressed_collectives._decode_reduce_chunks``.
 
-One grid step handles TILE_G groups of 32 elements.  The per-block base is
+One grid step handles TILE_G groups of 32 elements; the last step may be
+partial, so any number of groups takes the kernel.  The per-block base is
 pre-broadcast to a per-GROUP base outside (bases are n/512 elements —
 negligible traffic) so the kernel's index maps stay rectangular.
 """
@@ -29,6 +30,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import codec
 from repro.core.packing import GROUP
+from repro.kernels import resolve_interpret
 
 TILE_G = 256
 
@@ -58,16 +60,21 @@ def _decode_reduce_kernel(
         word = lo_ref[:, b][:, None]
         lo = lo | (((word >> pos) & jnp.uint32(1)) << jnp.uint32(b))
 
-    u = lay.uint_dtype
-    sign = (lo >> jnp.uint32(lay.mant_bits)).astype(u)
-    mant = (lo & jnp.uint32((1 << lay.mant_bits) - 1)).astype(u)
-    bits = (
-        (sign << u(lay.total_bits - 1))
-        | (exp.astype(u) << u(lay.mant_bits))
-        | mant
-    )
-    vals = jax.lax.bitcast_convert_type(bits, lay.dtype)
-    o_ref[...] = acc_ref[...] + vals.astype(jnp.float32)
+    # rebuild the element's bit pattern in uint32 lanes (Mosaic has no
+    # 16-bit shifts): a bf16 value is the high half of the f32 with the same
+    # value, other narrow formats are truncated to their width and converted
+    sign = lo >> jnp.uint32(lay.mant_bits)
+    mant = lo & jnp.uint32((1 << lay.mant_bits) - 1)
+    bits = ((sign << jnp.uint32(lay.total_bits - 1))
+            | (exp << jnp.uint32(lay.mant_bits)) | mant)
+    if lay.total_bits == 32:
+        vals = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    elif lay.name == "bfloat16":
+        vals = jax.lax.bitcast_convert_type(bits << jnp.uint32(16), jnp.float32)
+    else:
+        vals = jax.lax.bitcast_convert_type(
+            bits.astype(lay.uint_dtype), lay.dtype).astype(jnp.float32)
+    o_ref[...] = acc_ref[...] + vals
 
 
 @functools.partial(jax.jit, static_argnames=("dtype_name", "width", "interpret"))
@@ -78,16 +85,17 @@ def decode_reduce(
     acc: jax.Array,  # float32 (n_g*32,)
     dtype_name: str,
     width: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Returns acc + decode(wire) in one fused pass (f32 (n,))."""
+    """Returns acc + decode(wire) in one fused pass (f32 (n,)).
+
+    ``interpret=None`` resolves through ``kernels.resolve_interpret``."""
     lay = codec.LAYOUTS[dtype_name]
     n_g = payload.shape[0]
-    assert n_g % TILE_G == 0, n_g
     out = pl.pallas_call(
         functools.partial(_decode_reduce_kernel, lay, width),
         out_shape=jax.ShapeDtypeStruct((n_g, GROUP), jnp.float32),
-        grid=(n_g // TILE_G,),
+        grid=(pl.cdiv(n_g, TILE_G),),
         in_specs=[
             pl.BlockSpec((TILE_G, width), lambda i: (i, 0)),
             pl.BlockSpec((TILE_G, lay.lo_bits), lambda i: (i, 0)),
@@ -95,6 +103,6 @@ def decode_reduce(
             pl.BlockSpec((TILE_G, GROUP), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_G, GROUP), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(payload, lo_planes, group_bases.reshape(-1, 1), acc.reshape(-1, GROUP))
     return out.reshape(-1)

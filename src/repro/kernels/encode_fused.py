@@ -22,11 +22,14 @@ Exception blocks (``rng >= 2**width``) carry clamped payload exactly like
 re-read of ONLY the exception rows (<= ``exc_frac`` of the input) — the
 bulk stays one-pass.
 
-The residual/pack algebra is pure VPU bit arithmetic; per-block stats are
-cross-lane min/max reductions (natively supported).  The in-kernel
-``reshape`` from ``(TILE_B, block)`` to ``(TILE_B * block/32, 32)`` groups
-is contiguity-preserving (row-major, last dim folds by whole multiples), the
-same shape family the bitpack kernel streams.
+The residual/pack algebra is pure VPU bit arithmetic on 32-bit lanes
+(16- and 8-bit inputs are zero-extended on load: Mosaic has no 16-bit
+shifts), held as ``int32`` because Mosaic has no unsigned min/max or
+reduction: exponents fit in 8 bits, and a plane word is a sum of distinct
+powers of two, so the int32 sum has the uint32 word's bits.  The input is
+viewed as ``(blocks, block/32, 32)`` so every 32-element group is one row
+of lanes: per-block stats are a lane then a sublane min/max, and each
+bit-plane word is a lane sum.
 """
 from __future__ import annotations
 
@@ -38,61 +41,59 @@ from jax.experimental import pallas as pl
 
 from repro.core import codec
 from repro.core.packing import GROUP
+from repro.kernels import resolve_interpret
 
 TILE_B = 8  # blocks per grid step (matches plane_split.py)
 
 
+def _plane_words(v, n_planes: int, out_ref):
+    """Bit-plane pack int32 (..., 32) groups: plane ``b``'s word is the lane
+    sum of ``bit_b << lane``, bitcast to uint32."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, GROUP), 2)
+    for b in range(n_planes):  # static unroll: one lane reduction per plane
+        out_ref[:, :, b] = jax.lax.bitcast_convert_type(
+            jnp.sum(((v >> b) & 1) << pos, axis=-1), jnp.uint32)
+
+
 def _encode_kernel(lay: codec.FloatLayout, width: int, x_ref, pay_ref, lo_ref,
                    base_ref, rng_ref):
-    u = lay.uint_dtype
-    bits = jax.lax.bitcast_convert_type(x_ref[...], u)  # (TILE_B, block)
-    exp = ((bits >> u(lay.mant_bits)) & u((1 << lay.exp_bits) - 1)).astype(
-        jnp.uint32
-    )
-    sign = bits >> u(lay.total_bits - 1)
-    lo = ((sign << u(lay.mant_bits)) | (bits & u((1 << lay.mant_bits) - 1))
-          ).astype(jnp.uint32)
+    x = jax.lax.bitcast_convert_type(x_ref[...], lay.uint_dtype)
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.uint32), jnp.int32)
+    exp = (bits >> lay.mant_bits) & ((1 << lay.exp_bits) - 1)
+    sign = (bits >> (lay.total_bits - 1)) & 1
+    lo = (sign << lay.mant_bits) | (bits & ((1 << lay.mant_bits) - 1))
 
     # zero-escape stats (wire format of packing.pack_exponents): base is the
     # min NONZERO exponent (1 when the block is all-zero), rng the max code
     # value ``max_nz - base + 1`` (0 when all-zero: 0 - 1 + 1 wraps to 0).
     nz = exp != 0
-    base = jnp.min(jnp.where(nz, exp, jnp.uint32(255)), axis=-1, keepdims=True)
-    base = jnp.where(jnp.any(nz, axis=-1, keepdims=True), base, jnp.uint32(1))
-    mx = jnp.max(jnp.where(nz, exp, jnp.uint32(0)), axis=-1, keepdims=True)
-    base_ref[...] = base
-    rng_ref[...] = mx - base + jnp.uint32(1)
+    lo_e = jnp.min(jnp.min(jnp.where(nz, exp, 255), axis=2, keepdims=True),
+                   axis=1, keepdims=True)  # (TILE_B, 1, 1)
+    hi_e = jnp.max(jnp.max(jnp.where(nz, exp, 0), axis=2, keepdims=True),
+                   axis=1, keepdims=True)
+    base = jnp.where(hi_e > 0, lo_e, 1)
+    base_ref[...] = base.astype(jnp.uint32)
+    rng_ref[...] = jax.lax.bitcast_convert_type(hi_e - base + 1, jnp.uint32)
 
     # residuals: code 0 = exponent 0, code r>0 = exp - base + 1, clamped to
     # width bits (exception blocks: payload is garbage, restored from the
     # raw exception region by the caller — identical to pack_exponents)
-    resid = jnp.where(nz, exp - base + jnp.uint32(1), jnp.uint32(0))
-    resid = jnp.minimum(resid, jnp.uint32((1 << width) - 1))
-
-    pos = jax.lax.broadcasted_iota(jnp.uint32, (1, GROUP), 1)
-    g = resid.reshape(-1, GROUP)  # (TILE_B * block/32, 32)
-    for b in range(width):  # static unroll: W plane reductions
-        pay_ref[:, b] = jnp.sum(
-            ((g >> jnp.uint32(b)) & jnp.uint32(1)) << pos, axis=-1,
-            dtype=jnp.uint32,
-        )
-    gl = lo.reshape(-1, GROUP)
-    for b in range(lay.lo_bits):
-        lo_ref[:, b] = jnp.sum(
-            ((gl >> jnp.uint32(b)) & jnp.uint32(1)) << pos, axis=-1,
-            dtype=jnp.uint32,
-        )
+    resid = jnp.where(nz, exp - base + 1, 0)
+    resid = jnp.minimum(resid, (1 << width) - 1)
+    _plane_words(resid, width, pay_ref)
+    _plane_words(lo, lay.lo_bits, lo_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "block", "interpret"))
 def encode_fused(x: jax.Array, width: int, block: int = 512,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """x float (n,), n % (block*TILE_B) == 0, 1 <= width <= 32.
 
     Returns (payload uint32 (n//32, width), lo_planes uint32 (n//32,
     lo_bits), bases uint32 (n_blocks,), rng uint32 (n_blocks,)) — one HBM
     pass over ``x``; bit-identical to ``kernels/ref.encode_fused`` (and
     through it to the split_planes + pack_exponents composition).
+    ``interpret=None`` resolves through ``kernels.resolve_interpret``.
     """
     lay = codec.layout_of(x.dtype)
     n = x.shape[0]
@@ -100,24 +101,24 @@ def encode_fused(x: jax.Array, width: int, block: int = 512,
     assert 1 <= width <= 32, width
     nb = n // block
     gpb = block // GROUP  # packed groups per block
-    n_g = n // GROUP
-    xb = x.reshape(nb, block)
+    xb = x.reshape(nb, gpb, GROUP)
     pay, lo, base, rng = pl.pallas_call(
         functools.partial(_encode_kernel, lay, width),
         out_shape=(
-            jax.ShapeDtypeStruct((n_g, width), jnp.uint32),
-            jax.ShapeDtypeStruct((n_g, lay.lo_bits), jnp.uint32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((nb, gpb, width), jnp.uint32),
+            jax.ShapeDtypeStruct((nb, gpb, lay.lo_bits), jnp.uint32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.uint32),
         ),
         grid=(nb // TILE_B,),
-        in_specs=[pl.BlockSpec((TILE_B, block), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((TILE_B, gpb, GROUP), lambda i: (i, 0, 0))],
         out_specs=(
-            pl.BlockSpec((TILE_B * gpb, width), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_B * gpb, lay.lo_bits), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_B, 1), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_B, 1), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_B, gpb, width), lambda i: (i, 0, 0)),
+            pl.BlockSpec((TILE_B, gpb, lay.lo_bits), lambda i: (i, 0, 0)),
+            pl.BlockSpec((TILE_B, 1, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((TILE_B, 1, 1), lambda i: (i, 0, 0)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xb)
-    return pay, lo, base.reshape(-1), rng.reshape(-1)
+    return (pay.reshape(-1, width), lo.reshape(-1, lay.lo_bits),
+            base.reshape(-1), rng.reshape(-1))

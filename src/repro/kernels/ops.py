@@ -85,15 +85,11 @@ def decode_reduce(payload, lo_planes, group_bases, acc, dtype_name: str,
                   width: int, *, use_pallas: bool | None = None,
                   interpret: bool | None = None):
     use_pallas, interpret = resolve_use_pallas(use_pallas), resolve_interpret(interpret)
-    if use_pallas:
-        if payload.shape[0] % _decode_reduce.TILE_G == 0:
-            return _decode_reduce.decode_reduce(
-                payload, lo_planes, group_bases, acc, dtype_name, width,
-                interpret=interpret,
-            )
-        record_fallback("decode_reduce",
-                        f"n_groups={payload.shape[0]} not a "
-                        f"{_decode_reduce.TILE_G} multiple")
+    if use_pallas:  # any n_groups: the kernel's last tile may be partial
+        return _decode_reduce.decode_reduce(
+            payload, lo_planes, group_bases, acc, dtype_name, width,
+            interpret=interpret,
+        )
     return _ref.decode_reduce(payload, lo_planes, group_bases, acc, dtype_name, width)
 
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import codec
+from repro.kernels import resolve_interpret
 
 TILE_B = 8  # blocks per grid step
 
@@ -49,7 +50,8 @@ def _split_kernel(lay: codec.FloatLayout, x_ref, exp_ref, lo_ref, base_ref, rng_
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def split_with_stats(x: jax.Array, block: int = 512, interpret: bool = True):
+def split_with_stats(x: jax.Array, block: int = 512,
+                     interpret: bool | None = None):
     """x float (n,), n % (block*TILE_B) == 0.
 
     Returns (exp uint32 (n,), lo uint32 (n,), bases uint32 (n_blocks,),
@@ -77,6 +79,6 @@ def split_with_stats(x: jax.Array, block: int = 512, interpret: bool = True):
             pl.BlockSpec((TILE_B, 1), lambda i: (i, 0)),
             pl.BlockSpec((TILE_B, 1), lambda i: (i, 0)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xb)
     return exp.reshape(-1), lo.reshape(-1), base.reshape(-1), rng.reshape(-1)

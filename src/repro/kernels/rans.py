@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 PROB_BITS = 12
 M = 1 << PROB_BITS
 RANS_L = 1 << 16
@@ -87,7 +89,8 @@ def _decode_kernel(per: int, words_ref, state_ref, freq_ref, cum_ref, s2s_ref, s
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def encode(syms: jax.Array, freq: jax.Array, cum: jax.Array, interpret: bool = True):
+def encode(syms: jax.Array, freq: jax.Array, cum: jax.Array,
+           interpret: bool | None = None):
     """syms uint32 (per, lanes); lanes % LANE_TILE == 0.
 
     Returns (words u32 (per, lanes), mask u32 (per, lanes), state u32 (lanes,)).
@@ -113,7 +116,7 @@ def encode(syms: jax.Array, freq: jax.Array, cum: jax.Array, interpret: bool = T
             pl.BlockSpec((per, LANE_TILE), lambda i: (0, i)),
             pl.BlockSpec((1, LANE_TILE), lambda i: (0, i)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(syms, freq.reshape(1, 256), cum.reshape(1, 256))
     return words, mask, state[0]
 
@@ -121,7 +124,7 @@ def encode(syms: jax.Array, freq: jax.Array, cum: jax.Array, interpret: bool = T
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode(
     words: jax.Array, state: jax.Array, freq: jax.Array, cum: jax.Array,
-    s2s: jax.Array, interpret: bool = True,
+    s2s: jax.Array, interpret: bool | None = None,
 ):
     """Inverse of :func:`encode`; returns syms u32 (per, lanes)."""
     per, lanes = words.shape
@@ -138,6 +141,6 @@ def decode(
             pl.BlockSpec((1, M), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((per, LANE_TILE), lambda i: (0, i)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words, state.reshape(1, lanes), freq.reshape(1, 256), cum.reshape(1, 256),
       s2s.reshape(1, M))

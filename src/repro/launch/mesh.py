@@ -17,9 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, *, devices=None):
+    """``devices`` defaults to ``jax.devices()``."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_smoke_mesh(n_devices: int | None = None, *, pods: int = 1):

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer
 from repro.serve.engine import Request, ServeConfig, ServeEngine
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     if cfg.enc_dec:
         raise SystemExit("enc-dec serving demo not wired in this driver; "

@@ -21,15 +21,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.core.policy import CompressionPolicy
 from repro.data.pipeline import DataConfig, DataPipeline
-from repro.launch.mesh import make_mesh, make_smoke_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_smoke_mesh
 from repro.optim import optimizers as opt_lib
 from repro.runtime.fault_tolerance import RunnerConfig, StepRunner
 from repro.train import step as step_lib
 
 
-def build(args):
+def build(args, mesh=None):
+    """``mesh`` defaults to the smoke mesh over every visible device."""
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
-    mesh = make_smoke_mesh(pods=args.pods)
+    if mesh is None:
+        mesh = make_smoke_mesh(pods=args.pods)
     dp = step_lib.dp_axes_of(mesh)
     n_dp = int(np.prod([mesh.shape[a] for a in dp]))
     policy = (CompressionPolicy(min_bytes=args.compress_min_bytes)
@@ -57,7 +60,10 @@ def build(args):
     bshard = NamedSharding(mesh, P(dpax, None))
 
     def wrap(fn):
-        jfn = jax.jit(fn, donate_argnums=(0,))
+        # a ZeRO-1 step is two jitted programs (train/step.py), donating
+        # its state; another jit around it would fuse them back into one
+        jfn = fn if tcfg.partition == "zero1" else jax.jit(
+            fn, donate_argnums=(0,))
 
         def run(state, batch):
             batch = {k: jax.device_put(jnp.asarray(v), bshard)
@@ -99,6 +105,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg, tcfg, mesh, state, runner = build(args)
     start = 0
     if args.resume:

@@ -197,6 +197,12 @@ def zero1_step(
         norm_sq = jnp.float32(0)
         for i, (name, gb) in enumerate(zip(meta.dtype_names, gbuckets)):
             gs, f = ex.reduce_scatter(i, gb)
+            # materialize the f32 shard whichever wire made it: XLA would
+            # fuse the raw wire's bf16->f32 convert into the norm reduction
+            # below, and a TPU reduction's summation order follows its
+            # operand's storage dtype, so raw and compressed grad norms
+            # would part in the last bits
+            gs = jax.lax.optimization_barrier(gs)
             flag = jnp.maximum(flag, f)
             gs = gs / n_dp  # mean over DP
             gshards.append(gs)
@@ -216,21 +222,29 @@ def zero1_step(
         beta_af = 1.0 - c.astype(jnp.float32) ** (-ocfg.decay_rate)
         for i, (name, gs, bst) in enumerate(zip(meta.dtype_names, gshards,
                                                 state["buckets"])):
-            g = gs * scale
+            # the update reads materialized inputs and its outputs are
+            # fenced from their consumers, so it compiles to the same code
+            # whichever wire surrounds it: fused with its neighbours, XLA
+            # rounds the same expression differently from program to program
+            gs, bst, scale_i, lr_i, bc1_i, bc2_i, beta_i = (
+                jax.lax.optimization_barrier(
+                    (gs, bst, scale, lr, bc1, bc2, beta_af)))
+            g = gs * scale_i
             master = bst["master"]
             if ocfg.name == "adamw":
                 m = b1 * bst["m"] + (1 - b1) * g
                 v = b2 * bst["v"] + (1 - b2) * jnp.square(g)
-                upd = (m / bc1) / (jnp.sqrt(v / bc2) + ocfg.eps)
+                upd = (m / bc1_i) / (jnp.sqrt(v / bc2_i) + ocfg.eps)
                 nb = {"m": m, "v": v}
             else:
-                v = beta_af * bst["v"] + (1 - beta_af) * (jnp.square(g) + 1e-30)
+                v = beta_i * bst["v"] + (1 - beta_i) * (jnp.square(g) + 1e-30)
                 upd = g / (jnp.sqrt(v) + 1e-12)
                 rms = jnp.sqrt(jnp.mean(jnp.square(upd)) + 1e-30)
                 upd = upd / jnp.maximum(1.0, rms)
                 nb = {"v": v}
-            master = master - lr * (upd + ocfg.weight_decay * master)
-            nb["master"] = master
+            nb["master"] = master - lr_i * (upd + ocfg.weight_decay * master)
+            nb = jax.lax.optimization_barrier(nb)
+            master = nb["master"]
             new_state_buckets.append(nb)
 
             # -- all-gather (compressed): redistribute updated params -------
@@ -255,10 +269,14 @@ def _raw_reduce_scatter(x, axes, n_dp):
     in device-index order (``_seq_sum``) — the same order as the compressed
     fused/unfused paths, so compressed-vs-raw training is bit-comparable."""
     from repro.core.compressed_collectives import _seq_sum, raw_all_to_all
-    x2 = x.reshape(n_dp, -1)
+    # rows of 128 lanes where they fit: the TPU compiler spends minutes on
+    # an all_to_all whose minor dimension is a whole multi-million-element
+    # shard, and seconds on the same bytes as 128-lane rows
+    lanes = (128,) if x.size % (n_dp * 128) == 0 else ()
+    x2 = x.reshape((n_dp, -1) + lanes)
     ax = tuple(axes) if isinstance(axes, (tuple, list)) else axes
     recv = raw_all_to_all(x2, ax, 0, 0)
-    return _seq_sum(recv, jnp.float32)
+    return _seq_sum(recv, jnp.float32).reshape(-1)
 
 
 def _raw_all_gather(x, axes):

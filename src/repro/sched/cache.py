@@ -124,6 +124,11 @@ class PlanCache:
                 "hit_rate": self.stats.hit_rate,
             }
 
+    def plans(self) -> tuple:
+        """Every stored plan, least recently used first."""
+        with self._lock:
+            return tuple(self._plans.values())
+
     def __len__(self) -> int:
         return len(self._plans)
 
@@ -165,8 +170,7 @@ def save_plans(path: str, cache: "PlanCache" = None) -> int:
     """Serialize every plan in ``cache`` (default: the process cache) to
     ``path`` (atomic: tmp + rename).  Returns the number saved."""
     cache = default_cache() if cache is None else cache
-    with cache._lock:
-        plans = tuple(cache._plans.values())
+    plans = cache.plans()
     tmp = path + ".tmp"
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     with open(tmp, "wb") as f:

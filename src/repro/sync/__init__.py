@@ -3,7 +3,7 @@
 Broadcasts versioned model weights from one trainer to N inference
 replicas over the compressed host/P2P wire, with a lossless XOR-delta
 transform against the receiver's acked base version (``core/codec.
-xor_delta`` + the split+pack delta wire in ``core/packing.py``) and
+xor_bits`` + the split+pack delta wire in ``core/packing.py``) and
 automatic fallback to full-tensor sends when the base is stale, absent,
 or epoch-fenced.  The schedule compiles ONCE into a kind-"wsync"
 ``CommPlan`` (``sched/compile.compile_wsync_plan``) and is replayed by

@@ -10,7 +10,8 @@ Structure (validated for lowering on 512-device meshes):
          │       'model') + sequence-chunked cross-entropy
          ├─ partition = zero1:  inner shard_map — manualizes 'model'
          │    └─ flat per-dtype buckets → compressed reduce-scatter →
-         │       fp32 shard update → compressed all-gather (optim/zero1.py)
+         │       fp32 shard update → compressed all-gather (optim/zero1.py);
+         │       a program of its own, apart from the gradient program
          └─ partition = fsdp:   params enter DP-sharded; compressed
               all-gathers run inside the forward scan via block_param_fn,
               their custom-vjp transposes reduce-scatter the gradients
@@ -512,10 +513,16 @@ def _build_zero1_step(cfg: ArchConfig, tcfg: TrainConfig, mesh):
             check_vma=False,
         )(params, grads, opt_state)
 
-    def outer_body(state, batch):
-        params = state["params"]
+    def grads_body(params, batch):
         loss, grads = _microbatch_grads(loss_fn, params, batch,
                                         tcfg.microbatches)
+        # each DP rank's own (unreduced) gradient, on a leading dp axis
+        return (jax.lax.pmean(loss, tuple(dp)),
+                jax.tree.map(lambda g: g[None], grads))
+
+    def update_body(state, grads, loss):
+        params = state["params"]
+        grads = jax.tree.map(lambda g: g[0], grads)
         new_params, new_opt, flag, gnorm = sync_and_update(
             params, grads, state["opt"])
         if tcfg.guard_overflow:
@@ -530,24 +537,32 @@ def _build_zero1_step(cfg: ArchConfig, tcfg: TrainConfig, mesh):
             "step": state["step"] + jnp.where(flag == 0, 1, 0)
             if tcfg.guard_overflow else state["step"] + 1,
         }
-        metrics = {
-            "loss": jax.lax.pmean(loss, tuple(dp)),
-            "gnorm": gnorm,
-            "overflow": flag,
-        }
+        metrics = {"loss": loss, "gnorm": gnorm, "overflow": flag}
         return new_state, metrics
 
     state_specs = make_train_state_specs(cfg, tcfg, mesh)
-    batch_spec = _batch_specs_tree(cfg, dp)
+    manual_specs = _manual_state_specs(state_specs, dp)
+    dpax = dp if len(dp) > 1 else dp[0]
+    grad_specs = jax.tree.map(lambda _: P(dpax), manual_specs["params"],
+                              is_leaf=lambda x: isinstance(x, P))
+    # two programs: the gradient program does not depend on the wire, so
+    # every policy (and the raw retry step) runs the same executable, and
+    # a compressed step differs from its raw twin only in the sync program.
+    # In one program XLA tiles the backward's matmuls per program, and the
+    # twins' gradients part in the last bits.
+    grads_fn = jax.jit(jax.shard_map(
+        grads_body, mesh=mesh,
+        in_specs=(manual_specs["params"], _batch_specs_tree(cfg, dp)),
+        out_specs=(P(), grad_specs), axis_names=set(dp), check_vma=False))
+    update_fn = jax.jit(jax.shard_map(
+        update_body, mesh=mesh,
+        in_specs=(manual_specs, grad_specs, P()),
+        out_specs=(manual_specs, {"loss": P(), "gnorm": P(), "overflow": P()}),
+        axis_names=set(dp), check_vma=False), donate_argnums=(0,))
 
     def step(state, batch):
-        return jax.shard_map(
-            outer_body, mesh=mesh,
-            in_specs=(_manual_state_specs(state_specs, dp), batch_spec),
-            out_specs=(_manual_state_specs(state_specs, dp),
-                       {"loss": P(), "gnorm": P(), "overflow": P()}),
-            axis_names=set(dp), check_vma=False,
-        )(state, batch)
+        loss, grads = grads_fn(state["params"], batch)
+        return update_fn(state, grads, loss)
 
     return step, state_specs
 

@@ -37,10 +37,8 @@ from repro.serve.kv_transfer import transfer_cache
 from repro.train.step import TrainConfig, build_train_state, build_train_step
 
 res = {}
-# model axis kept trivial (=1): nested shard_map with auto axes inside the
-# rematted forward scan cannot lower on jaxlib 0.4.x for model>1 (verified
-# fine on current jax); dp spans 8 devices, which is what the compressed
-# collectives under test ride on.
+# model axis kept trivial (=1): dp spans all 8 devices, which is what the
+# compressed collectives under test ride on.
 mesh3 = make_mesh((2, 4, 1), ("pod", "data", "model"))
 mesh1 = make_mesh((8,), ("data",))
 policy = CompressionPolicy(min_bytes=0)
@@ -393,9 +391,8 @@ def _train_zero1():
 
 @section("train_fsdp", ["train_fsdp_bitexact", "train_fsdp_loss_drop"])
 def _train_fsdp():
-    # jaxlib 0.4.x cannot lower the per-layer compressed gathers inside the
-    # rematted forward scan (verifier error); the section decorator records
-    # a skip there, and the suite skips rather than fails.
+    # per-layer compressed gathers inside the rematted forward scan; a
+    # lowering failure is recorded as a skip by the section decorator
     _train_part("fsdp", {"fsdp_min_bytes": 0})
 
 

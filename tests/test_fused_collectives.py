@@ -98,12 +98,17 @@ def test_fused_pallas_kernel_path():
 
 
 def test_tile_misaligned_falls_back():
-    """n_groups % TILE_G != 0: ops.decode_reduce must route to the fused
-    jnp reference (same semantics) instead of the Pallas kernel."""
+    """n_groups % TILE_G != 0: ops.decode_reduce stays on the Pallas kernel
+    (its last tile is partial) instead of falling back to the reference,
+    and matches the unfused path bitwise."""
+    from repro import kernels
+
     chunk = 512 * 3  # 48 groups: not a TILE_G multiple
     x = make_chunks("bfloat16", 2, chunk, seed=5)
+    kernels.clear_fallbacks()
     unfused, fused, f1, f2 = fused_vs_unfused(x, width=5, use_pallas=True)
     assert (bits32(unfused) == bits32(fused)).all()
+    assert "decode_reduce" not in kernels.fallback_counts()
 
 
 def test_acc_dtype_fallback_unfused():

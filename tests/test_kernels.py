@@ -85,3 +85,101 @@ def test_rans_kernel_adversarial_uniform():
     assert (ops.rans_decode(w, s, table, use_pallas=True) == syms).all()
     # uniform-256 data costs ~8 bits/sym ~= 0.5 words/sym (state absorbs a bit)
     assert float(m.mean()) > 0.4
+
+
+# ---------------------------------------------------------------------------
+# backend probe: nothing may hide the device
+# ---------------------------------------------------------------------------
+
+def test_backend_probe_failure_raises(monkeypatch):
+    """A backend that fails to start is an error, never reported as the
+    CPU (which would move every wire onto the jnp reference)."""
+    from repro import kernels
+
+    def broken():
+        raise RuntimeError("backend failed to start")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    kernels.probe_cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="failed to start"):
+            kernels.backend()
+    finally:
+        monkeypatch.undo()
+        kernels.probe_cache_clear()
+
+
+def test_false_use_pallas_env_is_an_error_on_tpu(monkeypatch):
+    """On the TPU the compiled kernels are the only path: a false
+    REPRO_USE_PALLAS raises instead of running the reference."""
+    from repro import kernels
+
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
+    try:
+        for env, want in (("0", RuntimeError), ("1", True), (None, True)):
+            if env is None:
+                monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+            else:
+                monkeypatch.setenv("REPRO_USE_PALLAS", env)
+            kernels.default_use_pallas.cache_clear()
+            if want is RuntimeError:
+                with pytest.raises(RuntimeError, match="REPRO_USE_PALLAS"):
+                    kernels.default_use_pallas()
+            else:
+                assert kernels.default_use_pallas() is want
+                assert kernels.default_interpret() is False
+    finally:
+        monkeypatch.undo()
+        kernels.probe_cache_clear()
+
+
+class _Traced(Exception):
+    """Raised by the pallas_call spy once the kernel's mode is known."""
+
+
+def _u32(*shape):
+    return jnp.zeros(shape, jnp.uint32)
+
+
+def _kernel_calls():
+    from repro.kernels import bitpack, decode_reduce, encode_fused, plane_split
+    from repro.kernels import rans
+
+    return {  # each takes a size multiplier (fresh shapes -> fresh traces)
+        "encode_fused": lambda k: encode_fused.encode_fused(
+            jnp.zeros((4096 * k,), jnp.float32), 5),
+        "decode_reduce": lambda k: decode_reduce.decode_reduce(
+            _u32(256 * k, 5), _u32(256 * k, 24), _u32(256 * k),
+            jnp.zeros((8192 * k,), jnp.float32), "float32", 5),
+        "bitpack.pack": lambda k: bitpack.pack(_u32(8192 * k), 4),
+        "bitpack.unpack": lambda k: bitpack.unpack(_u32(256 * k, 4), 4),
+        "plane_split": lambda k: plane_split.split_with_stats(
+            jnp.zeros((4096 * k,), jnp.bfloat16)),
+        "rans.encode": lambda k: rans.encode(_u32(k, 128), _u32(256),
+                                             _u32(256)),
+        "rans.decode": lambda k: rans.decode(
+            _u32(k, 128), _u32(128), _u32(256), _u32(256), _u32(rans.M)),
+    }
+
+
+@pytest.mark.parametrize("kernel", sorted(_kernel_calls()))
+def test_kernel_interpret_none_resolves_through_probe(monkeypatch, kernel):
+    """``interpret=None`` (every kernel's default) follows the backend
+    probe: interpreted on the CPU, compiled where the probe finds a TPU."""
+    from jax.experimental import pallas as pl
+    from repro import kernels
+
+    seen = []
+
+    def spy(*args, interpret=None, **kw):
+        seen.append(interpret)
+        raise _Traced
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    call = _kernel_calls()[kernel]
+    with pytest.raises(_Traced):
+        call(1)  # the CPU: interpret mode
+    monkeypatch.setattr(kernels, "backend", lambda: "tpu")
+    with pytest.raises(_Traced):
+        call(2)
+    assert seen == [True, False]

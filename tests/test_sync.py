@@ -1,7 +1,7 @@
 """Weight-sync subsystem: delta codec, wsync plans, version protocol.
 
 Quick-gate coverage (1-device meshes + host path):
-  * ``codec.xor_delta`` is a bit-exact involution across dtypes, on
+  * ``codec.xor_bits`` is a bit-exact involution across dtypes, on
     arbitrary bit patterns (NaN payloads / Inf / subnormals included);
   * the delta wire (``packing.encode_delta``/``decode_delta``) round-trips
     warm deltas exactly, degrades to an overflow flag (never silent
@@ -122,7 +122,7 @@ POL = CompressionPolicy(min_bytes=0)
 
 
 # ---------------------------------------------------------------------------
-# xor_delta: bit-exact involution
+# xor_bits: bit-exact involution
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype_name", DTYPES + ["float8_e4m3fn",
@@ -130,20 +130,20 @@ POL = CompressionPolicy(min_bytes=0)
 def test_xor_delta_involution_arbitrary_bits(dtype_name):
     x = random_bits(dtype_name, 4096, seed=1)
     b = random_bits(dtype_name, 4096, seed=2)
-    out = codec.xor_delta(codec.xor_delta(x, b), b)
-    assert bits_equal(out, x)
+    d = codec.xor_bits(x, b)
+    assert d.dtype == codec.LAYOUTS[dtype_name].uint_dtype
+    assert bool(jnp.all((d ^ bits(b)) == bits(x)))
     # delta against self is exactly zero bits
-    z = codec.xor_delta(x, x)
-    assert bool(jnp.all(bits(z) == 0))
+    assert bool(jnp.all(codec.xor_bits(x, x) == 0))
 
 
 def test_xor_delta_rejects_mismatch():
     with pytest.raises(ValueError):
-        codec.xor_delta(jnp.zeros((4,), jnp.float32),
-                        jnp.zeros((4,), jnp.bfloat16))
+        codec.xor_bits(jnp.zeros((4,), jnp.float32),
+                       jnp.zeros((4,), jnp.bfloat16))
     with pytest.raises(ValueError):
-        codec.xor_delta(jnp.zeros((4,), jnp.float32),
-                        jnp.zeros((8,), jnp.float32))
+        codec.xor_bits(jnp.zeros((4,), jnp.float32),
+                       jnp.zeros((8,), jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -203,6 +203,36 @@ def test_delta_message_overflow_flag_on_cold_delta():
     b = random_bits("bfloat16", 8192, seed=7)
     m = packing.encode_delta(x, b, width=1, lo_width=1)
     assert int(m.overflow) == 1
+
+
+def _float_bitcasts(fn, *args) -> int:
+    """Number of uint->float bitcasts in ``fn``'s jaxpr, nested ones too."""
+    def walk(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "bitcast_convert_type"
+                    and jnp.issubdtype(eqn.outvars[0].aval.dtype,
+                                       jnp.floating)):
+                n += 1
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    n += walk(inner)
+        return n
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("dtype_name", DTYPES)
+def test_delta_codec_never_holds_the_delta_as_a_float(dtype_name):
+    """The XOR delta of two nearby floats is mostly subnormal as a float,
+    and a TPU flushes subnormals where a program unpacks them: the codec
+    keeps the delta as bits, and only decode's result is a float again."""
+    new, base = warm_pair(dtype_name, 1024)
+    w, wl = POL.delta_widths(dtype_name)
+    enc = lambda a, b: packing.encode_delta(a, b, width=w, lo_width=wl)
+    assert _float_bitcasts(enc, new, base) == 0
+    m = enc(new, base)
+    assert _float_bitcasts(packing.decode_delta, m, base) == 1
 
 
 def test_delta_wire_bytes_matches_eval_shape():
