@@ -104,5 +104,6 @@ def decode_reduce(
         ],
         out_specs=pl.BlockSpec((TILE_G, GROUP), lambda i: (i, 0)),
         interpret=resolve_interpret(interpret),
+        name="decode_reduce",  # the kernel's name in a profiler trace
     )(payload, lo_planes, group_bases.reshape(-1, 1), acc.reshape(-1, GROUP))
     return out.reshape(-1)

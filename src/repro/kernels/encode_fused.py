@@ -119,6 +119,7 @@ def encode_fused(x: jax.Array, width: int, block: int = 512,
             pl.BlockSpec((TILE_B, 1, 1), lambda i: (i, 0, 0)),
         ),
         interpret=resolve_interpret(interpret),
+        name="encode_fused",  # the kernel's name in a profiler trace
     )(xb)
     return (pay.reshape(-1, width), lo.reshape(-1, lay.lo_bits),
             base.reshape(-1), rng.reshape(-1))

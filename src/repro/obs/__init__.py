@@ -3,8 +3,11 @@ wire-efficiency observatory.
 
 The sensor layer of the plan runtime (ROADMAP item 2's recalibration loop
 reads it): a process-wide thread-safe metrics registry (``metrics.py``), a
-nestable wall-clock span tracer with Chrome-trace/Perfetto export
-(``trace.py``), the canonical metric/span name tables (``names.py`` —
+nestable span tracer (``trace.py``) whose every span goes to the JAX
+profiler as a ``TraceAnnotation``, on the profiler's clock beside the
+device ops, and to an in-process wall-clock ring buffer with
+Chrome-trace/Perfetto export, the canonical metric/span name tables
+(``names.py`` —
 cross-checked against docs/ARCHITECTURE.md by a tier-1 test), and a dump
 CLI (``python -m repro.obs.dump``).
 
@@ -22,8 +25,9 @@ On top of the sensors sits the analysis layer:
 Instrumented layers: ``sched/executor`` (plan spans + wire bytes/ratio per
 kind, fed from the consolidated WireReports, plus the per-bucket ledger),
 ``sched/cache`` (hit/miss/eviction gauges + cache events), ``serve/engine``
-(admission/prefill/decode spans, queue depth, tokens/step, KV-ship drift),
-``sync/engine`` (publish/encode spans, delta-vs-full counts, per-replica
+(admission/prefill/decode and weight-ingest spans, queue depth, tokens/step, KV-ship drift),
+``sync/engine`` (publish/encode spans, the codec, wire-to-host, checksum
+and observatory spans of each update, delta-vs-full counts, per-replica
 version lag, host-path ledger + drift), ``p2p/engine`` and
 ``runtime/fault_tolerance`` (stage/step spans + latency histograms),
 ``kernels.record_fallback`` (labeled counter mirror).

@@ -182,7 +182,9 @@ SPECS = {s.name: s for s in METRICS}
 # plan kind from sched/compile.PLAN_KINDS.  ph "i" = instant marker.
 SPANS = (
     ("plan:<kind>", "sched/executor.py",
-     "one plan execution (trace-time replay of every bucket wire)"),
+     "the executor replaying one plan's bucket wires while jit traces the "
+     "step: once per trace, not per execution, so it never times a "
+     "running step"),
     ("plan_cache:compile", "sched/cache.py",
      "a cache miss running its plan compiler"),
     ("plan_cache:hit", "sched/cache.py", "instant: plan-cache hit"),
@@ -198,6 +200,23 @@ SPANS = (
      "instant: update served from the per-base memo"),
     ("sync:encode", "sync/engine.py",
      "encoding an update (delta/full/raw per bucket)"),
+    ("sync:codec", "sync/engine.py",
+     "one bucket's device codec, from the encode dispatch through the "
+     "host's read of its overflow flag"),
+    ("sync:d2h", "sync/engine.py",
+     "copying one bucket's encoded (or raw) wire, or the raw leaves, to "
+     "the host"),
+    ("sync:checksum", "sync/engine.py",
+     "the trainer's CRC-32 over an encoded update's payload"),
+    ("sync:apply", "serve/engine.py",
+     "the replica decoding an update into its weights (apply_update)"),
+    ("serve:ingest", "serve/engine.py",
+     "one weight-sync update hot-swapped in (ingest_weights)"),
+    ("serve:verify", "serve/engine.py",
+     "the replica's CRC-32 check of an update before the fence"),
+    ("obs:sample", "sync/engine.py",
+     "the observatory's own cost in an encode: wire ledger, payload "
+     "sample, drift observation"),
     ("p2p:encode", "p2p/engine.py", "host Compressor encode"),
     ("p2p:split", "p2p/engine.py", "plane-split stage (rANS codec)"),
     ("p2p:entropy_code", "p2p/engine.py", "rANS exponent-plane encode"),

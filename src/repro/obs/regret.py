@@ -16,8 +16,9 @@ widths?":
 
 * **Samples** — the host encode paths (sync ``_encode_update``, p2p
   ``Compressor.encode``) are the only places concrete payload data exists
-  outside a trace; they deposit bounded, stride-downsampled copies here.
-  :func:`width_regret` re-runs ``calibrate.choose_width`` /
+  outside a trace; they deposit bounded, stride-downsampled copies here
+  (a device array is strided on the device, so only the sample reaches
+  the host).  :func:`width_regret` re-runs ``calibrate.choose_width`` /
   ``choose_delta_widths`` offline on those samples and prices the gap:
   *regret* = achieved wire bytes − (optimal predicted ratio × achieved
   raw bytes), per (kind, dtype).  A large positive regret is the
@@ -30,8 +31,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import config
@@ -42,6 +46,19 @@ SAMPLE_MAX_ELEMS = 1 << 16  # stride-downsample bound per sample
 LEDGER_METRICS = ("bucket_wire_bytes_total", "bucket_wire_raw_bytes_total")
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _strided_bits(arrays: tuple, stride: int) -> tuple:
+    """Every ``stride``-th element of each array, flattened, as its
+    unsigned bit pattern: the device never unpacks the floats, so NaN
+    payloads and subnormals survive."""
+    out = []
+    for a in arrays:
+        u = jax.lax.bitcast_convert_type(
+            a, jnp.dtype(f"uint{8 * a.dtype.itemsize}")).reshape(-1)
+        out.append(jax.lax.slice(u, (0,), (u.size,), (stride,)))
+    return tuple(out)
+
+
 @dataclasses.dataclass(frozen=True)
 class _Sample:
     x: np.ndarray          # flattened (possibly strided) payload copy
@@ -49,23 +66,44 @@ class _Sample:
     elems: int             # pre-downsample element count
 
 
+class _DeviceSample:
+    """A sample of device float arrays, strided on the device in one call
+    and copied to the host in the background: the encode that records it
+    waits for neither, and only the sample crosses, not the buckets."""
+
+    def __init__(self, arrays: tuple, stride: int, elems: int):
+        self._bits = _strided_bits(arrays, stride)
+        for b in self._bits:
+            b.copy_to_host_async()
+        self._dtypes = tuple(a.dtype for a in arrays)
+        self._elems = elems
+
+    def sample(self) -> _Sample:
+        x, *base = (np.array(b).view(d)
+                    for b, d in zip(self._bits, self._dtypes))
+        return _Sample(x=x, base=base[0] if base else None,
+                       elems=self._elems)
+
+
 class _SampleStore:
     def __init__(self):
         self._lock = threading.Lock()
-        self._store: dict = {}  # (kind, dtype_name) -> deque[_Sample]
+        # (kind, dtype_name) -> deque[_Sample | _DeviceSample]
+        self._store: dict = {}
 
     def record(self, kind: str, dtype_name: str, x, base=None) -> None:
-        x = np.asarray(x).reshape(-1)
-        elems = int(x.size)
-        if base is not None:
-            base = np.asarray(base).reshape(-1)
-        if elems > SAMPLE_MAX_ELEMS:
-            stride = -(-elems // SAMPLE_MAX_ELEMS)
-            x = x[::stride]
-            if base is not None:
-                base = base[::stride]  # keep element pairing for the delta
-        s = _Sample(x=np.array(x), base=None if base is None
-                    else np.array(base), elems=elems)
+        elems = int(np.prod(np.shape(x)))
+        stride = max(1, -(-elems // SAMPLE_MAX_ELEMS))
+        # same stride for the base: keeps element pairing for the delta
+        arrays = (x,) if base is None else (x, base)
+        if stride > 1 and all(isinstance(a, jax.Array)
+                              and jnp.issubdtype(a.dtype, jnp.floating)
+                              for a in arrays):
+            s = _DeviceSample(arrays, stride, elems)
+        else:
+            x, *base = (np.array(np.asarray(a).reshape(-1)[::stride])
+                        for a in arrays)
+            s = _Sample(x=x, base=base[0] if base else None, elems=elems)
         with self._lock:
             ring = self._store.get((kind, dtype_name))
             if ring is None:
@@ -74,8 +112,11 @@ class _SampleStore:
             ring.append(s)
 
     def items(self) -> dict:
+        """(kind, dtype) -> retained samples as host arrays, newest last."""
         with self._lock:
-            return {k: tuple(v) for k, v in self._store.items()}
+            return {k: tuple(s.sample() if isinstance(s, _DeviceSample)
+                             else s for s in v)
+                    for k, v in self._store.items()}
 
     def clear(self) -> None:
         with self._lock:
@@ -199,8 +240,6 @@ def width_regret(*, block: int = 512, target_exc_rate: float = 1e-3,
     """Re-calibrate on the recent samples and price every sampled (kind,
     dtype) bucket set: achieved wire bytes (ledger) vs what the freshly
     chosen width predicts for the same raw bytes.  Sorted worst-first."""
-    import jax.numpy as jnp
-
     from repro.core import calibrate
 
     totals = ledger_totals()["by_bucket"]

@@ -1,23 +1,31 @@
-"""Comm-span tracer with Chrome-trace/Perfetto export.
+"""Comm-span tracer: the JAX profiler's host trace plus an in-process ring
+buffer with Chrome-trace/Perfetto export.
 
 Spans make the runtime's overlap claims *verifiable instead of asserted*:
-``with obs.span("plan:psum", plan_key=...)`` records a wall-clock
-(``perf_counter``) interval into a bounded ring buffer; spans nest (a
-per-thread stack tracks depth), and :func:`export_chrome_trace` writes the
-buffer as Chrome-trace JSON (``{"traceEvents": [{"ph": "X", "ts", "dur",
-"name", "pid", "tid", "args"}, ...]}``) that loads directly in
-Perfetto / ``chrome://tracing`` — a train step, a wsync publish fan-out or
-a serve admission renders as a readable timeline.
+``with obs.span("plan:psum", plan_key=...)`` goes to two places.  It enters
+a ``jax.profiler.TraceAnnotation`` of the same name, with the args given at
+entry, so a profiler trace (``jax.profiler.start_trace`` or TensorBoard)
+shows the span on the profiler's one clock, beside the device ops it
+waited for.  It also records a wall-clock (``perf_counter``) interval into
+a bounded ring buffer, the in-process view that :func:`spans`, the tests
+and the ``python -m repro.obs.dump`` CLI read; spans nest (a per-thread
+stack tracks depth), and :func:`export_chrome_trace` writes the buffer as
+Chrome-trace JSON (``{"traceEvents": [{"ph": "X", "ts", "dur", "name",
+"pid", "tid", "args"}, ...]}``) that loads directly in Perfetto /
+``chrome://tracing``.  An annotation costs about a microsecond when no
+profiler runs.
 
 Point-in-time markers (cache hits, retries) are ``instant`` events
-(``ph: "i"``).  The ring buffer (``REPRO_OBS_SPAN_CAP``, default 65536)
-keeps the newest records; ``REPRO_TRACE_DIR`` names the default export
-directory.  Span names follow ``<subsystem>:<operation>`` — the canonical
-list lives in ``obs/names.py`` and docs/ARCHITECTURE.md.
+(``ph: "i"``; a zero-length annotation in the profiler's trace).  The
+ring buffer (``REPRO_OBS_SPAN_CAP``, default 65536) keeps the newest
+records; ``REPRO_TRACE_DIR`` names the default export directory.  Span
+names follow ``<subsystem>:<operation>`` — the canonical list lives in
+``obs/names.py`` and docs/ARCHITECTURE.md.
 
-Timestamps are relative to a process-wide epoch taken at import, so one
-export shows every thread on a common clock.  With ``REPRO_OBS=0``,
-``span()``/``instant()`` collapse to a shared no-op.
+Ring-buffer timestamps are relative to a process-wide epoch taken at
+import, so one export shows every thread on a common clock.  With
+``REPRO_OBS=0``, ``span()``/``instant()`` collapse to a shared no-op that
+records nothing and annotates nothing.
 """
 from __future__ import annotations
 
@@ -28,6 +36,8 @@ import os
 import tempfile
 import threading
 import time
+
+from jax import profiler
 
 from repro.obs import config
 
@@ -83,9 +93,10 @@ class _Span:
 
     The args dict is read at exit, so instrumentation may attach values
     discovered inside the span body (e.g. the plan kind a cache compile
-    produced)."""
+    produced); the profiler's annotation carries the args given at entry."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "dur", "depth")
+    __slots__ = ("_tracer", "name", "args", "t0", "dur", "depth",
+                 "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self._tracer = tracer
@@ -98,11 +109,14 @@ class _Span:
         stack = self._tracer._stack()
         self.depth = len(stack)
         stack.append(self)
+        self._annotation = profiler.TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         self.dur = t1 - self.t0
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
@@ -118,7 +132,8 @@ def _jsonable(v):
 
 
 class SpanTracer:
-    """Bounded ring buffer of spans with per-thread nesting stacks."""
+    """Bounded ring buffer of spans with per-thread nesting stacks; every
+    span and instant is also a profiler annotation."""
 
     def __init__(self, capacity: int = DEFAULT_SPAN_CAPACITY) -> None:
         self._buf: collections.deque = collections.deque(maxlen=capacity)
@@ -138,11 +153,14 @@ class SpanTracer:
     # -- recording -----------------------------------------------------------
 
     def span(self, name: str, **args) -> _Span:
-        """Context manager recording one wall-clock span (nestable)."""
+        """Context manager recording one wall-clock span (nestable),
+        annotated for the profiler while it is open."""
         return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:
         """Record a point-in-time marker (Chrome ``ph: "i"``)."""
+        with profiler.TraceAnnotation(name, **args):
+            pass
         self._record(SpanRecord(
             name=name, ts=time.perf_counter() - _EPOCH, dur=0.0,
             tid=threading.get_ident(), depth=len(self._stack()), args=args,

@@ -152,25 +152,35 @@ class ServeEngine:
         from repro.core.integrity import WireIntegrityError
         from repro.sync.engine import apply_update, verify_update
 
-        if update.checksum is not None and not verify_update(update):
-            obs.metric("serve_ingest_rejects_total").inc(reason="checksum")
-            raise WireIntegrityError(
-                f"update v{update.version} failed its payload checksum; "
-                f"re-send it (escalate delta -> full -> raw)")
-        if update.base_version is not None:
-            if (update.base_version != self.weight_version
-                    or update.epoch != self.weight_epoch):
-                obs.metric("serve_ingest_rejects_total").inc(reason="fence")
-                raise ValueError(
-                    f"delta update v{update.version} assumes base "
-                    f"v{update.base_version}@e{update.epoch} but this engine "
-                    f"holds v{self.weight_version}@e{self.weight_epoch}; "
-                    f"request a full send")
-            self.params = apply_update(update, base_params=self.params)
-        else:
-            self.params = apply_update(update)
-        self.weight_version = update.version
-        self.weight_epoch = update.epoch
+        with obs.span("serve:ingest", version=update.version):
+            if update.checksum is not None:
+                with obs.span("serve:verify"):
+                    intact = verify_update(update)
+                if not intact:
+                    obs.metric("serve_ingest_rejects_total").inc(
+                        reason="checksum")
+                    raise WireIntegrityError(
+                        f"update v{update.version} failed its payload "
+                        f"checksum; re-send it (escalate delta -> full -> "
+                        f"raw)")
+            if update.base_version is not None:
+                if (update.base_version != self.weight_version
+                        or update.epoch != self.weight_epoch):
+                    obs.metric("serve_ingest_rejects_total").inc(
+                        reason="fence")
+                    raise ValueError(
+                        f"delta update v{update.version} assumes base "
+                        f"v{update.base_version}@e{update.epoch} but this "
+                        f"engine holds v{self.weight_version}"
+                        f"@e{self.weight_epoch}; request a full send")
+                with obs.span("sync:apply", mode="delta"):
+                    self.params = apply_update(update,
+                                               base_params=self.params)
+            else:
+                with obs.span("sync:apply", mode="full"):
+                    self.params = apply_update(update)
+            self.weight_version = update.version
+            self.weight_epoch = update.epoch
         return self.weight_version
 
     # -- admission -----------------------------------------------------------

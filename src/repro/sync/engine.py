@@ -281,51 +281,62 @@ class WeightSyncEngine:
                         base_bucket = codec.pad_flat_bits(
                             codec.concat_members(base_leaves, b.members),
                             b.block)
-                        m = packing.encode_delta(
-                            bucket, base_bucket, width=b.delta_width,
-                            lo_width=b.delta_lo_width, block=b.block,
-                            exc_frac=b.exc_frac)
-                        if not int(m.overflow):  # else: fall through to full
-                            mode, msg = MODE_DELTA, jax.device_get(m)
+                        with obs.span("sync:codec", mode=MODE_DELTA):
+                            m = packing.encode_delta(
+                                bucket, base_bucket, width=b.delta_width,
+                                lo_width=b.delta_lo_width, block=b.block,
+                                exc_frac=b.exc_frac)
+                            overflow = int(m.overflow)
+                        if not overflow:  # else: fall through to full
+                            with obs.span("sync:d2h", mode=MODE_DELTA):
+                                mode, msg = MODE_DELTA, jax.device_get(m)
                             wire += m.wire_bytes()
                             used_delta = True
                     if msg is None:
-                        m = packing.encode_message(
-                            bucket, width=b.width, block=b.block,
-                            exc_frac=b.exc_frac, fused=b.encode_fused)
-                        if int(m.exp.overflow):
+                        with obs.span("sync:codec", mode=MODE_FULL):
+                            m = packing.encode_message(
+                                bucket, width=b.width, block=b.block,
+                                exc_frac=b.exc_frac, fused=b.encode_fused)
+                            overflow = int(m.exp.overflow)
+                        if overflow:
                             # even the full wire's exceptions overflowed
                             # (pathological exponent spread): ship the bucket
                             # raw — the host twin of the runtime's
                             # retry-uncompressed guard.  Never corrupt.
-                            mode, msg = (MODE_RAW,
-                                         _raw_wire(bucket, b.dtype_name))
+                            with obs.span("sync:d2h", mode=MODE_RAW):
+                                mode, msg = (MODE_RAW,
+                                             _raw_wire(bucket, b.dtype_name))
                             wire += msg.nbytes
                         else:
-                            mode, msg = MODE_FULL, jax.device_get(m)
+                            with obs.span("sync:d2h", mode=MODE_FULL):
+                                mode, msg = MODE_FULL, jax.device_get(m)
                             wire += m.wire_bytes()
                 else:
-                    msg = _raw_wire(bucket, b.dtype_name)
+                    with obs.span("sync:d2h", mode=MODE_RAW):
+                        msg = _raw_wire(bucket, b.dtype_name)
                     wire += msg.nbytes
                 bucket_counter.inc(mode=mode)
                 if obs.enabled():
                     # host-path ledger + offline-recalibration sample: its
                     # own kind, so the plan-kind exactness check stays
                     # exact under mixed workloads
-                    w_used = {MODE_DELTA: b.delta_width,
-                              MODE_FULL: b.width}.get(mode, 0)
-                    raw_b = int(bucket.size) * jnp.dtype(bucket.dtype).itemsize
-                    obs.metric("bucket_wire_raw_bytes_total").inc(
-                        raw_b, kind="wsync_host", dtype=b.dtype_name,
-                        width=w_used)
-                    obs.metric("bucket_wire_bytes_total").inc(
-                        wire - wire_before, kind="wsync_host",
-                        dtype=b.dtype_name, width=w_used)
-                    regret_lib.record_sample("wsync_host", b.dtype_name,
-                                             bucket, base=base_bucket)
+                    with obs.span("obs:sample"):
+                        w_used = {MODE_DELTA: b.delta_width,
+                                  MODE_FULL: b.width}.get(mode, 0)
+                        raw_b = (int(bucket.size)
+                                 * jnp.dtype(bucket.dtype).itemsize)
+                        obs.metric("bucket_wire_raw_bytes_total").inc(
+                            raw_b, kind="wsync_host", dtype=b.dtype_name,
+                            width=w_used)
+                        obs.metric("bucket_wire_bytes_total").inc(
+                            wire - wire_before, kind="wsync_host",
+                            dtype=b.dtype_name, width=w_used)
+                        regret_lib.record_sample("wsync_host", b.dtype_name,
+                                                 bucket, base=base_bucket)
                 buckets.append((b.dtype_name, b.members, mode, msg))
-            raw_leaves = tuple((i, np.asarray(leaves[i]))
-                               for i in plan.raw_leaf_ix)
+            with obs.span("sync:d2h", mode="leaves"):
+                raw_leaves = tuple((i, np.asarray(leaves[i]))
+                                   for i in plan.raw_leaf_ix)
         wire += sum(arr.nbytes for _, arr in raw_leaves)
         raw_total = sum(l.size * jnp.dtype(l.dtype).itemsize
                         for l in leaves if hasattr(l, "dtype"))
@@ -336,7 +347,8 @@ class WeightSyncEngine:
             n_leaves=len(leaves), buckets=tuple(buckets),
             raw_leaves=raw_leaves, wire_bytes=int(wire),
             raw_bytes=int(raw_total))
-        update.checksum = update_checksum(update)
+        with obs.span("sync:checksum"):
+            update.checksum = update_checksum(update)
         if obs.enabled() and force is None and raw_total > 0:
             # drift: the plan PREDICTS this send's mode mix (delta when a
             # base is acked and the widths are calibrated, full otherwise);
@@ -352,8 +364,9 @@ class WeightSyncEngine:
                     + sum(bb.raw_bytes for bb in plan.buckets
                           if not bb.compressed)
                     + sum(arr.nbytes for _, arr in raw_leaves))
-            drift_lib.observe((plan.key, "host"), plan.kind,
-                              pred / raw_total, update.ratio)
+            with obs.span("obs:sample"):
+                drift_lib.observe((plan.key, "host"), plan.kind,
+                                  pred / raw_total, update.ratio)
         return update
 
     def ack(self, replica, version: int, epoch: Optional[int] = None) -> bool:

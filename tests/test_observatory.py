@@ -263,6 +263,57 @@ def test_sample_store_downsamples_and_bounds():
     assert len(ring) == regret_lib.SAMPLE_CAPACITY  # bounded
 
 
+def _float_bits(shape, dtype, seed):
+    """Random bit patterns of ``dtype``'s width with NaN payloads (quiet
+    and signalling, both signs), subnormals, infinities and zeros
+    planted, as a device array of ``dtype``."""
+    fi = jnp.finfo(dtype)
+    bits, nmant = fi.bits, fi.nmant
+    uint = np.dtype(f"uint{bits}")
+    exp_all = ((1 << (bits - 1 - nmant)) - 1) << nmant
+    sign = 1 << (bits - 1)
+    special = [exp_all | 1, sign | exp_all | (1 << (nmant - 1)) | 5,
+               exp_all | ((1 << nmant) - 1), 1, sign | ((1 << nmant) - 1),
+               exp_all, sign | exp_all, 0, sign]
+    rng = np.random.default_rng(seed)
+    u = rng.integers(0, 1 << bits, size=int(np.prod(shape)),
+                     dtype=np.uint64).astype(uint)
+    u[rng.choice(u.size, 64 * len(special), replace=False)] = np.repeat(
+        np.asarray(special, uint), 64)
+    u[::regret_lib.SAMPLE_MAX_ELEMS // 7] = special[0]  # some are sampled
+    return jax.lax.bitcast_convert_type(jnp.asarray(u.reshape(shape)), dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16, jnp.float32])
+def test_device_sample_is_the_host_stride_bit_for_bit(dtype, monkeypatch):
+    """A device bucket is strided on the device: the sample and its base
+    are the bits ``np.asarray(x).reshape(-1)[::stride]`` keeps, and only
+    the sample crosses to the host."""
+    shape = (7, 28091)  # 196,637 elements: stride 4
+    x, base = _float_bits(shape, dtype, 0), _float_bits(shape, dtype, 1)
+    stride = -(-x.size // regret_lib.SAMPLE_MAX_ELEMS)
+    want_x = np.asarray(x).reshape(-1)[::stride]
+    want_base = np.asarray(base).reshape(-1)[::stride]
+    crossed = []
+    strided = regret_lib._strided_bits
+
+    def spy(arrays, s):
+        out = strided(arrays, s)
+        crossed.append([o.size for o in out])
+        return out
+
+    monkeypatch.setattr(regret_lib, "_strided_bits", spy)
+    regret_lib.record_sample("wsync_host", jnp.dtype(dtype).name, x,
+                             base=base)
+    (s,) = regret_lib.samples()[("wsync_host", jnp.dtype(dtype).name)]
+    uint = f"uint{jnp.finfo(dtype).bits}"
+    assert s.elems == x.size and s.x.dtype == want_x.dtype
+    assert np.array_equal(s.x.view(uint), want_x.view(uint))
+    assert np.array_equal(s.base.view(uint), want_base.view(uint))
+    assert np.isnan(s.x.astype(np.float32)).any()  # NaNs were sampled
+    assert crossed == [[want_x.size, want_base.size]]  # one call
+
+
 # ---------------------------------------------------------------------------
 # drift detection
 # ---------------------------------------------------------------------------
