@@ -300,6 +300,61 @@ class DeltaPlane:
     n: int  # original element count (pre-padding)
 
 
+_WORD_ROW = 512  # mask elements per row of the word-packing matmul
+
+
+def _word_weights() -> np.ndarray:
+    """(512, 32) bf16-exact powers of two: column ``w`` (``16 + w``) sums
+    the low (high) 16 bits of the row's word ``w``."""
+    lane = np.arange(_WORD_ROW)
+    half = (lane % GROUP) // 16
+    wts = np.zeros((_WORD_ROW, 2 * _WORD_ROW // GROUP), np.float32)
+    wts[lane, half * (_WORD_ROW // GROUP) + lane // GROUP] = 2.0 ** (lane % 16)
+    return wts
+
+
+@partial(jax.jit, static_argnames=("size", "fill"))
+def exception_indices(mask: jax.Array, *, size: int, fill: int) -> jax.Array:
+    """``jnp.nonzero(mask, size=size, fill_value=fill)[0]`` as int32, for a
+    bool ``mask`` whose length is a multiple of 32.
+
+    ``jnp.nonzero`` scatters one update per element of ``mask``.  Here the
+    index is found in two levels.  The mask is packed into 32-element words
+    (bit ``i`` of word ``r`` is element ``32 r + i``, as in
+    :func:`bitplane_pack`) by one matmul against powers of two: each half
+    word sums to under 2**16, exact in f32, and the mask is read in
+    lane-dense rows.  The word that holds the ``k``-th true element comes
+    from ``jnp.nonzero``'s own cumsum-of-bincount trick over the words'
+    counts (one scatter update per word); its rank inside the word from the
+    slot where that word's run of slots starts, and the bit from a popcount
+    binary search.  Every op after the word counts is over ``size``, with
+    one gather."""
+    n = mask.shape[0]
+    assert n % GROUP == 0, n
+    m = jnp.pad(mask, (0, (-n) % _WORD_ROW)).reshape(-1, _WORD_ROW)
+    halves = jnp.dot(m.astype(jnp.bfloat16),
+                     jnp.asarray(_word_weights(), jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.uint32)
+    nw = _WORD_ROW // GROUP
+    words = (halves[:, :nw] | (halves[:, nw:] << 16)).reshape(-1)
+    incl = jnp.cumsum(jax.lax.population_count(words).astype(jnp.int32))
+    # r[k] = #{words whose inclusive count is <= k}: the k-th true element's word
+    r = jnp.cumsum(jnp.zeros(size, jnp.int32).at[incl].add(1, mode="drop"))
+    k = jnp.arange(size, dtype=jnp.int32)
+    new_word = jnp.concatenate([jnp.ones(1, bool), r[1:] != r[:-1]])
+    j = k - jax.lax.cummax(jnp.where(new_word, k, 0))  # rank in its word
+    w = words[jnp.minimum(r, words.shape[0] - 1)]
+    bit = jnp.zeros(size, jnp.int32)
+    for s in (16, 8, 4, 2, 1):  # the j-th set bit of w, from bit 0 up
+        low = jax.lax.population_count(
+            w & jnp.uint32((1 << s) - 1)).astype(jnp.int32)
+        up = j >= low
+        j = jnp.where(up, j - low, j)
+        w = jnp.where(up, w >> jnp.uint32(s), w)
+        bit = jnp.where(up, bit + s, bit)
+    return jnp.where(k < incl[-1], GROUP * r + bit, jnp.int32(fill))
+
+
 def pack_delta_plane(vals: jax.Array, width: int, *,
                      exc_frac: float = 0.02) -> DeltaPlane:
     """Pack a uint32 lo-delta stream at ``width`` bits/element.
@@ -317,8 +372,7 @@ def pack_delta_plane(vals: jax.Array, width: int, *,
     cap = min(n, max(4, int(np.ceil(n * exc_frac))))
     bad = ~fits
     n_bad = jnp.sum(bad.astype(jnp.int32))
-    (exc_idx,) = jnp.nonzero(bad, size=cap, fill_value=v.shape[0])
-    exc_idx = exc_idx.astype(jnp.int32)
+    exc_idx = exception_indices(bad, size=cap, fill=v.shape[0])
     exc_raw = v[jnp.minimum(exc_idx, v.shape[0] - 1)]
     exc_raw = jnp.where(exc_idx < v.shape[0], exc_raw, 0)
     overflow = (n_bad > cap).astype(jnp.int32)

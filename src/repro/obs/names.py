@@ -111,6 +111,13 @@ METRICS = (
     MetricSpec("sync_replica_version_lag", "gauge", ("replica",),
                "sync/engine.py",
                "latest published version minus the replica's acked version"),
+    MetricSpec("sync_delta_exceptions_total", "counter", ("plane",),
+               "sync/engine.py",
+               "delta exception-list slots used, by plane (lo/exp)"),
+    MetricSpec("sync_delta_exception_slots_total", "counter", ("plane",),
+               "sync/engine.py",
+               "delta exception-list slots shipped (the capacity), by "
+               "plane (lo/exp)"),
     # -- p2p/engine.py
     MetricSpec("p2p_encode_seconds", "histogram", ("codec",),
                "p2p/engine.py", "host Compressor.encode wall time"),

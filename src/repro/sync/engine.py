@@ -48,6 +48,18 @@ MODE_RAW = "raw"
 FORCE_MODES = (None, MODE_FULL, MODE_RAW)
 
 
+def _count_exceptions(msg: packing.DeltaMessage) -> None:
+    """Occupancy of a host-copied delta's two exception lists: each
+    ``exc_idx`` is sorted, its unused slots at the plane's fill value."""
+    lo_fill = msg.lo.payload.shape[0] * packing.GROUP
+    for plane, p, fill in (("lo", msg.lo, lo_fill),
+                           ("exp", msg.exp, msg.exp.n_blocks)):
+        obs.metric("sync_delta_exceptions_total").inc(
+            int(np.searchsorted(p.exc_idx, fill)), plane=plane)
+        obs.metric("sync_delta_exception_slots_total").inc(
+            int(p.exc_idx.size), plane=plane)
+
+
 def _raw_wire(bucket, dtype_name):
     """Raw bucket -> wire ndarray.  Codec float dtypes travel as their
     uint bit patterns: converting sub-f32 floats through host numpy can
@@ -290,6 +302,7 @@ class WeightSyncEngine:
                         if not overflow:  # else: fall through to full
                             with obs.span("sync:d2h", mode=MODE_DELTA):
                                 mode, msg = MODE_DELTA, jax.device_get(m)
+                            _count_exceptions(msg)
                             wire += m.wire_bytes()
                             used_delta = True
                     if msg is None:

@@ -270,6 +270,48 @@ def test_sync_engine_instrumentation():
                for s in obs.spans())
 
 
+def test_sync_delta_exception_counters():
+    """After one delta update the exception counters read, per plane, the
+    exceptions the delta really has and the lists' static capacity."""
+    from repro.core import codec, packing
+    from repro.core.policy import CompressionPolicy
+    from repro.sync.engine import WeightSyncEngine
+
+    n = 4096
+    rng = np.random.default_rng(0)
+    base = jnp.asarray(rng.normal(0, 0.02, n), jnp.bfloat16)
+    flip = rng.integers(0, 8, n).astype(np.uint16)
+    flip[rng.random(n) > 0.3] = 0
+    flip[rng.choice(n, 20, replace=False)] |= 1 << 6  # lo exceptions
+    flip[[3, 9]] |= np.array([1 << 7, 1 << 10], np.uint16)  # block 0's
+    # exponent deltas 1 and 8: a range the 2-bit exponent width cannot hold
+    new = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(base, jnp.uint16) ^ jnp.asarray(flip),
+        jnp.bfloat16)
+    eng = WeightSyncEngine(policy=CompressionPolicy(min_bytes=0))
+    eng.ack("r0", eng.publish({"w": base}))
+    eng.publish({"w": new})
+    upd = eng.update_for("r0")
+    assert upd.mode == "delta"
+
+    (b,) = eng.plan_for({"w": new}).buckets
+    exp, lo = codec.split_bits(codec.xor_bits(new, base),
+                               codec.LAYOUTS["bfloat16"])
+    lo_used = int(np.sum(np.asarray(lo) > (1 << b.delta_lo_width) - 1))
+    e = np.asarray(exp).astype(np.int32).reshape(-1, b.block)
+    nz = e != 0
+    span = (np.where(nz, e, 0).max(1) - np.where(nz, e, 255).min(1) + 1)
+    exp_used = int(np.sum(nz.any(1) & (span >= 1 << b.delta_width)))
+    assert lo_used >= 20 and exp_used == 1
+    lo_cap = min(n, max(4, int(np.ceil(n * b.exc_frac))))
+    exp_cap = packing.exception_capacity(n // b.block, b.exc_frac)
+    c = obs.snapshot()["counters"]
+    assert c["sync_delta_exceptions_total"] == {
+        "plane=lo": lo_used, "plane=exp": exp_used}
+    assert c["sync_delta_exception_slots_total"] == {
+        "plane=lo": lo_cap, "plane=exp": exp_cap}
+
+
 def test_p2p_compressor_spans_and_histograms():
     from repro.p2p.engine import Compressor
 

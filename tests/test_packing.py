@@ -104,3 +104,22 @@ def test_jit_static_shapes():
     m1, m2 = enc(x1), enc(x2)
     assert m1.lo.shape == m2.lo.shape
     assert m1.exp.payload.shape == m2.exp.payload.shape
+
+
+@pytest.mark.parametrize("size_of", [lambda n: 1, lambda n: max(1, n // 50),
+                                     lambda n: n],
+                         ids=["size1", "size_n_50", "size_n"])
+@pytest.mark.parametrize("n,density", [(32 * 333, 0.0), (32 * 333, 0.005),
+                                       (32 * 333, 0.1), (32 * 333, 1.0),
+                                       (32, 0.5)],
+                         ids=["empty", "sparse", "dense", "full", "one_word"])
+def test_exception_indices_equal_nonzero(n, density, size_of):
+    """The two-level exception index is ``jnp.nonzero`` exactly: the same
+    first ``size`` indices (truncation included), the same fill, int32."""
+    mask = jnp.asarray(np.random.default_rng(n + int(density * 1000))
+                       .random(n) < density)
+    size = size_of(n)
+    got = packing.exception_indices(mask, size=size, fill=n)
+    (want,) = jnp.nonzero(mask, size=size, fill_value=n)
+    assert got.dtype == jnp.int32 and got.shape == (size,)
+    assert np.array_equal(np.asarray(got), np.asarray(want))

@@ -256,6 +256,70 @@ def test_pack_delta_plane_exceptions_exact():
     assert np.array_equal(np.asarray(packing.unpack_delta_plane(p)), vals)
 
 
+def _lo_delta(new, base):
+    lay = codec.LAYOUTS[jnp.dtype(new.dtype).name]
+    _, lo = codec.split_bits(codec.xor_bits(new, base), lay)
+    return lo.astype(jnp.uint32)
+
+
+@pytest.mark.parametrize("case,lo_width,exc_frac,overflow", [
+    ("warm", 2, 0.2, 0),    # many exceptions, within capacity
+    ("warm", 2, 0.02, 1),   # list truncated at capacity
+    ("cold", 1, 0.02, 1),   # uncorrelated versions
+])
+def test_pack_delta_plane_exceptions_match_nonzero(case, lo_width, exc_frac,
+                                                   overflow):
+    """The lo plane's exception list is the one ``jnp.nonzero`` builds:
+    same indices, raw values and overflow flag, on warm and cold deltas."""
+    n = 5000  # ragged: pads to the 32-element group
+    if case == "warm":
+        new, base = warm_pair("bfloat16", n)
+    else:
+        new, base = (random_bits("bfloat16", n, seed=6),
+                     random_bits("bfloat16", n, seed=7))
+    vals = _lo_delta(new, base)
+    p = packing.pack_delta_plane(vals, lo_width, exc_frac=exc_frac)
+
+    v = np.pad(np.asarray(vals), (0, (-n) % packing.GROUP))
+    bad = v > (1 << lo_width) - 1
+    cap = min(n, max(4, int(np.ceil(n * exc_frac))))
+    (idx,) = jnp.nonzero(jnp.asarray(bad), size=cap, fill_value=v.size)
+    idx = np.asarray(idx)
+    raw = np.where(idx < v.size, v[np.minimum(idx, v.size - 1)], 0)
+    assert int(bad.sum() > cap) == overflow
+    assert np.array_equal(np.asarray(p.exc_idx), idx)
+    assert np.array_equal(np.asarray(p.exc_raw), raw)
+    assert int(p.overflow) == overflow
+
+
+def _scatter_add_updates(fn, *args) -> list:
+    """Update counts of every scatter-add in ``fn``'s jaxpr, nested too."""
+    def walk(jaxpr):
+        out = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scatter-add":
+                out.append(eqn.invars[2].aval.size)
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    out += walk(inner)
+        return out
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def test_exception_indices_scatter_is_per_word():
+    """The exception index scatters at most one update per 32-element word
+    (``jnp.nonzero`` scatters one per element, the control)."""
+    n, size = 32 * 512, 328
+    mask = jnp.zeros(n, bool)
+    helper = _scatter_add_updates(
+        lambda m: packing.exception_indices(m, size=size, fill=n), mask)
+    control = _scatter_add_updates(
+        lambda m: jnp.nonzero(m, size=size, fill_value=n), mask)
+    assert helper and max(helper) <= n // packing.GROUP, helper
+    assert max(control) == n
+
+
 def test_choose_delta_widths_warm_vs_cold():
     new, base = warm_pair("bfloat16", 1 << 15, flip_bits=2)
     w, wl = calibrate.choose_delta_widths(new, base)
