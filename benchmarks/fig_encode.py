@@ -134,8 +134,7 @@ def run(k: int = 8, smoke: bool = False):
             def unfused(v):
                 exp, lo = codec.split_planes(v)
                 lo_pl = packing.bitplane_pack(
-                    packing._pad_to(lo.astype(jnp.uint32), packing.GROUP,
-                                    "zero"), lay.lo_bits)
+                    packing._pad_to(lo, packing.GROUP, "zero"), lay.lo_bits)
                 pk = packing.pack_exponents(exp, width=width)
                 return {"lo": lo_pl, "payload": pk.payload, "bases": pk.bases,
                         "exc_idx": pk.exc_idx, "exc_raw": pk.exc_raw,

@@ -16,6 +16,7 @@ including NaN payloads and infinities): ``merge(split(x)) == x`` bitwise.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -148,6 +149,11 @@ def concat_bits(parts: list) -> jax.Array:
     weight-sync buckets)."""
     if len(parts) == 1:
         return parts[0]
+    return _concat_bits(tuple(parts))
+
+
+@jax.jit  # one program: op by op, each part's uint copy would be a buffer
+def _concat_bits(parts: tuple) -> jax.Array:
     lay = layout_of(parts[0].dtype)
     u = lay.uint_dtype
     bits = jnp.concatenate(
@@ -155,6 +161,7 @@ def concat_bits(parts: list) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, lay.dtype)
 
 
+@partial(jax.jit, static_argnames=("lo", "hi"))  # no whole-array uint copy
 def slice_bits(x: jax.Array, lo: int, hi: int) -> jax.Array:
     """``x[lo:hi]`` for a flat float array, in the uint domain (XLA's float
     slice may quiet signaling-NaN payloads, like its concatenate; the

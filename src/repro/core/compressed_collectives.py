@@ -197,9 +197,7 @@ def _encode_chunks(x2d: jax.Array, *, width: int, block: int, exc_frac: float,
     def enc(row):
         exp, lo = codec.split_planes(row)
         lo_planes = packing.bitplane_pack(
-            packing._pad_to(lo.astype(jnp.uint32), packing.GROUP, "zero"),
-            lay.lo_bits,
-        )
+            packing._pad_to(lo, packing.GROUP, "zero"), lay.lo_bits)
         pk = packing.pack_exponents(exp, width=width, block=block, exc_frac=exc_frac)
         return {
             "lo": lo_planes,
@@ -230,7 +228,7 @@ def _decode_chunks(wire: dict, *, dtype, n: int, width: int, block: int):
             exp_bits=lay.exp_bits,
         )
         exp = packing.unpack_exponents(pk)
-        lo = packing.bitplane_unpack(w["lo"], lay.lo_bits)[:n].astype(lay.uint_dtype)
+        lo = packing.bitplane_unpack(w["lo"], lay.lo_bits, lay.uint_dtype)[:n]
         return codec.merge_planes(exp, lo, lay.dtype, (n,))
 
     # one chunk at a time: a vmapped decode keeps every chunk's per-plane
@@ -310,10 +308,11 @@ def _decode_reduce_chunks(
         pos = (exc_idx[:, None] * block
                + jnp.arange(block, dtype=jnp.int32)[None, :]).reshape(-1)
         saved = acc[jnp.minimum(pos, n - 1)]
-        grp = (jnp.minimum(exc_idx, nb - 1)[:, None] * gpb
-               + jnp.arange(gpb, dtype=jnp.int32)[None, :]).reshape(-1)
-        lo_vals = packing.bitplane_unpack(
-            w["lo"][grp], lay.lo_bits).astype(lay.uint_dtype)
+        wpb = gpb * lay.lo_bits  # lo words per block
+        words = (jnp.minimum(exc_idx, nb - 1)[:, None] * wpb
+                 + jnp.arange(wpb, dtype=jnp.int32)[None, :]).reshape(-1)
+        lo_vals = packing.bitplane_unpack(w["lo"][words], lay.lo_bits,
+                                          lay.uint_dtype)
         exact = codec.merge_planes(
             w["exc_raw"].reshape(-1), lo_vals, lay.dtype, (cap * block,)
         ).astype(jnp.float32)

@@ -17,8 +17,11 @@ Losslessness is unconditional:
     never silently corrupted.
 
 Packing itself is *bit-plane* packing: groups of 32 residuals map to ``W``
-uint32 words (one word per bit-plane).  This is a pure-VPU transform — the
-Pallas kernel in ``kernels/bitpack.py`` implements the identical layout.
+uint32 words (one word per bit-plane), carried flat in group-major order
+(word ``g * W + b``).  The packer sums each plane's bits into words by a
+bf16 matmul against powers of two; the Pallas kernel in
+``kernels/bitpack.py`` implements the identical layout on the VPU, tiled as
+``(n // 32, W)``.
 """
 from __future__ import annotations
 
@@ -38,30 +41,105 @@ GROUP = 32  # residuals per packed group (one uint32 word per bit-plane)
 # Bit-plane pack / unpack (pure jnp reference; kernels/bitpack.py mirrors it)
 # ---------------------------------------------------------------------------
 
-def bitplane_pack(vals: jax.Array, width: int) -> jax.Array:
-    """Pack ``vals`` (uint32 (n,), n % 32 == 0, each < 2**width) into
-    bit-planes: returns uint32 (n // 32, width); word ``[g, b]`` holds bit
-    ``b`` of the 32 values of group ``g`` (value ``i`` at bit position ``i``).
-    """
-    assert vals.shape[0] % GROUP == 0, vals.shape
-    g = vals.reshape(-1, GROUP).astype(jnp.uint32)
-    pos = jnp.arange(GROUP, dtype=jnp.uint32)
-    planes = [
-        jnp.sum(((g >> jnp.uint32(b)) & jnp.uint32(1)) << pos, axis=-1, dtype=jnp.uint32)
-        for b in range(width)
-    ]
-    return jnp.stack(planes, axis=-1)
+_SLICE = 1 << 20  # elements per step of the packer's and unpacker's loops
+_WORD_ROW = 512  # values per row of the word-packing matmuls
 
 
-def bitplane_unpack(packed: jax.Array, width: int) -> jax.Array:
-    """Inverse of :func:`bitplane_pack`; returns uint32 (n,)."""
+def _plane_weights(width: int, b: int) -> np.ndarray:
+    """(512, 32 * width) bf16-exact powers of two for one matmul over a row
+    of 512 values: bit ``b`` of value ``i`` lands in word ``(i // 32) *
+    width + b`` of the row's 16 groups, at bit ``i % 32``.  Column ``w``
+    (``16 * width + w``) sums the low (high) 16 bits of word ``w``: under
+    2**16, exact in f32."""
+    lane = np.arange(_WORD_ROW)
+    words = _WORD_ROW // GROUP * width
+    wts = np.zeros((_WORD_ROW, 2 * words), np.float32)
+    wts[lane, (lane % GROUP) // 16 * words + lane // GROUP * width + b] = (
+        2.0 ** (lane % 16))
+    return wts
+
+
+def _pack_groups(v: jax.Array, width: int) -> jax.Array:
+    """Pack lane-dense rows of 512 values by one bf16 matmul per bit-plane
+    (each plane's weights fill only its own words, so the sum is exact)."""
+    n = v.shape[0]
+    rows = jnp.pad(v, (0, (-n) % _WORD_ROW)).reshape(-1, _WORD_ROW)
+    halves = sum(
+        jnp.dot(((rows >> b) & 1).astype(jnp.bfloat16),
+                jnp.asarray(_plane_weights(width, b), jnp.bfloat16),
+                preferred_element_type=jnp.float32)
+        for b in range(width)).astype(jnp.uint32)
+    words = _WORD_ROW // GROUP * width
+    flat = (halves[:, :words] | (halves[:, words:] << 16)).reshape(-1)
+    return flat[: n // GROUP * width]
+
+
+def _unpack_groups(p: jax.Array, width: int, dtype) -> jax.Array:
+    p = p.reshape(-1, width)
     pos = jnp.arange(GROUP, dtype=jnp.uint32)
-    vals = jnp.zeros((packed.shape[0], GROUP), jnp.uint32)
+    vals = jnp.zeros((p.shape[0], GROUP), jnp.uint32)
     for b in range(width):
         vals = vals | (
-            ((packed[:, b : b + 1] >> pos) & jnp.uint32(1)) << jnp.uint32(b)
+            ((p[:, b : b + 1] >> pos) & jnp.uint32(1)) << jnp.uint32(b)
         )
-    return vals.reshape(-1)
+    return vals.astype(dtype).reshape(-1)
+
+
+def _sliced(fn, x: jax.Array, step_in: int, step_out: int, n_out: int,
+            dtype) -> jax.Array:
+    """``fn`` over ``x`` in slices of ``step_in`` elements, each giving
+    ``step_out`` elements of the flat output: a loop over the whole slices,
+    then the ragged tail, so every temporary scales with the slice."""
+    full, tail = divmod(x.shape[0], step_in)
+    if full == 0:
+        return fn(x)
+    out = jnp.zeros((n_out,), dtype)
+
+    def part(x):
+        # the barrier keeps XLA from moving ``fn``'s reshapes across the
+        # slice, onto the whole of ``x``
+        return fn(jax.lax.optimization_barrier(x))
+
+    def body(i, out):
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, part(jax.lax.dynamic_slice_in_dim(x, i * step_in, step_in)),
+            i * step_out, 0)
+
+    out = jax.lax.fori_loop(0, full, body, out)
+    if tail:
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, part(x[full * step_in:]), full * step_out, 0)
+    return out
+
+
+@partial(jax.jit, static_argnames=("width",))
+def bitplane_pack(vals: jax.Array, width: int) -> jax.Array:
+    """Pack ``vals`` (uint (n,), n % 32 == 0, each < 2**width) into
+    bit-planes: returns flat uint32 (n // 32 * width,); word ``g * width +
+    b`` holds bit ``b`` of the 32 values of group ``g`` (value ``i`` at bit
+    position ``i``).
+
+    The wire is flat so that no bucket-sized array has a minor dimension
+    narrower than the chip's 128 lanes (a ``(n // 32, 32)`` uint32 array
+    takes 16 bytes per element in TPU memory): the values are packed
+    ``_SLICE`` at a time, each slice as lane-dense rows of 512 by
+    :func:`_pack_groups`' matmuls, into the flat output."""
+    n = vals.shape[0]
+    assert n % GROUP == 0, vals.shape
+    return _sliced(lambda v: _pack_groups(v, width), vals, _SLICE,
+                   _SLICE // GROUP * width, n // GROUP * width, jnp.uint32)
+
+
+@partial(jax.jit, static_argnames=("width", "dtype"))
+def bitplane_unpack(packed: jax.Array, width: int,
+                    dtype=jnp.uint32) -> jax.Array:
+    """Inverse of :func:`bitplane_pack` (flat words in); returns ``dtype``
+    (n,) (values above ``dtype``'s range keep their low bits)."""
+    assert packed.ndim == 1 and packed.shape[0] % width == 0, (
+        packed.shape, width)
+    return _sliced(lambda q: _unpack_groups(q, width, dtype), packed,
+                   _SLICE // GROUP * width, _SLICE,
+                   packed.shape[0] // width * GROUP, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +153,7 @@ def bitplane_unpack(packed: jax.Array, width: int) -> jax.Array:
 )
 @dataclasses.dataclass(frozen=True)
 class PackedPlane:
-    payload: jax.Array  # uint32 (n_pad // 32, width) bit-planes of residuals
+    payload: jax.Array  # uint32 (n_pad // 32 * width,) bit-planes of residuals
     bases: jax.Array  # uint8  (n_blocks,) per-block minimum exponent
     exc_idx: jax.Array  # int32  (E,) exception block ids (n_blocks = unused)
     exc_raw: jax.Array  # uint8  (E, block) raw exponents of exception blocks
@@ -135,35 +213,11 @@ def pack_exponents(
     as just another symbol; the static codec needs the explicit escape)."""
     assert block % GROUP == 0
     n = exp.shape[0]
-    expp = _pad_to(exp, block)
-    blocks = expp.reshape(-1, block)
-    nb = blocks.shape[0]
-    nz = blocks != 0
-    big = jnp.where(nz, blocks, jnp.uint8(255))
-    base = jnp.min(big, axis=-1)  # 255 if block is all-zero
-    base = jnp.where(jnp.any(nz, axis=-1), base, jnp.uint8(1))
-    mx = jnp.max(jnp.where(nz, blocks, jnp.uint8(0)), axis=-1)
-    rng = mx.astype(jnp.int32) - base.astype(jnp.int32) + 1  # max code value
-    ok = rng < (1 << width)
-
-    resid = jnp.where(
-        nz,
-        blocks.astype(jnp.int32) - base[:, None].astype(jnp.int32) + 1,
-        0,
-    ).astype(jnp.uint32)
-    resid = jnp.minimum(resid, jnp.uint32((1 << width) - 1))  # exc blocks: payload is garbage, restored from exc_raw
-    payload = bitplane_pack(resid.reshape(-1), width)
-
-    cap = exception_capacity(nb, exc_frac)
-    bad = ~ok
-    n_bad = jnp.sum(bad.astype(jnp.int32))
-    (exc_idx,) = jnp.nonzero(bad, size=cap, fill_value=nb)
-    exc_idx = exc_idx.astype(jnp.int32)
-    exc_raw = blocks[jnp.minimum(exc_idx, nb - 1)]
-    exc_raw = jnp.where((exc_idx < nb)[:, None], exc_raw, 0)
-    overflow = (n_bad > cap).astype(jnp.int32)
+    cap = exception_capacity(-(-n // block), exc_frac)
+    resid, base, exc_idx, exc_raw, overflow = _block_codes(
+        exp, width=width, block=block, cap=cap)
     return PackedPlane(
-        payload=payload,
+        payload=bitplane_pack(resid, width),
         bases=base,
         exc_idx=exc_idx,
         exc_raw=exc_raw,
@@ -175,16 +229,56 @@ def pack_exponents(
     )
 
 
+# The codec's steps around the packer and unpacker are programs of their
+# own: run op by op, each intermediate of a bucket-sized plane would be a
+# bucket-sized buffer, and the host runs ahead of the chip.
+
+@partial(jax.jit, static_argnames=("width", "block", "cap"))
+def _block_codes(exp: jax.Array, *, width: int, block: int, cap: int):
+    """:func:`pack_exponents`' codes (flat uint8), block bases and
+    exception list."""
+    blocks = _pad_to(exp, block).reshape(-1, block)
+    nb = blocks.shape[0]
+    nz = blocks != 0
+    big = jnp.where(nz, blocks, jnp.uint8(255))
+    base = jnp.min(big, axis=-1)  # 255 if block is all-zero
+    base = jnp.where(jnp.any(nz, axis=-1), base, jnp.uint8(1))
+    mx = jnp.max(jnp.where(nz, blocks, jnp.uint8(0)), axis=-1)
+    rng = mx.astype(jnp.int32) - base.astype(jnp.int32) + 1  # max code value
+    ok = rng < (1 << width)
+
+    # a nonzero exponent is at least its block's base, so every code fits
+    # in uint8: the residuals stay one byte per element
+    resid = jnp.where(nz, blocks - base[:, None] + jnp.uint8(1), jnp.uint8(0))
+    if width < 8:  # exc blocks: payload is garbage, restored from exc_raw
+        resid = jnp.minimum(resid, jnp.uint8((1 << width) - 1))
+
+    bad = ~ok
+    n_bad = jnp.sum(bad.astype(jnp.int32))
+    (exc_idx,) = jnp.nonzero(bad, size=cap, fill_value=nb)
+    exc_idx = exc_idx.astype(jnp.int32)
+    exc_raw = blocks[jnp.minimum(exc_idx, nb - 1)]
+    exc_raw = jnp.where((exc_idx < nb)[:, None], exc_raw, 0)
+    overflow = (n_bad > cap).astype(jnp.int32)
+    return resid.reshape(-1), base, exc_idx, exc_raw, overflow
+
+
 def unpack_exponents(p: PackedPlane) -> jax.Array:
     """Exact inverse of :func:`pack_exponents` (when ``overflow == 0``)."""
-    resid = bitplane_unpack(p.payload, p.width).reshape(p.n_blocks, p.block)
-    blocks = jnp.where(
-        resid == 0,
-        jnp.uint32(0),
-        resid + p.bases[:, None].astype(jnp.uint32) - 1,
-    ).astype(jnp.uint8)
-    blocks = blocks.at[p.exc_idx].set(p.exc_raw, mode="drop")
-    return blocks.reshape(-1)[: p.n]
+    return _block_exponents(bitplane_unpack(p.payload, p.width, jnp.uint8),
+                            p.bases, p.exc_idx, p.exc_raw, n=p.n,
+                            block=p.block)
+
+
+@partial(jax.jit, static_argnames=("n", "block"))
+def _block_exponents(resid, bases, exc_idx, exc_raw, *, n: int, block: int):
+    # uint8 arithmetic wraps as the uint8 result of the uint32 sum would
+    resid = resid.reshape(-1, block)
+    blocks = jnp.where(resid == 0, jnp.uint8(0),
+                       resid + bases[:, None].astype(jnp.uint8)
+                       - jnp.uint8(1))
+    blocks = blocks.at[exc_idx].set(exc_raw, mode="drop")
+    return blocks.reshape(-1)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +293,7 @@ def unpack_exponents(p: PackedPlane) -> jax.Array:
 )
 @dataclasses.dataclass(frozen=True)
 class CompressedMessage:
-    lo: jax.Array  # uint32 (n_pad // 32, lo_bits) bit-planes of sign|mantissa
+    lo: jax.Array  # uint32 (n_pad // 32 * lo_bits,) bit-planes of sign|mantissa
     exp: PackedPlane
     dtype_name: str
     shape: tuple
@@ -221,6 +315,11 @@ class CompressedMessage:
 
     def ratio(self) -> float:
         return self.wire_bytes() / self.raw_bytes()
+
+
+# programs of their own, as the steps around the packer (above)
+_split_planes = jax.jit(codec.split_planes)
+_merge_planes = jax.jit(codec.merge_planes, static_argnums=(2, 3))
 
 
 def encode_message(
@@ -248,9 +347,8 @@ def encode_message(
         return CompressedMessage(
             lo=w["lo"], exp=packed, dtype_name=lay.name, shape=tuple(x.shape)
         )
-    exp, lo = codec.split_planes(x)
-    lo32 = _pad_to(lo.astype(jnp.uint32), GROUP, pad_mode="zero")
-    lo_planes = bitplane_pack(lo32, lay.lo_bits)
+    exp, lo = _split_planes(x)
+    lo_planes = bitplane_pack(_pad_to(lo, GROUP, pad_mode="zero"), lay.lo_bits)
     packed = pack_exponents(exp, width=width, block=block, exc_frac=exc_frac)
     return CompressedMessage(
         lo=lo_planes, exp=packed, dtype_name=lay.name, shape=tuple(x.shape)
@@ -260,9 +358,9 @@ def encode_message(
 def decode_message(m: CompressedMessage) -> jax.Array:
     lay = codec.LAYOUTS[m.dtype_name]
     n = int(np.prod(m.shape)) if m.shape else 1
-    lo = bitplane_unpack(m.lo, lay.lo_bits)[:n].astype(lay.uint_dtype)
+    lo = bitplane_unpack(m.lo, lay.lo_bits, lay.uint_dtype)[:n]
     exp = unpack_exponents(m.exp)
-    return codec.merge_planes(exp, lo, lay.dtype, m.shape)
+    return _merge_planes(exp, lo, lay.dtype, m.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -292,25 +390,12 @@ def decode_message(m: CompressedMessage) -> jax.Array:
 class DeltaPlane:
     """Width-packed lo-delta plane with element-granular exact exceptions."""
 
-    payload: jax.Array  # uint32 (n_pad // 32, width) bit-planes
+    payload: jax.Array  # uint32 (n_pad // 32 * width,) bit-planes
     exc_idx: jax.Array  # int32 (E,) element indices (n_pad = unused slot)
     exc_raw: jax.Array  # uint32 (E,) raw lo values of exception elements
     overflow: jax.Array  # int32 scalar: 1 if exceptions overflowed capacity
     width: int
     n: int  # original element count (pre-padding)
-
-
-_WORD_ROW = 512  # mask elements per row of the word-packing matmul
-
-
-def _word_weights() -> np.ndarray:
-    """(512, 32) bf16-exact powers of two: column ``w`` (``16 + w``) sums
-    the low (high) 16 bits of the row's word ``w``."""
-    lane = np.arange(_WORD_ROW)
-    half = (lane % GROUP) // 16
-    wts = np.zeros((_WORD_ROW, 2 * _WORD_ROW // GROUP), np.float32)
-    wts[lane, half * (_WORD_ROW // GROUP) + lane // GROUP] = 2.0 ** (lane % 16)
-    return wts
 
 
 @partial(jax.jit, static_argnames=("size", "fill"))
@@ -321,9 +406,10 @@ def exception_indices(mask: jax.Array, *, size: int, fill: int) -> jax.Array:
     ``jnp.nonzero`` scatters one update per element of ``mask``.  Here the
     index is found in two levels.  The mask is packed into 32-element words
     (bit ``i`` of word ``r`` is element ``32 r + i``, as in
-    :func:`bitplane_pack`) by one matmul against powers of two: each half
-    word sums to under 2**16, exact in f32, and the mask is read in
-    lane-dense rows.  The word that holds the ``k``-th true element comes
+    :func:`bitplane_pack`) by one matmul against :func:`bitplane_pack`'s
+    powers of two over lane-dense rows of 512 (the bool mask straight into
+    the matmul: through ``_pack_groups``' uint8 shifts, a described v5e
+    compile needs over 2 bytes of temp per element).  The word that holds the ``k``-th true element comes
     from ``jnp.nonzero``'s own cumsum-of-bincount trick over the words'
     counts (one scatter update per word); its rank inside the word from the
     slot where that word's run of slots starts, and the bit from a popcount
@@ -333,7 +419,7 @@ def exception_indices(mask: jax.Array, *, size: int, fill: int) -> jax.Array:
     assert n % GROUP == 0, n
     m = jnp.pad(mask, (0, (-n) % _WORD_ROW)).reshape(-1, _WORD_ROW)
     halves = jnp.dot(m.astype(jnp.bfloat16),
-                     jnp.asarray(_word_weights(), jnp.bfloat16),
+                     jnp.asarray(_plane_weights(1, 0), jnp.bfloat16),
                      preferred_element_type=jnp.float32).astype(jnp.uint32)
     nw = _WORD_ROW // GROUP
     words = (halves[:, :nw] | (halves[:, nw:] << 16)).reshape(-1)
@@ -357,7 +443,7 @@ def exception_indices(mask: jax.Array, *, size: int, fill: int) -> jax.Array:
 
 def pack_delta_plane(vals: jax.Array, width: int, *,
                      exc_frac: float = 0.02) -> DeltaPlane:
-    """Pack a uint32 lo-delta stream at ``width`` bits/element.
+    """Pack an unsigned lo-delta stream at ``width`` bits/element.
 
     Elements that do not fit (the carry tail) escape exactly through a
     static-capacity exception list of ``max(4, exc_frac * n)`` entries;
@@ -365,27 +451,45 @@ def pack_delta_plane(vals: jax.Array, width: int, *,
     caller must fall back to a full send)."""
     assert width >= 1, width
     n = vals.shape[0]
-    v = _pad_to(vals.astype(jnp.uint32), GROUP, pad_mode="zero")
-    mask = jnp.uint32((1 << width) - 1)
-    fits = v <= mask
-    payload = bitplane_pack(jnp.where(fits, v, jnp.uint32(0)), width)
+    v = _pad_to(vals, GROUP, pad_mode="zero")
+    kept, bad = _split_fits(v, width=width)
+    payload = bitplane_pack(kept, width)
     cap = min(n, max(4, int(np.ceil(n * exc_frac))))
-    bad = ~fits
-    n_bad = jnp.sum(bad.astype(jnp.int32))
     exc_idx = exception_indices(bad, size=cap, fill=v.shape[0])
-    exc_raw = v[jnp.minimum(exc_idx, v.shape[0] - 1)]
-    exc_raw = jnp.where(exc_idx < v.shape[0], exc_raw, 0)
-    overflow = (n_bad > cap).astype(jnp.int32)
+    exc_raw, overflow = _gather_exceptions(v, bad, exc_idx)
     return DeltaPlane(payload=payload, exc_idx=exc_idx, exc_raw=exc_raw,
                       overflow=overflow, width=width, n=n)
 
 
-def unpack_delta_plane(p: DeltaPlane) -> jax.Array:
+@partial(jax.jit, static_argnames=("width",))
+def _split_fits(v: jax.Array, *, width: int):
+    """The values that fit ``width`` bits (the others zeroed), and the
+    mask of those that do not."""
+    dt = v.dtype.type
+    fits = v <= dt(min((1 << width) - 1, jnp.iinfo(v.dtype).max))
+    return jnp.where(fits, v, dt(0)), ~fits
+
+
+@jax.jit
+def _gather_exceptions(v: jax.Array, bad: jax.Array, exc_idx: jax.Array):
+    """The exception list's raw values, and whether it overflowed."""
+    exc_raw = v[jnp.minimum(exc_idx, v.shape[0] - 1)].astype(jnp.uint32)
+    exc_raw = jnp.where(exc_idx < v.shape[0], exc_raw, jnp.uint32(0))
+    overflow = (jnp.sum(bad.astype(jnp.int32)) > exc_idx.shape[0])
+    return exc_raw, overflow.astype(jnp.int32)
+
+
+def unpack_delta_plane(p: DeltaPlane, dtype=jnp.uint32) -> jax.Array:
     """Exact inverse of :func:`pack_delta_plane` (when ``overflow == 0``).
-    Returns uint32 (n,)."""
-    vals = bitplane_unpack(p.payload, p.width)
-    vals = vals.at[p.exc_idx].set(p.exc_raw, mode="drop")
-    return vals[: p.n]
+    Returns ``dtype`` (n,): the packed stream's own dtype, or wider."""
+    return _scatter_exceptions(bitplane_unpack(p.payload, p.width, dtype),
+                               p.exc_idx, p.exc_raw, n=p.n)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _scatter_exceptions(vals, exc_idx, exc_raw, *, n: int):
+    vals = vals.at[exc_idx].set(exc_raw.astype(vals.dtype), mode="drop")
+    return vals[:n]
 
 
 @partial(
@@ -440,11 +544,9 @@ def encode_delta(
     Bit-exact through :func:`decode_delta` whenever ``overflow == 0`` —
     including NaN payloads, infinities and subnormals in either operand."""
     lay = codec.layout_of(x.dtype)
-    exp, lo = codec.split_bits(codec.xor_bits(x.reshape(-1),
-                                              base.reshape(-1)), lay)
+    exp, lo = _xor_split(x, base)
     packed = pack_exponents(exp, width=width, block=block, exc_frac=exc_frac)
-    lo_plane = pack_delta_plane(lo.astype(jnp.uint32), lo_width,
-                                exc_frac=exc_frac)
+    lo_plane = pack_delta_plane(lo, lo_width, exc_frac=exc_frac)
     return DeltaMessage(lo=lo_plane, exp=packed, dtype_name=lay.name,
                         shape=tuple(x.shape))
 
@@ -454,8 +556,25 @@ def decode_delta(m: DeltaMessage, base: jax.Array) -> jax.Array:
     (the sync protocol's invariant — version fencing guarantees it)."""
     lay = codec.LAYOUTS[m.dtype_name]
     n = int(np.prod(m.shape)) if m.shape else 1
-    lo = unpack_delta_plane(m.lo)[:n].astype(lay.uint_dtype)
+    lo = unpack_delta_plane(m.lo, lay.uint_dtype)[:n]
     exp = unpack_exponents(m.exp)
+    return _merge_xor(exp, lo, base, m.shape)
+
+
+@jax.jit
+def _xor_split(x: jax.Array, base: jax.Array):
+    """The delta's planes in one program (no bucket-sized intermediates)."""
+    return codec.split_bits(codec.xor_bits(x.reshape(-1), base.reshape(-1)),
+                            codec.layout_of(x.dtype))
+
+
+@partial(jax.jit, static_argnames=("shape",))
+def _merge_xor(exp: jax.Array, lo: jax.Array, base: jax.Array,
+               shape: tuple) -> jax.Array:
+    """The delta's planes merged and XORed back onto ``base``, in one
+    program."""
+    lay = codec.layout_of(base.dtype)
+    n = int(np.prod(shape)) if shape else 1
     bits = codec.merge_bits(exp, lo, lay, n) ^ jax.lax.bitcast_convert_type(
         base.reshape(-1), lay.uint_dtype)
-    return jax.lax.bitcast_convert_type(bits, lay.dtype).reshape(m.shape)
+    return jax.lax.bitcast_convert_type(bits, lay.dtype).reshape(shape)

@@ -110,8 +110,7 @@ def split_send(
     # Stage A (early transmission): the lo plane is final after the split —
     # pack to lo_bits and put it on the wire with NO dependence on stage B.
     lo_planes = packing.bitplane_pack(
-        packing._pad_to(lo.astype(jnp.uint32), packing.GROUP, "zero"), lay.lo_bits
-    )
+        packing._pad_to(lo, packing.GROUP, "zero"), lay.lo_bits)
     lo_recv = _permute(lo_planes, axis_name, perm)
 
     # Stage B (overlapped): block-pack the exponent plane, then transfer.
@@ -151,9 +150,8 @@ def split_send(
         n=xf.shape[0], exp_bits=lay.exp_bits,
     )
     exp_out = packing.unpack_exponents(rpk)
-    lo_out = packing.bitplane_unpack(lo_recv, lay.lo_bits)[: xf.shape[0]].astype(
-        lay.uint_dtype
-    )
+    lo_out = packing.bitplane_unpack(lo_recv, lay.lo_bits,
+                                     lay.uint_dtype)[: xf.shape[0]]
     out = codec.merge_planes(exp_out, lo_out, lay.dtype, (xf.shape[0],))
     if reduce_into is not None:  # unfused reducing receiver (A/B baseline)
         acc = reduce_into.reshape(-1).astype(jnp.float32)
@@ -195,9 +193,7 @@ def encode_send(
     else:
         exp, lo = codec.split_planes(xf)
         lo_planes = packing.bitplane_pack(
-            packing._pad_to(lo.astype(jnp.uint32), packing.GROUP, "zero"),
-            lay.lo_bits,
-        )
+            packing._pad_to(lo, packing.GROUP, "zero"), lay.lo_bits)
         pk = packing.pack_exponents(exp, width=width, block=block,
                                     exc_frac=exc_frac)
         wire = {
@@ -218,9 +214,8 @@ def encode_send(
         block=block, n=xf.shape[0], exp_bits=lay.exp_bits,
     )
     exp_out = packing.unpack_exponents(rpk)
-    lo_out = packing.bitplane_unpack(lo_recv, lay.lo_bits)[: xf.shape[0]].astype(
-        lay.uint_dtype
-    )
+    lo_out = packing.bitplane_unpack(lo_recv, lay.lo_bits,
+                                     lay.uint_dtype)[: xf.shape[0]]
     out = codec.merge_planes(exp_out, lo_out, lay.dtype, (xf.shape[0],))
     return out[:n].reshape(x.shape), recv["overflow"]
 

@@ -49,10 +49,11 @@ GROUP = packing.GROUP
 
 def pack(vals, width: int, *, use_pallas: bool | None = None,
          interpret: bool | None = None):
+    """``packing.bitplane_pack``'s flat words."""
     use_pallas, interpret = resolve_use_pallas(use_pallas), resolve_interpret(interpret)
     if use_pallas:
         if vals.shape[0] % (32 * _bitpack.TILE_G) == 0:
-            return _bitpack.pack(vals, width, interpret=interpret)
+            return _bitpack.pack(vals, width, interpret=interpret).reshape(-1)
         record_fallback("pack", f"n={vals.shape[0]} not a "
                                 f"{32 * _bitpack.TILE_G} multiple")
     return _ref.pack(vals, width)
@@ -60,11 +61,13 @@ def pack(vals, width: int, *, use_pallas: bool | None = None,
 
 def unpack(packed, width: int, *, use_pallas: bool | None = None,
            interpret: bool | None = None):
+    """``packing.bitplane_unpack`` of flat words."""
     use_pallas, interpret = resolve_use_pallas(use_pallas), resolve_interpret(interpret)
     if use_pallas:
-        if packed.shape[0] % _bitpack.TILE_G == 0:
-            return _bitpack.unpack(packed, width, interpret=interpret)
-        record_fallback("unpack", f"n_groups={packed.shape[0]} not a "
+        groups = packed.reshape(-1, width)
+        if groups.shape[0] % _bitpack.TILE_G == 0:
+            return _bitpack.unpack(groups, width, interpret=interpret)
+        record_fallback("unpack", f"n_groups={groups.shape[0]} not a "
                                   f"{_bitpack.TILE_G} multiple")
     return _ref.unpack(packed, width)
 
@@ -84,11 +87,14 @@ def split_with_stats(x, block: int = 512, *, use_pallas: bool | None = None,
 def decode_reduce(payload, lo_planes, group_bases, acc, dtype_name: str,
                   width: int, *, use_pallas: bool | None = None,
                   interpret: bool | None = None):
+    """Decode the flat wire words ``payload`` and ``lo_planes`` and add
+    them into ``acc``."""
     use_pallas, interpret = resolve_use_pallas(use_pallas), resolve_interpret(interpret)
     if use_pallas:  # any n_groups: the kernel's last tile may be partial
+        lo_bits = codec.LAYOUTS[dtype_name].lo_bits
         return _decode_reduce.decode_reduce(
-            payload, lo_planes, group_bases, acc, dtype_name, width,
-            interpret=interpret,
+            payload.reshape(-1, width), lo_planes.reshape(-1, lo_bits),
+            group_bases, acc, dtype_name, width, interpret=interpret,
         )
     return _ref.decode_reduce(payload, lo_planes, group_bases, acc, dtype_name, width)
 
@@ -120,10 +126,11 @@ def _encode_planes(xf: jax.Array, width: int, block: int, use_pallas: bool,
                    interpret: bool):
     """Core plane encode of a flat block-multiple array.
 
-    Returns (payload (n//32, width), lo_planes (n//32, lo_bits), bases
-    uint32 (nb,), rng uint32 (nb,)).  The Pallas path pads to the kernel
-    tile (exponent-preserving pad) and slices — ragged-vs-tile never falls
-    back; ``use_pallas=False`` is the fused jnp reference."""
+    Returns (payload (n//32*width,), lo_planes (n//32*lo_bits,), bases
+    uint32 (nb,), rng uint32 (nb,)), the planes as flat words.  The Pallas
+    path pads to the kernel tile (exponent-preserving pad) and
+    slices — ragged-vs-tile never falls back; ``use_pallas=False`` is the
+    fused jnp reference."""
     lay = codec.layout_of(xf.dtype)
     n = xf.shape[0]
     assert n % block == 0, (n, block)
@@ -136,10 +143,9 @@ def _encode_planes(xf: jax.Array, width: int, block: int, use_pallas: bool,
             xf, jnp.broadcast_to(_edge_exp_pad(xf, lay), (n_tile - n,))])
     pay, lo, bases, rng = _encode_fused.encode_fused(
         xf, width, block, interpret=interpret)
-    if n_tile != n:
-        pay, lo = pay[: n // GROUP], lo[: n // GROUP]
-        bases, rng = bases[: n // block], rng[: n // block]
-    return pay, lo, bases, rng
+    pay = pay[: n // GROUP].reshape(-1)  # the kernel's tiles, as flat words
+    lo = lo[: n // GROUP].reshape(-1)
+    return pay, lo, bases[: n // block], rng[: n // block]
 
 
 def _exceptions_from(x_blocks: jax.Array, rng: jax.Array, lay, width: int,
@@ -173,8 +179,8 @@ def encode_fused(x: jax.Array, width: int, *, block: int = 512,
     ``codec.split_planes`` + ``packing.bitplane_pack(lo)`` +
     ``packing.pack_exponents(exp)`` (including both of its padding modes;
     see :func:`_edge_exp_pad`).  ``payload`` covers ``n`` padded to a block
-    multiple, ``lo`` covers ``n`` padded to a GROUP multiple, matching the
-    legacy shapes exactly.
+    multiple, ``lo`` covers ``n`` padded to a GROUP multiple, both as flat
+    words like the legacy composition's.
     """
     use_pallas, interpret = resolve_use_pallas(use_pallas), resolve_interpret(interpret)
     lay = codec.layout_of(x.dtype)
@@ -193,10 +199,12 @@ def encode_fused(x: jax.Array, width: int, *, block: int = 512,
     if use_pallas:
         pay, lo, bases, rng = _encode_fused.encode_fused(
             xe, width, block, interpret=interpret)
-        pay, bases, rng = pay[: n_blk // GROUP], bases[:nb], rng[:nb]
+        # the kernel's tiles, as flat words
+        pay, lo = pay[: n_blk // GROUP].reshape(-1), lo.reshape(-1)
+        bases, rng = bases[:nb], rng[:nb]
     else:
         pay, lo, bases, rng = _ref.encode_fused(xe, width, block)
-    lo = lo[: n_grp // GROUP]
+    lo = lo[: n_grp // GROUP * lay.lo_bits]
     cap = packing.exception_capacity(nb, exc_frac)
     exc_idx, exc_raw, overflow = _exceptions_from(
         xe[: n_blk].reshape(nb, block), rng, lay, width, cap)
@@ -231,8 +239,8 @@ def encode_fused_chunks(x2d: jax.Array, width: int, *, block: int = 512,
     gpc = chunk // GROUP
     pay, lo, bases, rng = _encode_planes(
         x2d.reshape(-1), width, block, use_pallas, interpret)
-    pay = pay.reshape(n_chunks, gpc, width)
-    lo = lo.reshape(n_chunks, gpc, lay.lo_bits)
+    pay = pay.reshape(n_chunks, gpc * width)
+    lo = lo.reshape(n_chunks, gpc * lay.lo_bits)
     bases = bases.reshape(n_chunks, nb_c)
     rng = rng.reshape(n_chunks, nb_c)
     cap = packing.exception_capacity(nb_c, exc_frac)

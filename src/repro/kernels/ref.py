@@ -31,9 +31,10 @@ def split_with_stats(x: jax.Array, block: int = 512):
 def encode_fused(x: jax.Array, width: int, block: int = 512):
     """One-pass jnp oracle of the fused split+pack kernel.
 
-    x float (n,), n % block == 0.  Returns (payload uint32 (n//32, width),
-    lo_planes uint32 (n//32, lo_bits), bases uint32 (n_blocks,), rng uint32
-    (n_blocks,)).  ``payload``/``bases`` are bit-identical to
+    x float (n,), n % block == 0.  Returns (payload uint32 (n//32*width,),
+    lo_planes uint32 (n//32*lo_bits,), bases uint32 (n_blocks,), rng uint32
+    (n_blocks,)), the planes as flat words (the kernel writes them as
+    ``(n//32, width)`` tiles).  ``payload``/``bases`` are bit-identical to
     ``packing.pack_exponents``'s wire fields (zero-escape, clamped exception
     payload), ``lo_planes`` to ``packing.bitplane_pack(lo, lo_bits)``, and
     ``rng`` is the max residual code value (``rng < 2**width`` iff the block
@@ -52,7 +53,7 @@ def encode_fused(x: jax.Array, width: int, block: int = 512):
     resid = jnp.where(nz, b - base[:, None] + jnp.uint32(1), jnp.uint32(0))
     resid = jnp.minimum(resid, jnp.uint32((1 << width) - 1))
     payload = packing.bitplane_pack(resid.reshape(-1), width)
-    lo_planes = packing.bitplane_pack(lo.astype(jnp.uint32), lay.lo_bits)
+    lo_planes = packing.bitplane_pack(lo, lay.lo_bits)
     return payload, lo_planes, base, rng
 
 
@@ -67,7 +68,7 @@ def decode_reduce(payload, lo_planes, group_bases, acc, dtype_name: str, width: 
     exp = jnp.where(
         r2 == 0, jnp.uint32(0), r2 + group_bases[:, None].astype(jnp.uint32) - 1
     ).reshape(-1).astype(jnp.uint8)
-    lo = packing.bitplane_unpack(lo_planes, lay.lo_bits).astype(lay.uint_dtype)
+    lo = packing.bitplane_unpack(lo_planes, lay.lo_bits, lay.uint_dtype)
     vals = codec.merge_planes(exp, lo, lay.dtype, (resid.shape[0],))
     return acc.reshape(-1) + vals.astype(jnp.float32)
 

@@ -68,7 +68,12 @@ class ArchConfig:
     enc_seq: int = 1500  # stub frontend sequence length
     frontend: str = "none"  # none | audio_stub | vision_stub
     rope_theta: float = 10000.0
+    # RoPE on the first rope_dims of each head (None: all of them), as two
+    # halves or, interleaved, as adjacent pairs (x[2i], x[2i+1])
+    rope_dims: Optional[int] = None
+    rope_interleaved: bool = False
     mrope: bool = False  # qwen2-vl M-RoPE (text-only degenerate = RoPE; stub)
+    qkv_bias: bool = False  # bias vectors on the q, k and v projections
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -116,7 +121,8 @@ class ArchConfig:
             q = d * self.n_heads * hd
             kv = 2 * d * self.kv_heads * hd
             o = self.n_heads * hd * d
-            return q + kv + o
+            bias = (self.n_heads + 2 * self.kv_heads) * hd if self.qkv_bias else 0
+            return q + kv + o + bias
         if mixer == "mla":
             m = self.mla
             q = d * self.n_heads * (hd + m.rope_dim) if not m.q_lora else (

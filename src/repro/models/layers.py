@@ -50,16 +50,29 @@ def rope_table(positions, dim, theta):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, cos, sin):
-    """x (B, S, H, hd); cos/sin (B, S, hd//2) or (S, hd//2)."""
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+def apply_rope(x, cos, sin, interleaved: bool = False):
+    """x (B, S, H, hd); cos/sin (B, S, r//2) or (S, r//2).  Rotates the
+    first r dims of each head, as two halves or, ``interleaved``, as
+    adjacent pairs (x[2i], x[2i+1]); the other hd - r dims pass."""
+    r = 2 * cos.shape[-1]
+    xf = x.astype(jnp.float32)
+    xr = xf[..., :r] if r < x.shape[-1] else xf
+    if interleaved:
+        x1, x2 = xr[..., 0::2], xr[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xr, 2, axis=-1)
     if cos.ndim == 2:
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     else:
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(
-        x.dtype
-    )
+    y1, y2 = x1 * cos - x2 * sin, x1 * sin + x2 * cos
+    if interleaved:
+        y = jnp.stack([y1, y2], -1).reshape(xr.shape)
+    else:
+        y = jnp.concatenate([y1, y2], -1)
+    if r < x.shape[-1]:
+        y = jnp.concatenate([y, xf[..., r:]], -1)
+    return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -69,19 +82,35 @@ def apply_rope(x, cos, sin):
 def init_attention(key, cfg: ArchConfig, dtype):
     d, hd = cfg.d_model, cfg.hd
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": _dense_init(ks[0], (d, cfg.n_heads * hd), dtype),
         "wk": _dense_init(ks[1], (d, cfg.kv_heads * hd), dtype),
         "wv": _dense_init(ks[2], (d, cfg.kv_heads * hd), dtype),
         "wo": _dense_init(ks[3], (cfg.n_heads * hd, d), dtype),
     }
+    if cfg.qkv_bias:
+        p["bq"] = jnp.zeros((cfg.n_heads * hd,), dtype)
+        p["bk"] = jnp.zeros((cfg.kv_heads * hd,), dtype)
+        p["bv"] = jnp.zeros((cfg.kv_heads * hd,), dtype)
+    return p
 
 
 def spec_attention(cfg: ArchConfig):
-    return {
+    s = {
         "wq": P(None, "model"), "wk": P(None, "model"),
         "wv": P(None, "model"), "wo": P("model", None),
     }
+    if cfg.qkv_bias:
+        s.update(bq=P("model"), bk=P("model"), bv=P("model"))
+    return s
+
+
+def project(params, x, name: str):
+    """``x @ params["w" + name]``, plus ``params["b" + name]`` where the
+    projection has a bias (``ArchConfig.qkv_bias``)."""
+    y = x @ params["w" + name]
+    b = params.get("b" + name)
+    return y if b is None else y + b
 
 
 def _tile_mask(qpos, kpos, causal, window):
@@ -269,13 +298,13 @@ def attention(params, x, cfg: ArchConfig, *, spec: LayerSpec, positions,
     the KV cache's sequence dim is sharded (context-parallel decode)."""
     B, S, D = x.shape
     hd = cfg.hd
-    q = (x @ params["wq"]).reshape(B, S, cfg.n_heads, hd)
+    q = project(params, x, "q").reshape(B, S, cfg.n_heads, hd)
     if kv_override is None:
-        k = (x @ params["wk"]).reshape(B, S, cfg.kv_heads, hd)
-        v = (x @ params["wv"]).reshape(B, S, cfg.kv_heads, hd)
-        cos, sin = rope_table(positions, hd, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        k = project(params, x, "k").reshape(B, S, cfg.kv_heads, hd)
+        v = project(params, x, "v").reshape(B, S, cfg.kv_heads, hd)
+        cos, sin = rope_table(positions, cfg.rope_dims or hd, cfg.rope_theta)
+        q = apply_rope(q, cos, sin, cfg.rope_interleaved)
+        k = apply_rope(k, cos, sin, cfg.rope_interleaved)
     else:
         k, v = kv_override
         causal = False

@@ -246,8 +246,8 @@ def _run_encoder(params, frames, cfg: ArchConfig):
         # bidirectional: kv_override with own kv (no causal mask)
         mix_in = L.rms_norm(h, p["norm1"], cfg.norm_eps)
         B, S, _ = mix_in.shape
-        k = (mix_in @ p["mixer"]["wk"]).reshape(B, S, cfg.kv_heads, cfg.hd)
-        v = (mix_in @ p["mixer"]["wv"]).reshape(B, S, cfg.kv_heads, cfg.hd)
+        k = L.project(p["mixer"], mix_in, "k").reshape(B, S, cfg.kv_heads, cfg.hd)
+        v = L.project(p["mixer"], mix_in, "v").reshape(B, S, cfg.kv_heads, cfg.hd)
         out, _ = L.attention(
             p["mixer"], mix_in, cfg, spec=enc_spec, positions=pos,
             kv_override=(k, v),
@@ -288,8 +288,8 @@ def forward(params, batch: dict, cfg: ArchConfig, *, remat: bool = True,
         p = bpf(p, spec_i)
         if eo is not None:
             B_, T_, _ = eo.shape
-            k = (eo @ p["cross"]["wk"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
-            v = (eo @ p["cross"]["wv"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            k = L.project(p["cross"], eo, "k").reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            v = L.project(p["cross"], eo, "v").reshape(B_, T_, cfg.kv_heads, cfg.hd)
             eo = (k, v)
         h, _ = _apply_layer(p, h, cfg, spec, positions=positions,
                             enc_out=eo, cp_axis=cp_axis)
@@ -331,8 +331,8 @@ def prefill(params, batch: dict, cfg: ArchConfig, cache, *, cp_axis=None):
     def apply(p, h, spec, c, eo):
         if eo is not None and "cross" in p:
             B_, T_, _ = eo.shape
-            k = (eo @ p["cross"]["wk"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
-            v = (eo @ p["cross"]["wv"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            k = L.project(p["cross"], eo, "k").reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            v = L.project(p["cross"], eo, "v").reshape(B_, T_, cfg.kv_heads, cfg.hd)
             eo = (k, v)
         return _apply_layer(p, h, cfg, spec, positions=positions, cache=c,
                             cache_pos=None, enc_out=eo, cp_axis=cp_axis,
@@ -388,8 +388,8 @@ def decode_step(params, tokens, cache, cfg: ArchConfig, *, enc_out=None,
     def apply(p, h, spec, c, eo):
         if eo is not None and "cross" in p:
             B_, T_, _ = eo.shape
-            k = (eo @ p["cross"]["wk"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
-            v = (eo @ p["cross"]["wv"]).reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            k = L.project(p["cross"], eo, "k").reshape(B_, T_, cfg.kv_heads, cfg.hd)
+            v = L.project(p["cross"], eo, "v").reshape(B_, T_, cfg.kv_heads, cfg.hd)
             eo = (k, v)
         return _apply_layer(p, h, cfg, spec, positions=positions, cache=c,
                             cache_pos=pos, enc_out=eo, cp_axis=cp_axis)

@@ -122,8 +122,7 @@ class Compressor:
             def pipeline(flat):
                 exp, lo = codec.split_planes(flat)
                 lo_packed = packing.bitplane_pack(
-                    packing._pad_to(lo.astype(jnp.uint32), 32, "zero"),
-                    lay.lo_bits)
+                    packing._pad_to(lo, 32, "zero"), lay.lo_bits)
                 pk = packing.pack_exponents(exp, width=width, block=blk)
                 return lo_packed, pk
 
@@ -188,8 +187,7 @@ class Compressor:
                 t0 = time.perf_counter()
                 exp, lo = self._split(arr)
                 lo_packed = packing.bitplane_pack(
-                    packing._pad_to(lo.astype(jnp.uint32), 32, "zero"),
-                    lay.lo_bits)
+                    packing._pad_to(lo, 32, "zero"), lay.lo_bits)
                 jax.block_until_ready(lo_packed)
                 t_split = time.perf_counter() - t0
             with obs.span("p2p:entropy_code", lanes=self.lanes):
@@ -264,7 +262,7 @@ class Compressor:
         lay = codec.LAYOUTS[msg.dtype_name]
         n = int(np.prod(msg.shape)) if msg.shape else 1
         lo = packing.bitplane_unpack(jnp.asarray(msg.lo_payload),
-                                     lay.lo_bits)[:n].astype(lay.uint_dtype)
+                                     lay.lo_bits, lay.uint_dtype)[:n]
         if msg.codec == "rans":
             p = msg.exp_payload
             table = ans.FreqTable(

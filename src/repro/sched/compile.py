@@ -390,8 +390,7 @@ def p2p_wire_bytes(n_padded: int, dtype, *, width: int, block: int,
     def enc(xf):
         exp, lo = codec.split_planes(xf)
         lo_planes = packing.bitplane_pack(
-            packing._pad_to(lo.astype(jnp.uint32), packing.GROUP, "zero"),
-            lay.lo_bits)
+            packing._pad_to(lo, packing.GROUP, "zero"), lay.lo_bits)
         pk = packing.pack_exponents(exp, width=width, block=block,
                                     exc_frac=exc_frac)
         return {"lo": lo_planes, "payload": pk.payload, "bases": pk.bases,

@@ -51,7 +51,7 @@ FORCE_MODES = (None, MODE_FULL, MODE_RAW)
 def _count_exceptions(msg: packing.DeltaMessage) -> None:
     """Occupancy of a host-copied delta's two exception lists: each
     ``exc_idx`` is sorted, its unused slots at the plane's fill value."""
-    lo_fill = msg.lo.payload.shape[0] * packing.GROUP
+    lo_fill = msg.lo.payload.size // msg.lo.width * packing.GROUP
     for plane, p, fill in (("lo", msg.lo, lo_fill),
                            ("exp", msg.exp, msg.exp.n_blocks)):
         obs.metric("sync_delta_exceptions_total").inc(

@@ -100,8 +100,8 @@ def test_fused_pallas_kernel_planes_match_ref():
     x = make_input("bfloat16", TILE, seed=3)
     got = ek.encode_fused(x, 5, interpret=True)
     want = ref.encode_fused(x, 5)
-    for g, w in zip(got, want):
-        assert g.dtype == w.dtype and (g == w).all()
+    for g, w in zip(got, want):  # the kernel's planes are tiles, the ref's flat
+        assert g.dtype == w.dtype and (g.reshape(w.shape) == w).all()
 
 
 @given(st.integers(1, 8), st.integers(1, 40))
@@ -238,7 +238,7 @@ def test_kernel_fallbacks_counted_and_exposed():
     try:
         vals = jnp.zeros((32 * 3,), jnp.uint32)  # not a 32*TILE_G multiple
         ops.pack(vals, 4, use_pallas=True)
-        ops.unpack(jnp.zeros((3, 4), jnp.uint32), 4, use_pallas=True)
+        ops.unpack(jnp.zeros((3 * 4,), jnp.uint32), 4, use_pallas=True)
         ops.split_with_stats(jnp.zeros((1024,), jnp.bfloat16),
                              use_pallas=True)
         counts = kernels.fallback_counts()

@@ -143,3 +143,31 @@ def test_active_params_less_than_total_for_moe(name):
         assert cfg.active_param_count() < cfg.param_count()
     else:
         assert cfg.active_param_count() == cfg.param_count()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("batched", [False, True])
+def test_rope_whole_head_in_halves_is_unchanged(dtype, batched):
+    """RoPE over the whole head in two halves (every config but GLM-4's)
+    is bit-identical to the rotary as it was before ``rope_dims`` and
+    ``rope_interleaved``."""
+    def before(x, cos, sin):
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        if cos.ndim == 2:
+            cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+        else:
+            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                               -1).astype(x.dtype)
+
+    from repro.models import layers as L
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 3, 64)).astype(dtype)
+    pos = jnp.arange(12) + 1000
+    cos, sin = L.rope_table(jnp.stack([pos, pos]) if batched else pos, 64,
+                            10000.0)
+    got = jax.jit(L.apply_rope)(x, cos, sin)
+    want = jax.jit(before)(x, cos, sin)
+    u = jnp.uint32 if dtype == "float32" else jnp.uint16
+    assert (jax.lax.bitcast_convert_type(got, u)
+            == jax.lax.bitcast_convert_type(want, u)).all()

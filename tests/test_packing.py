@@ -13,7 +13,7 @@ def test_bitplane_roundtrip(width):
     rng = np.random.default_rng(width)
     vals = jnp.asarray(rng.integers(0, 1 << width, 32 * 17), jnp.uint32)
     pk = packing.bitplane_pack(vals, width)
-    assert pk.shape == (17, width)
+    assert pk.shape == (17 * width,)
     up = packing.bitplane_unpack(pk, width)
     assert (up == vals).all()
 
@@ -123,3 +123,48 @@ def test_exception_indices_equal_nonzero(n, density, size_of):
     (want,) = jnp.nonzero(mask, size=size, fill_value=n)
     assert got.dtype == jnp.int32 and got.shape == (size,)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _padded_pack(vals, width):
+    """The packer as it was before the flat wire: a ``(n // 32, width)``
+    array, lane-padded on a TPU."""
+    g = vals.reshape(-1, 32).astype(jnp.uint32)
+    pos = jnp.arange(32, dtype=jnp.uint32)
+    return jnp.stack([jnp.sum(((g >> jnp.uint32(b)) & 1) << pos, axis=-1,
+                              dtype=jnp.uint32) for b in range(width)], -1)
+
+
+def _padded_unpack(packed, width):
+    pos = jnp.arange(32, dtype=jnp.uint32)
+    vals = jnp.zeros((packed.shape[0], 32), jnp.uint32)
+    for b in range(width):
+        vals = vals | (((packed[:, b:b + 1] >> pos) & 1) << jnp.uint32(b))
+    return vals.reshape(-1)
+
+
+@pytest.mark.parametrize("width", range(1, 9))
+@pytest.mark.parametrize("n", [
+    32,                                # one group
+    packing._SLICE - 32,               # just under one slice: no loop
+    2 * packing._SLICE + 32 * 5,       # a loop of two slices, a ragged tail
+    3 * packing._SLICE,                # a loop of whole slices only
+])
+def test_bitplane_flat_matches_padded_form(width, n):
+    """The flat packer and unpacker are bit-identical to the lane-padded
+    form, word for word in ``[g, b]`` order, looped or not; bits above
+    ``width`` are ignored by both; narrow dtypes in and out."""
+    rng = np.random.default_rng(width * 1000 + n)
+    vals = jnp.asarray(rng.integers(0, 1 << (width + 2), n), jnp.uint32)
+    want = _padded_pack(vals, width)
+    got = packing.bitplane_pack(vals, width)
+    assert got.shape == (n // 32 * width,) and got.dtype == jnp.uint32
+    assert (got == want.reshape(-1)).all()
+    assert (packing.bitplane_pack(vals.astype(jnp.uint8), width)
+            == packing.bitplane_pack(vals.astype(jnp.uint8).astype(
+                jnp.uint32), width)).all()
+    back = packing.bitplane_unpack(got, width)
+    assert back.dtype == jnp.uint32
+    assert (back == _padded_unpack(want, width)).all()
+    narrow = packing.bitplane_unpack(got, width, jnp.uint8)
+    assert narrow.dtype == jnp.uint8 and (narrow == back.astype(
+        jnp.uint8)).all()

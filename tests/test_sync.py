@@ -570,6 +570,33 @@ def test_engine_full_then_delta_then_prune_fallback():
     assert tree_bits_equal(apply_update(u4), p4)
 
 
+def test_engine_round_trips_glm4_smoke_tree():
+    """GLM-4's tree (QKV biases, untied head) through the host engine:
+    ``encode_message``/``decode_message`` for the full publish, then
+    ``encode_delta``/``decode_delta``, each bit-exact, on the flat wire."""
+    from repro import configs
+    from repro.models import transformer
+
+    params = transformer.init(jax.random.PRNGKey(3),
+                              configs.get_smoke("glm4_9b"))
+    assert "bq" in params["blocks"][0]["mixer"]
+    eng = WeightSyncEngine(policy=POL, plan_cache=sched.PlanCache())
+    eng.publish(params)
+    u1 = eng.update_for("r0")
+    assert u1.mode == "full"
+    assert all(m.lo.ndim == 1 and m.exp.payload.ndim == 1
+               for _, _, mode, m in u1.buckets if mode == "full")
+    held = apply_update(u1)
+    assert tree_bits_equal(held, params)
+    eng.ack("r0", u1.version, u1.epoch)
+    p2 = perturb_params(params, seed=5)
+    eng.publish(p2)
+    u2 = eng.update_for("r0")
+    assert u2.mode == "delta"
+    assert u2.wire_bytes == eng.plan_for(p2).delta_wire_bytes
+    assert tree_bits_equal(apply_update(u2, base_params=held), p2)
+
+
 def test_engine_current_replica_gets_zero_delta():
     """A replica already at the latest version re-syncs via the all-zero
     delta — far cheaper than a full re-send, and still bit-exact."""

@@ -85,7 +85,7 @@ def test_decode_reduce_compiles_for_v5e(one_chip, chunk, dtype):
     text = _compile_text(
         lambda p, lo, gb, a: ops.decode_reduce(
             p, lo, gb, a, dtype, WIDTH, use_pallas=True, interpret=False),
-        u32(n_g, WIDTH), u32(n_g, lo_bits), u32(n_g), acc)
+        u32(n_g * WIDTH), u32(n_g * lo_bits), u32(n_g), acc)
     assert "tpu_custom_call" in text
 
 
@@ -104,3 +104,23 @@ def test_exception_indices_compiles_lean_for_v5e(one_chip):
         lambda m: packing.exception_indices(m, size=size, fill=n)
     ).lower(mask).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * n
+
+
+@pytest.mark.parametrize("side", ["pack", "unpack"])
+def test_bitplane_codec_compiles_lean_for_v5e(one_chip, side):
+    """The bit-plane packer and unpacker at glm4_9b's whole
+    359,154,176-element weight-sync bucket: temporaries under four bytes
+    per element.  One lane-padded ``(n / 32, 32)`` uint32 array, which the
+    lane-narrow form materialised, is sixteen."""
+    from repro.core import packing
+
+    n = 359_154_176
+    if side == "pack":
+        arg = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip)
+        fn = lambda v: packing.bitplane_pack(v, WIDTH)  # noqa: E731
+    else:
+        arg = jax.ShapeDtypeStruct((n // 32 * WIDTH,), jnp.uint32,
+                                   sharding=one_chip)
+        fn = lambda p: packing.bitplane_unpack(p, WIDTH)  # noqa: E731
+    compiled = jax.jit(fn).lower(arg).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
