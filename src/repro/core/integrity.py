@@ -19,6 +19,14 @@ recovery").
 CRC-32 (zlib) is deliberate: integrity here defends against *transport
 corruption* (the fault model injects bit flips), not adversaries, and
 the checksum must stay far cheaper than the encode it protects.
+
+The checksum of an array is the CRC-32, chained from the running value,
+of its dtype's ``str``, then the ``repr`` of its shape (a 0-d array
+counts as shape ``(1,)``), then its bytes in C order.  ``zlib.crc32``
+reads those bytes from the array's own memory through a flat ``uint8``
+view; only an array that is not C-contiguous is first copied, once, into
+C order (``wire_crc_bytes_total`` counts each array's bytes under
+``path="view"`` or ``path="copy"``).
 """
 from __future__ import annotations
 
@@ -26,6 +34,8 @@ import dataclasses
 import zlib
 
 import numpy as np
+
+from repro import obs
 
 
 class WireIntegrityError(ValueError):
@@ -63,10 +73,13 @@ def crc32_tree(obj, seed: int = 0) -> int:
                 visit(k)
                 visit(o[k])
         elif hasattr(o, "shape") and hasattr(o, "dtype"):
-            arr = np.ascontiguousarray(np.asarray(o))  # device -> host view
+            arr = np.asarray(o)  # device -> host
+            path = "view" if arr.flags.c_contiguous else "copy"
+            arr = np.ascontiguousarray(arr)
             c = zlib.crc32(str(arr.dtype).encode(), c)
             c = zlib.crc32(repr(arr.shape).encode(), c)
-            c = zlib.crc32(arr.tobytes(), c)
+            c = zlib.crc32(arr.reshape(-1).view(np.uint8), c)
+            obs.metric("wire_crc_bytes_total").inc(arr.nbytes, path=path)
         elif dataclasses.is_dataclass(o) and not isinstance(o, type):
             for f in dataclasses.fields(o):
                 visit(getattr(o, f.name))

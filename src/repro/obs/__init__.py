@@ -30,7 +30,8 @@ kind, fed from the consolidated WireReports, plus the per-bucket ledger),
 and observatory spans of each update, delta-vs-full counts, per-replica
 version lag, host-path ledger + drift), ``p2p/engine`` and
 ``runtime/fault_tolerance`` (stage/step spans + latency histograms),
-``kernels.record_fallback`` (labeled counter mirror).
+``kernels.record_fallback`` (labeled counter mirror), ``core/integrity``
+(wire CRC bytes, read in place or copied).
 
 Env knobs:
   * ``REPRO_OBS=0``       — every instrumentation call becomes a near-zero

@@ -139,6 +139,11 @@ METRICS = (
     MetricSpec("fault_injected_total", "counter", ("kind",),
                "runtime/faults.py",
                "faults injected by the active FaultPlan, per kind"),
+    # -- core/integrity.py
+    MetricSpec("wire_crc_bytes_total", "counter", ("path",),
+               "core/integrity.py",
+               "bytes of arrays hashed by the wire CRC-32, by path (view: "
+               "read in place; copy: copied once into C order)"),
     # -- sync/fleet.py
     MetricSpec("sync_integrity_failures_total", "counter", ("reason",),
                "sync/fleet.py",

@@ -9,6 +9,9 @@ Quick-gate coverage:
   * every ``SyncUpdate`` (delta/full/raw) carries a payload checksum that
     survives the round trip and catches a single flipped bit;
   * forced full/raw escalation encodes remain bit-exact;
+  * ``crc32_tree`` equals the copying formula (dtype, shape, C-order
+    bytes) for every dtype and layout, and reads C-contiguous arrays in
+    place (one copy for any other layout);
   * KV wires (``pack_cache``) verify their checksum before decode;
     ``ServeEngine`` rejects corrupt ingests and retries corrupt KV
     shipments within a bounded budget;
@@ -21,6 +24,8 @@ Quick-gate coverage:
 """
 import dataclasses
 import shutil
+import tracemalloc
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +215,127 @@ def test_crc32_tree_sensitivity():
     # dtype/shape are covered, not just bytes
     assert crc32_tree(np.zeros(4, np.float32)) != crc32_tree(
         np.zeros(2, np.float64))
+
+
+def _crc32_tree_copying(obj, seed=0):
+    """The checksum's formula as first written: each array copied into C
+    order and then into a ``bytes`` object.  ``crc32_tree`` must agree
+    with it bit for bit, whatever way it reads the bytes."""
+    c = seed & 0xFFFFFFFF
+
+    def visit(o):
+        nonlocal c
+        if o is None or isinstance(o, (bool, int, float, str)):
+            c = zlib.crc32(repr(o).encode(), c)
+        elif isinstance(o, bytes):
+            c = zlib.crc32(o, c)
+        elif isinstance(o, (list, tuple)):
+            for x in o:
+                visit(x)
+        elif isinstance(o, dict):
+            for k in sorted(o, key=repr):
+                visit(k)
+                visit(o[k])
+        elif hasattr(o, "shape") and hasattr(o, "dtype"):
+            arr = np.ascontiguousarray(np.asarray(o))
+            c = zlib.crc32(str(arr.dtype).encode(), c)
+            c = zlib.crc32(repr(arr.shape).encode(), c)
+            c = zlib.crc32(arr.tobytes(), c)
+        elif dataclasses.is_dataclass(o) and not isinstance(o, type):
+            for f in dataclasses.fields(o):
+                visit(getattr(o, f.name))
+        else:
+            c = zlib.crc32(repr(o).encode(), c)
+
+    visit(obj)
+    return c
+
+
+CRC_DTYPES = (np.uint8, np.uint16, np.uint32, np.int32, np.bool_,
+              np.float32, jnp.bfloat16, jnp.float8_e4m3fn)
+
+
+def _random_array(dtype, shape, seed):
+    """Random bit patterns of ``dtype`` (NaN payloads included)."""
+    dtype = np.dtype(dtype)
+    n = int(np.prod(shape))
+    raw = np.random.default_rng(seed).integers(
+        0, 256, n * dtype.itemsize, dtype=np.uint8)
+    if dtype == np.bool_:
+        raw &= 1
+    return raw.view(dtype).reshape(shape)
+
+
+CRC_LAYOUTS = {
+    "0d": lambda d, s: _random_array(d, (), s),
+    "empty": lambda d, s: _random_array(d, (0, 5), s),
+    "1d": lambda d, s: _random_array(d, (37,), s),
+    "2d_c": lambda d, s: _random_array(d, (6, 7), s),
+    "fortran": lambda d, s: np.asfortranarray(_random_array(d, (6, 7), s)),
+    "strided": lambda d, s: _random_array(d, (12, 14), s)[::2, 1::3],
+}
+
+
+@pytest.mark.parametrize("layout", sorted(CRC_LAYOUTS))
+@pytest.mark.parametrize("dtype", CRC_DTYPES,
+                         ids=lambda d: np.dtype(d).name)
+def test_crc32_tree_matches_copying_formula(dtype, layout):
+    a = CRC_LAYOUTS[layout](dtype, 3)
+    b = CRC_LAYOUTS[layout](dtype, 4)
+    assert crc32_tree(a) == _crc32_tree_copying(a)
+    # seeds chain exactly as the formula's do, across arrays and trees
+    chained = crc32_tree(b, seed=crc32_tree(a, seed=0xDEADBEEF))
+    assert chained == _crc32_tree_copying(
+        b, seed=_crc32_tree_copying(a, seed=0xDEADBEEF))
+    assert crc32_tree({"a": a, "b": (b, 1)}) == _crc32_tree_copying(
+        {"a": a, "b": (b, 1)})
+
+
+def test_crc32_tree_matches_copying_formula_jax_array():
+    x = jnp.asarray(_random_array(np.float32, (5, 7), 6), jnp.bfloat16)
+    assert crc32_tree(x) == _crc32_tree_copying(x)
+    assert crc32_tree(x) == crc32_tree(np.asarray(x))
+
+
+def test_crc32_tree_matches_copying_formula_delta_message():
+    from repro.core import packing
+
+    rng = np.random.default_rng(7)
+    base = jnp.asarray(rng.normal(0, 0.02, 4096), jnp.bfloat16)
+    new = perturb({"w": base})["w"]
+    msg = jax.device_get(packing.encode_delta(new, base, width=2,
+                                              lo_width=3))
+    assert crc32_tree(msg) == _crc32_tree_copying(msg)
+    assert crc32_tree(msg, seed=11) == _crc32_tree_copying(msg, seed=11)
+
+
+def test_crc32_tree_hashes_in_place():
+    """A C-contiguous payload is read where it lies: no copy of its bytes.
+    Any other layout is copied into C order once, never twice."""
+    from repro import obs
+
+    n = 1 << 24  # 64 MiB of uint32
+    flat = np.arange(n, dtype=np.uint32)
+    fortran = np.asfortranarray(flat.reshape(4096, 4096))
+    obs.set_enabled(True)
+    obs.reset()
+    try:
+        peaks = []
+        for arr in (flat, fortran):
+            tracemalloc.start()
+            try:
+                crc32_tree(arr)
+                peaks.append(tracemalloc.get_traced_memory()[1])
+            finally:
+                tracemalloc.stop()
+        assert peaks[0] < 1 << 20, peaks
+        assert fortran.nbytes <= peaks[1] < 1.5 * fortran.nbytes, peaks
+        counted = obs.snapshot()["counters"]["wire_crc_bytes_total"]
+        assert counted == {"path=view": flat.nbytes,
+                           "path=copy": fortran.nbytes}
+    finally:
+        obs.set_enabled(None)
+        obs.reset()
 
 
 # ---------------------------------------------------------------------------
