@@ -46,7 +46,6 @@ MODULES = [
     ("sync", "benchmarks.fig_sync"),
     ("faults", "benchmarks.fig_faults"),
     ("tree", "benchmarks.fig_tree"),
-    ("drift", "benchmarks.fig_drift"),
     ("obs", "repro.obs.dump"),
 ]
 
@@ -103,12 +102,9 @@ def main():
         # Reset the counters per module: fallback attribution must name the
         # benchmark that actually degraded, not accumulate across figs (the
         # once-per-op warning also re-arms, so each module logs its own).
-        # Same for the observatory: regret samples / drift windows / the
-        # flight recorder must describe the module being measured, not its
-        # predecessors (the metrics registry itself keeps accumulating —
-        # the final snapshot is the whole run's).
+        # The metrics registry keeps accumulating: the final snapshot is
+        # the whole run's.
         kernels.clear_fallbacks()
-        obs.clear_observatory()
         ok = True
         budget_s = None
         gates = None

@@ -54,8 +54,7 @@ def width_cost_curve(
 ) -> tuple:
     """The full predicted cost curve: one :class:`WidthChoice` per candidate
     exponent width ``1..exp_bits`` (escape rate and wire ratio AT that
-    width).  :func:`choose_width` picks from this curve; the regret
-    analytics (``obs/regret.py``) score achieved-vs-optimal widths with it.
+    width).  :func:`choose_width` picks from this curve.
     """
     exp, _ = codec.split_planes(x)
     return _exp_cost_curve(exp, codec.layout_of(x.dtype), block,

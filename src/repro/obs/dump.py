@@ -2,7 +2,6 @@
 
     PYTHONPATH=src python -m repro.obs.dump [--target train_sync|sync|serve]
                                             [--out DIR] [--steps N]
-                                            [--report]
 
 Runs a small instrumented workload end to end and writes three artifacts
 to ``--out`` (default ``REPRO_TRACE_DIR``):
@@ -10,12 +9,6 @@ to ``--out`` (default ``REPRO_TRACE_DIR``):
   * ``trace_<target>.json``   — Chrome-trace/Perfetto timeline of the run
   * ``metrics_<target>.json`` — the metrics-registry snapshot
   * ``metrics_<target>.md``   — the same snapshot as a markdown table
-
-``--report`` additionally renders the wire-efficiency observatory
-(``report_<target>.md`` / ``.json``): top width-regret buckets
-(``obs/regret.py``), drift events and currently-stale plans
-(``obs/drift.py``), and sparkline tables of the recorded ratio series
-(``obs/recorder.py``).
 
 Targets are pluggable (``TARGETS``); the default ``train_sync`` runs the
 smollm smoke model through the fault-tolerant step runner and then a
@@ -27,7 +20,6 @@ exercises the full telemetry path.
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import tempfile
 
@@ -133,83 +125,8 @@ TARGETS = {
 }
 
 
-def build_report(*, window: int = 200, top: int = 10) -> dict:
-    """Assemble the observatory report from the live analysis layer:
-    top width-regret rows, the drift report, per-kind ledger totals, and
-    windowed stats + sparklines of every recorded ratio series."""
-    from repro import obs
-    from repro.obs import drift as drift_lib
-    from repro.obs import regret as regret_lib
-
-    rec = obs.recorder()
-    series = {}
-    for key in rec.series():
-        name, _, labels_key = key.partition("|")
-        if "ratio" not in name:
-            continue
-        st = rec.window(name, n=window, labels_key=labels_key)
-        vals = [s.value for s in rec.samples(name, n=window,
-                                             labels_key=labels_key)]
-        series[key] = dict(st.to_dict(), spark=obs.sparkline(vals))
-    ledger = regret_lib.ledger_totals()
-    return {
-        "regret": [r.to_dict() for r in regret_lib.width_regret()[:top]],
-        "drift": drift_lib.detector().report().to_dict(),
-        "ledger_by_kind": ledger["by_kind"],
-        "ledger_by_bucket": {
-            f"{k}/{d}/w{w}": v
-            for (k, d, w), v in sorted(ledger["by_bucket"].items())},
-        "ratio_series": series,
-    }
-
-
-def report_to_markdown(rep: dict) -> str:
-    """Human rendering of :func:`build_report`'s dict."""
-    lines = ["# Wire-efficiency observatory", ""]
-    lines += ["## Top regret buckets", ""]
-    if rep["regret"]:
-        lines += ["| kind | dtype | width (achieved→optimal) | wire KiB "
-                  "(achieved/optimal) | regret KiB | regret/raw |",
-                  "|---|---|---|---|---|---|"]
-        for r in rep["regret"]:
-            lines.append(
-                f"| {r['kind']} | {r['dtype_name']} "
-                f"| {r['achieved_width']}→{r['optimal_width']} "
-                f"| {r['achieved_wire_bytes']/2**10:.1f}/"
-                f"{r['optimal_wire_bytes']/2**10:.1f} "
-                f"| {r['regret_bytes']/2**10:+.1f} "
-                f"| {r['regret_frac']:+.4f} |")
-    else:
-        lines.append("(no host-path samples recorded)")
-    lines += ["", "## Drift", ""]
-    ev = rep["drift"]["events"]
-    if ev:
-        lines += ["| plan key | kind | predicted | live at fire |",
-                  "|---|---|---|---|"]
-        lines += [f"| {e['key_hex']} | {e['kind']} "
-                  f"| {e['predicted_ratio']:.4f} | {e['live_ratio']:.4f} |"
-                  for e in ev]
-        stale = rep["drift"]["stale"]
-        lines += ["", f"currently stale: "
-                  f"{', '.join(s['key_hex'] for s in stale) or 'none'}"]
-    else:
-        lines.append("no drift events (live wire matched every plan's "
-                     "prediction)")
-    lines += ["", "## Ratio series (flight recorder)", ""]
-    if rep["ratio_series"]:
-        lines += ["| series | n | mean | last | spark |",
-                  "|---|---|---|---|---|"]
-        esc = "\\|"  # literal pipe inside a markdown table cell
-        lines += [f"| {key.replace('|', esc)} | {s['count']} "
-                  f"| {s['mean']:.4f} | {s['last']:.4f} | {s['spark']} |"
-                  for key, s in sorted(rep["ratio_series"].items())]
-    else:
-        lines.append("(no ratio series recorded)")
-    return "\n".join(lines) + "\n"
-
-
-def dump(target: str = "train_sync", out: str = None, steps: int = 3,
-         report: bool = False) -> dict:
+def dump(target: str = "train_sync", out: str = None,
+         steps: int = 3) -> dict:
     """Run ``target`` and write trace + metric artifacts; returns paths."""
     from repro import obs
 
@@ -227,27 +144,16 @@ def dump(target: str = "train_sync", out: str = None, steps: int = 3,
     md_path = os.path.join(out, f"metrics_{target}.md")
     with open(md_path, "w") as f:
         f.write(obs.registry().to_markdown() + "\n")
-    paths = {"trace": trace_path, "metrics_json": json_path,
-             "metrics_md": md_path}
-    if report:
-        rep = build_report()
-        rep_json = os.path.join(out, f"report_{target}.json")
-        with open(rep_json, "w") as f:
-            json.dump(rep, f, indent=2, sort_keys=True)
-        rep_md = os.path.join(out, f"report_{target}.md")
-        with open(rep_md, "w") as f:
-            f.write(report_to_markdown(rep))
-        paths.update({"report_json": rep_json, "report_md": rep_md})
-    return paths
+    return {"trace": trace_path, "metrics_json": json_path,
+            "metrics_md": md_path}
 
 
 def run() -> None:
-    """benchmarks/run.py entry point (key "obs"): default smoke dump,
-    observatory report included."""
-    paths = dump(report=True)
+    """benchmarks/run.py entry point (key "obs"): the default smoke
+    dump."""
+    paths = dump()
     print(f"obs dump: trace -> {paths['trace']}")
     print(f"obs dump: metrics -> {paths['metrics_json']}")
-    print(f"obs dump: report -> {paths['report_md']}")
 
 
 def main():
@@ -259,11 +165,8 @@ def main():
     ap.add_argument("--steps", type=int, default=3,
                     help="workload size (train steps / publishes / "
                          "decode steps)")
-    ap.add_argument("--report", action="store_true",
-                    help="also write the observatory report "
-                         "(regret/drift/sparklines)")
     args = ap.parse_args()
-    paths = dump(args.target, args.out, args.steps, report=args.report)
+    paths = dump(args.target, args.out, args.steps)
     for k, v in paths.items():
         print(f"{k}: {v}")
 

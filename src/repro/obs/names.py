@@ -16,7 +16,6 @@ import dataclasses
 
 from repro.obs import config
 from repro.obs import metrics as metrics_lib
-from repro.obs import recorder as recorder_lib
 
 # plan_wire_ratio_hist buckets: wire/raw, so the interesting mass is
 # (0, 1]; >1 catches pathological expansion (tiny payload overheads)
@@ -53,20 +52,6 @@ METRICS = (
                "sched/executor.py",
                "distribution of consolidated wire ratios per plan kind",
                buckets=RATIO_BUCKETS),
-    # -- per-bucket wire ledger (obs/regret.py reads it back): plan kinds
-    #    sum EXACTLY to the consolidated plan:<kind> WireReports; host
-    #    paths ledger under their own kinds (wsync_host, p2p_host)
-    MetricSpec("bucket_wire_raw_bytes_total", "counter",
-               ("kind", "dtype", "width"), "sched/executor.py",
-               "per-bucket raw bytes, by (plan kind, dtype, width)"),
-    MetricSpec("bucket_wire_bytes_total", "counter",
-               ("kind", "dtype", "width"), "sched/executor.py",
-               "per-bucket packed wire bytes, by (plan kind, dtype, width)"),
-    # -- obs/drift.py
-    MetricSpec("wire_drift_events_total", "counter", ("kind",),
-               "obs/drift.py",
-               "drift-detector firings (live ratio left the plan's "
-               "compile-time prediction)"),
     # -- sched/cache.py: gauges mirror PlanCache.cache_info() after every
     #    lookup ("default" = the process cache, "local" = private instances)
     MetricSpec("plan_cache_hits", "gauge", ("cache",),
@@ -227,8 +212,8 @@ SPANS = (
     ("serve:verify", "serve/engine.py",
      "the replica's CRC-32 check of an update before the fence"),
     ("obs:sample", "sync/engine.py",
-     "the observatory's own cost in an encode: wire ledger, payload "
-     "sample, drift observation"),
+     "one bucket's routing and exception-list counters in an encode "
+     "(sync_buckets_total, sync_delta_exception*_total)"),
     ("p2p:encode", "p2p/engine.py", "host Compressor encode"),
     ("p2p:split", "p2p/engine.py", "plane-split stage (rANS codec)"),
     ("p2p:entropy_code", "p2p/engine.py", "rANS exponent-plane encode"),
@@ -250,72 +235,23 @@ SPANS = (
      "trainer failover: checkpoint restore + epoch fence"),
     ("fleet:forward", "sync/fleet.py",
      "instant: an interior replica forwarded the encoded wire verbatim"),
-    ("drift:fire", "obs/drift.py",
-     "instant: the drift detector flagged a stale plan (live wire ratio "
-     "beyond the hysteresis threshold)"),
 )
-
-
-class _RecordedMetric:
-    """Tee wrapper: forwards each observation to the registry metric AND
-    into the flight recorder (``obs/recorder.py``), keyed by the same
-    declared-order label string — so every instrumented series gets a
-    windowed history for free."""
-
-    __slots__ = ("_m",)
-
-    def __init__(self, m):
-        self._m = m
-
-    @property
-    def name(self):
-        return self._m.name
-
-    @property
-    def kind(self):
-        return self._m.kind
-
-    @property
-    def label_names(self):
-        return self._m.label_names
-
-    def series(self):
-        return self._m.series()
-
-    def inc(self, value=1, **labels):
-        self._m.inc(value, **labels)  # validates labels before we record
-        recorder_lib.record(self._m.name, value, self._m._key(labels))
-
-    def dec(self, value=1, **labels):
-        self._m.dec(value, **labels)
-        recorder_lib.record(self._m.name, -value, self._m._key(labels))
-
-    def set(self, value, **labels):
-        self._m.set(value, **labels)
-        recorder_lib.record(self._m.name, value, self._m._key(labels))
-
-    def observe(self, value, **labels):
-        self._m.observe(value, **labels)
-        recorder_lib.record(self._m.name, value, self._m._key(labels))
 
 
 def metric(name: str):
     """The live metric for a canonical ``name`` (no-op when REPRO_OBS=0).
 
     Creates it in the default registry on first use with the spec's
-    declared type/labels, so instrumentation cannot drift from the table;
-    observations are teed into the flight recorder.  Unknown names raise
-    KeyError."""
+    declared type/labels, so instrumentation cannot drift from the table.
+    Unknown names raise KeyError."""
     if not config.enabled():
         _ = SPECS[name]  # typos still fail loudly in disabled mode
         return metrics_lib.NOOP_METRIC
     spec = SPECS[name]
     reg = metrics_lib.registry()
     if spec.kind == "histogram":
-        m = reg.histogram(
+        return reg.histogram(
             spec.name, labels=spec.labels, help=spec.help,
             buckets=spec.buckets or metrics_lib.DEFAULT_TIME_BUCKETS)
-    else:
-        m = getattr(reg, spec.kind)(spec.name, labels=spec.labels,
-                                    help=spec.help)
-    return _RecordedMetric(m)
+    return getattr(reg, spec.kind)(spec.name, labels=spec.labels,
+                                   help=spec.help)

@@ -159,18 +159,6 @@ class Compressor:
             sp.args["wire_bytes"] = msg.wire_bytes()
         obs.metric("p2p_encode_seconds").observe(
             msg.t_split + msg.t_encode, codec=self.codec_name)
-        if obs.enabled():
-            # host-path wire ledger + recalibration sample (obs/regret.py);
-            # its own kind keeps the plan-kind exactness contract exact
-            w_used = int(msg.width or 0)
-            obs.metric("bucket_wire_raw_bytes_total").inc(
-                msg.raw_bytes, kind="p2p_host", dtype=msg.dtype_name,
-                width=w_used)
-            obs.metric("bucket_wire_bytes_total").inc(
-                msg.wire_bytes(), kind="p2p_host", dtype=msg.dtype_name,
-                width=w_used)
-            from repro.obs import regret as regret_lib
-            regret_lib.record_sample("p2p_host", msg.dtype_name, x)
         return msg
 
     def _encode_impl(self, x, *, tensor_class: str, reuse_table: bool,

@@ -31,8 +31,6 @@ Entry points:
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,7 +44,6 @@ from repro.core.compressed_collectives import (
     reduce_scatter_compressed,
 )
 from repro import obs
-from repro.obs import drift as drift_lib
 from repro.core.policy import (WireReport, capture_wire_reports,
                                record_wire_report)
 from repro.sched import compile as sched_compile
@@ -104,35 +101,6 @@ def _emit(plan: CommPlan, caught) -> None:
                                                 kind=plan.kind)
         obs.metric("plan_wire_ratio").set(rep.ratio, kind=plan.kind)
         obs.metric("plan_wire_ratio_hist").observe(rep.ratio, kind=plan.kind)
-        # executor wires are statically sized (jax.eval_shape at compile
-        # time), so live == predicted and this can only fire when a plan
-        # is replayed against a differently-gated report mix
-        drift_lib.observe_plan(plan, rep)
-
-
-@contextlib.contextmanager
-def _bucket_ledger(plan: CommPlan, dtype_name: str, width: int):
-    """Per-bucket wire ledger: capture ONE bucket's wire reports, forward
-    them verbatim to the enclosing plan capture (so consolidation sees
-    exactly what it would without us), and ledger the bucket's raw/wire
-    byte sums under (kind, dtype, width) — the data source of
-    ``obs/regret.py``.  Per-kind ledger sums therefore equal the
-    consolidated ``plan:<kind>`` totals byte-for-byte.  No-op when obs is
-    disabled."""
-    if not obs.enabled():
-        yield
-        return
-    with capture_wire_reports() as inner:
-        yield
-    for r in inner:
-        record_wire_report(r)
-    if inner:
-        obs.metric("bucket_wire_raw_bytes_total").inc(
-            sum(r.raw_bytes for r in inner),
-            kind=plan.kind, dtype=dtype_name, width=width)
-        obs.metric("bucket_wire_bytes_total").inc(
-            sum(r.wire_bytes for r in inner),
-            kind=plan.kind, dtype=dtype_name, width=width)
 
 
 # ---------------------------------------------------------------------------
@@ -207,16 +175,13 @@ def execute_psum(plan: CommPlan, tree, axis_name):
         for b in plan.buckets:
             parts = [leaves[i].reshape(-1) for i, _, _ in b.members]
             bucket = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-            with _bucket_ledger(plan, b.dtype_name, b.width):
-                red, f = _exec_psum_bucket(b, bucket, axis_name,
-                                           plan.use_pallas)
+            red, f = _exec_psum_bucket(b, bucket, axis_name, plan.use_pallas)
             flag = jnp.maximum(flag, f)
             offs = np.cumsum([0] + [m[2] for m in b.members])
             for k, (i, shape, _) in enumerate(b.members):
                 out[i] = red[offs[k]: offs[k + 1]].reshape(shape)
-        with _bucket_ledger(plan, "raw", 0):
-            for i in plan.raw_leaf_ix:
-                out[i] = psum_safe(leaves[i], axis_name)
+        for i in plan.raw_leaf_ix:
+            out[i] = psum_safe(leaves[i], axis_name)
     _emit(plan, caught)
     return jax.tree_util.tree_unflatten(treedef, out), flag
 
@@ -268,9 +233,7 @@ def reduce_scatter_with_plan(x, axis_name, *, policy=None,
                 n_dev=n_dev, tensor_class=tensor_class, key=key))
     with _plan_span(plan), capture_wire_reports() as caught:
         b = plan.buckets[0]
-        with _bucket_ledger(plan, b.dtype_name, b.width):
-            out, flag = _exec_reduce_scatter(b, x, axis_name,
-                                             plan.use_pallas)
+        out, flag = _exec_reduce_scatter(b, x, axis_name, plan.use_pallas)
     _emit(plan, caught)
     return out, flag
 
@@ -293,8 +256,7 @@ def all_gather_with_plan(y, axis_name, *, policy=None,
                 n_dev=n_dev, tensor_class=tensor_class, key=key))
     with _plan_span(plan), capture_wire_reports() as caught:
         b = plan.buckets[0]
-        with _bucket_ledger(plan, b.dtype_name, b.width):
-            out, flag = _exec_all_gather(b, y, axis_name, plan.use_pallas)
+        out, flag = _exec_all_gather(b, y, axis_name, plan.use_pallas)
     _emit(plan, caught)
     return out, flag
 
@@ -330,15 +292,13 @@ class Zero1Execution:
 
     def reduce_scatter(self, i: int, gbucket):
         b = self.plan.buckets[i].rs
-        with _bucket_ledger(self.plan, b.dtype_name, b.width):
-            return _exec_reduce_scatter(b, gbucket, self.axis_name,
-                                        self.plan.use_pallas)
+        return _exec_reduce_scatter(b, gbucket, self.axis_name,
+                                    self.plan.use_pallas)
 
     def all_gather(self, i: int, shard):
         b = self.plan.buckets[i].ag
-        with _bucket_ledger(self.plan, b.dtype_name, b.width):
-            return _exec_all_gather(b, shard, self.axis_name,
-                                    self.plan.use_pallas)
+        return _exec_all_gather(b, shard, self.axis_name,
+                                self.plan.use_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -378,11 +338,10 @@ def execute_p2p(plan: CommPlan, x, axis_name, perm, *, reduce_into=None):
             f"plan's signature {shape}/{plan.buckets[0].dtype_name}")
     with _plan_span(plan), capture_wire_reports() as caught:
         b = plan.buckets[0]
-        with _bucket_ledger(plan, b.dtype_name, b.width):
-            out, flag = _exec_p2p_bucket(b, x, axis_name, perm,
-                                         strategy=plan.strategy,
-                                         use_pallas=plan.use_pallas,
-                                         reduce_into=reduce_into)
+        out, flag = _exec_p2p_bucket(b, x, axis_name, perm,
+                                     strategy=plan.strategy,
+                                     use_pallas=plan.use_pallas,
+                                     reduce_into=reduce_into)
     _emit(plan, caught)
     return out, flag
 
@@ -438,21 +397,19 @@ def execute_kv_transfer(plan: CommPlan, cache, axis_name, perm):
         for b in plan.buckets:
             parts = [leaves[i].reshape(-1) for i, _, _ in b.members]
             bucket = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-            with _bucket_ledger(plan, b.dtype_name, b.width):
-                got, f = _exec_p2p_bucket(b, bucket, axis_name, perm,
-                                          strategy=plan.strategy,
-                                          use_pallas=plan.use_pallas)
+            got, f = _exec_p2p_bucket(b, bucket, axis_name, perm,
+                                      strategy=plan.strategy,
+                                      use_pallas=plan.use_pallas)
             flag = jnp.maximum(flag, f)
             offs = np.cumsum([0] + [m[2] for m in b.members])
             for k, (i, shape, _) in enumerate(b.members):
                 out[i] = got[offs[k]: offs[k + 1]].reshape(shape)
-        with _bucket_ledger(plan, "raw", 0):
-            for i in plan.raw_leaf_ix:
-                out[i] = raw_ppermute(
-                    leaves[i][None] if leaves[i].ndim == 0 else leaves[i],
-                    axis_name, perm)
-                if leaves[i].ndim == 0:
-                    out[i] = out[i][0]
+        for i in plan.raw_leaf_ix:
+            out[i] = raw_ppermute(
+                leaves[i][None] if leaves[i].ndim == 0 else leaves[i],
+                axis_name, perm)
+            if leaves[i].ndim == 0:
+                out[i] = out[i][0]
     _emit(plan, caught)
     return jax.tree_util.tree_unflatten(treedef, out), flag
 
@@ -525,25 +482,23 @@ def execute_wsync(plan: CommPlan, tree, axis_name, perm, *, base=None):
             bucket = codec.concat_members(leaves, b.members)
             bucket_base = (codec.concat_members(base_leaves, b.members)
                            if base_leaves is not None else None)
-            with _bucket_ledger(plan, b.dtype_name, b.width):
-                got, f = wsync_dispatch(
-                    bucket, bucket_base, axis_name, perm,
-                    compressed=b.path == PATH_COMPRESSED, width=b.width,
-                    delta_width=b.delta_width,
-                    delta_lo_width=b.delta_lo_width,
-                    block=b.block, exc_frac=b.exc_frac,
-                    strategy=plan.strategy, fused=b.fused,
-                    encode_fused=b.encode_fused, use_pallas=plan.use_pallas)
+            got, f = wsync_dispatch(
+                bucket, bucket_base, axis_name, perm,
+                compressed=b.path == PATH_COMPRESSED, width=b.width,
+                delta_width=b.delta_width,
+                delta_lo_width=b.delta_lo_width,
+                block=b.block, exc_frac=b.exc_frac,
+                strategy=plan.strategy, fused=b.fused,
+                encode_fused=b.encode_fused, use_pallas=plan.use_pallas)
             flag = jnp.maximum(flag, f)
             for i, leaf in codec.split_members(got, b.members):
                 out[i] = leaf
-        with _bucket_ledger(plan, "raw", 0):
-            for i in plan.raw_leaf_ix:
-                out[i] = raw_ppermute(
-                    leaves[i][None] if leaves[i].ndim == 0 else leaves[i],
-                    axis_name, perm)
-                if leaves[i].ndim == 0:
-                    out[i] = out[i][0]
+        for i in plan.raw_leaf_ix:
+            out[i] = raw_ppermute(
+                leaves[i][None] if leaves[i].ndim == 0 else leaves[i],
+                axis_name, perm)
+            if leaves[i].ndim == 0:
+                out[i] = out[i][0]
     _emit(plan, caught)
     return jax.tree_util.tree_unflatten(treedef, out), flag
 

@@ -273,9 +273,9 @@ class ServeEngine:
         with obs.span("serve:kv_ship"):
             last_err = None
             for _ in range(max(self._kv_max_tries, 1)):
-                wire, plan = ship_cache(one_cache, self.kv_compressor,
-                                        policy=self.kv_policy,
-                                        plan_cache=self.kv_plan_cache)
+                wire = ship_cache(one_cache, self.kv_compressor,
+                                  policy=self.kv_policy,
+                                  plan_cache=self.kv_plan_cache)[0]
                 if self.kv_fault_injector is not None:
                     wire = self.kv_fault_injector(wire)
                 try:
@@ -284,31 +284,10 @@ class ServeEngine:
                     last_err = e
                     obs.metric("serve_kv_retries_total").inc()
                     continue
-                self._observe_kv_drift(wire, plan)
                 return out
             raise WireIntegrityError(
                 f"KV shipment failed integrity {self._kv_max_tries} times"
             ) from last_err
-
-    @staticmethod
-    def _observe_kv_drift(wire, plan) -> None:
-        """Feed one KV shipment's live wire ratio into the drift detector
-        against its plan's compile-time prediction.  The packed host codec
-        is eval_shape-static (stationary traffic observes live ==
-        predicted); the rANS codec's ``used_bytes`` is the data-dependent
-        term a KV distribution shift moves."""
-        if not obs.enabled() or plan is None:
-            return
-        from repro.obs import drift as drift_lib
-
-        live_wire = live_raw = 0
-        for m in wire.get("messages", ()):
-            if hasattr(m, "wire_bytes"):
-                live_wire += m.wire_bytes()
-                live_raw += m.raw_bytes
-        if live_raw > 0 and plan.raw_bytes > 0:
-            drift_lib.observe((plan.key, "host"), plan.kind, plan.ratio,
-                              live_wire / live_raw)
 
     def _next_key(self):
         self._key, k = jax.random.split(self._key)

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.obs import drift as drift_lib
-from repro.obs import regret as regret_lib
 from repro.core import codec, integrity, packing
 from repro.core.policy import CompressionPolicy
 from repro.sched.plan import PATH_COMPRESSED
@@ -282,8 +280,6 @@ class WeightSyncEngine:
             for b in plan.buckets:
                 bucket = codec.concat_members(leaves, b.members)
                 mode, msg = MODE_RAW, None
-                base_bucket = None
-                wire_before = wire
                 if b.path == PATH_COMPRESSED and force != MODE_RAW:
                     # pad to the block grid like the in-mesh wire, so the
                     # plan's eval_shape accounting IS this wire's size (and
@@ -302,7 +298,6 @@ class WeightSyncEngine:
                         if not overflow:  # else: fall through to full
                             with obs.span("sync:d2h", mode=MODE_DELTA):
                                 mode, msg = MODE_DELTA, jax.device_get(m)
-                            _count_exceptions(msg)
                             wire += m.wire_bytes()
                             used_delta = True
                     if msg is None:
@@ -328,24 +323,10 @@ class WeightSyncEngine:
                     with obs.span("sync:d2h", mode=MODE_RAW):
                         msg = _raw_wire(bucket, b.dtype_name)
                     wire += msg.nbytes
-                bucket_counter.inc(mode=mode)
-                if obs.enabled():
-                    # host-path ledger + offline-recalibration sample: its
-                    # own kind, so the plan-kind exactness check stays
-                    # exact under mixed workloads
-                    with obs.span("obs:sample"):
-                        w_used = {MODE_DELTA: b.delta_width,
-                                  MODE_FULL: b.width}.get(mode, 0)
-                        raw_b = (int(bucket.size)
-                                 * jnp.dtype(bucket.dtype).itemsize)
-                        obs.metric("bucket_wire_raw_bytes_total").inc(
-                            raw_b, kind="wsync_host", dtype=b.dtype_name,
-                            width=w_used)
-                        obs.metric("bucket_wire_bytes_total").inc(
-                            wire - wire_before, kind="wsync_host",
-                            dtype=b.dtype_name, width=w_used)
-                        regret_lib.record_sample("wsync_host", b.dtype_name,
-                                                 bucket, base=base_bucket)
+                with obs.span("obs:sample"):
+                    bucket_counter.inc(mode=mode)
+                    if mode == MODE_DELTA:
+                        _count_exceptions(msg)
                 buckets.append((b.dtype_name, b.members, mode, msg))
             with obs.span("sync:d2h", mode="leaves"):
                 raw_leaves = tuple((i, np.asarray(leaves[i]))
@@ -362,24 +343,6 @@ class WeightSyncEngine:
             raw_bytes=int(raw_total))
         with obs.span("sync:checksum"):
             update.checksum = update_checksum(update)
-        if obs.enabled() and force is None and raw_total > 0:
-            # drift: the plan PREDICTS this send's mode mix (delta when a
-            # base is acked and the widths are calibrated, full otherwise);
-            # every wire size below is eval_shape-static, so a stationary
-            # workload observes live == predicted EXACTLY and only the
-            # data-dependent fallbacks (delta/full overflow) can diverge —
-            # which is precisely the stale-calibration signal.
-            comp = [bb for bb in plan.buckets if bb.compressed]
-            delta_planned = (base_leaves is not None
-                             and any(bb.delta_width for bb in comp))
-            pred = ((plan.delta_wire_bytes if delta_planned
-                     else plan.wire_bytes)
-                    + sum(bb.raw_bytes for bb in plan.buckets
-                          if not bb.compressed)
-                    + sum(arr.nbytes for _, arr in raw_leaves))
-            with obs.span("obs:sample"):
-                drift_lib.observe((plan.key, "host"), plan.kind,
-                                  pred / raw_total, update.ratio)
         return update
 
     def ack(self, replica, version: int, epoch: Optional[int] = None) -> bool:

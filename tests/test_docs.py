@@ -158,17 +158,6 @@ def test_obs_span_convention_documented():
         f"Observability section does not mention spans: {missing}")
 
 
-def test_observatory_machinery_documented():
-    """The flight-recorder/regret/drift subsection names the modules the
-    observatory is built from and the tools it feeds."""
-    section = _obs_section()
-    for needle in ("obs/recorder.py", "obs/regret.py", "obs/drift.py",
-                   "check_ledger_exactness", "width_regret",
-                   "REPRO_OBS_RING_CAP", "BENCH_TRAJECTORY.json"):
-        assert needle in section, (
-            f"Observability section does not mention {needle}")
-
-
 # ---------------------------------------------------------------------------
 # Broadcast-schedule section: the kind table IS sched.plan.BROADCAST_KINDS
 # ---------------------------------------------------------------------------

@@ -1,6 +1,11 @@
 """Observability layer: registry semantics, span tracing, Chrome-trace
-export, runtime instrumentation, and the REPRO_OBS=0 no-op contract."""
+export, runtime instrumentation, the REPRO_OBS=0 no-op contract, and the
+perf trajectory file."""
+import collections
+import gc
 import json
+import os
+import re
 import threading
 
 import jax
@@ -112,6 +117,28 @@ def test_disabled_mode_is_noop():
         obs.metric("typo_total")  # names still validated when disabled
 
 
+def test_disabled_mode_noops():
+    """Disabled, every canonical metric is the shared no-op: writes of
+    each kind leave the registry empty, and typos still raise."""
+    obs.set_enabled(False)
+    for spec in obs.METRICS:
+        m = obs.metric(spec.name)
+        assert m is obs.NOOP_METRIC
+        labels = {k: "x" for k in spec.labels}
+        if spec.kind == "counter":
+            m.inc(3, **labels)
+        elif spec.kind == "gauge":
+            m.set(3, **labels)
+        else:
+            m.observe(3, **labels)
+    obs.set_enabled(True)
+    assert obs.snapshot() == {"counters": {}, "gauges": {},
+                              "histograms": {}}
+    obs.set_enabled(False)
+    with pytest.raises(KeyError):
+        obs.metric("not_a_metric")  # typo check stays on while disabled
+
+
 # ---------------------------------------------------------------------------
 # span tracer + Chrome trace
 # ---------------------------------------------------------------------------
@@ -165,7 +192,8 @@ def test_chrome_trace_schema(tmp_path):
 # runtime instrumentation
 # ---------------------------------------------------------------------------
 
-def _run_plan_psum():
+def _run_plan(kind):
+    """One plan execution of ``kind`` on a one-device ``data`` mesh."""
     from jax.sharding import PartitionSpec as P
 
     from repro import sched
@@ -174,23 +202,28 @@ def _run_plan_psum():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
     pol = CompressionPolicy(min_bytes=0)
     cache = sched.PlanCache()
-    tree = {"w": jnp.arange(4096, dtype=jnp.float32)}
-
-    def fn(t):
-        return sched.psum_with_plan(t, "data", policy=pol, cache=cache)
-
-    f = jax.shard_map(fn, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
+    x = jnp.arange(4096, dtype=jnp.float32)
+    run = {
+        "psum": lambda v: sched.psum_with_plan(
+            {"w": v}, "data", policy=pol, cache=cache),
+        "reduce_scatter": lambda v: sched.reduce_scatter_with_plan(
+            v, "data", policy=pol, cache=cache),
+        "all_gather": lambda v: sched.all_gather_with_plan(
+            v, "data", policy=pol, cache=cache),
+    }[kind]
+    f = jax.shard_map(run, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
                       axis_names={"data"}, check_vma=False)
-    return f(tree)
+    return f(x)
 
 
-def test_executor_metrics_agree_with_wire_reports():
+@pytest.mark.parametrize("kind", ["psum", "reduce_scatter", "all_gather"])
+def test_executor_metrics_agree_with_wire_reports(kind):
     """The acceptance contract: per-kind wire totals in the snapshot ==
     summarize_wire_reports over the plan:* reports of the same run."""
     from repro.roofline.analysis import summarize_wire_reports
 
     policy_mod.clear_wire_reports()
-    _run_plan_psum()
+    _run_plan(kind)
     reports = [r for r in policy_mod.wire_reports()
                if r.name.startswith("plan:")]
     assert reports, "plan execution must emit a consolidated report"
@@ -207,12 +240,25 @@ def test_executor_metrics_agree_with_wire_reports():
             f"kind={kind}"] == d["raw_bytes"]
         assert snap["counters"]["plan_wire_bytes_total"][
             f"kind={kind}"] == d["wire_bytes"]
-    assert snap["counters"]["plan_exec_total"] == {"kind=psum": 1}
-    ratio = snap["gauges"]["plan_wire_ratio"]["kind=psum"]
+    assert [r.name for r in reports] == [f"plan:{kind}"]
+    assert reports[0].raw_bytes > 0
+    assert snap["counters"]["plan_exec_total"] == {f"kind={kind}": 1}
+    ratio = snap["gauges"]["plan_wire_ratio"][f"kind={kind}"]
     assert ratio == pytest.approx(reports[-1].ratio)
-    # the execution also left a plan:psum span and cache events
+    # the execution also left a plan:<kind> span and cache events
     names = [s.name for s in obs.spans()]
-    assert "plan:psum" in names and "plan_cache:compile" in names
+    assert f"plan:{kind}" in names and "plan_cache:compile" in names
+
+
+def test_plan_wire_ratio_hist_and_gauge():
+    """One plan execution populates the labeled ratio histogram, and the
+    last-ratio gauge is kept alongside it."""
+    _run_plan("psum")
+    snap = obs.snapshot()
+    h = snap["histograms"]["plan_wire_ratio_hist"]["kind=psum"]
+    assert h["count"] == 1
+    assert snap["gauges"]["plan_wire_ratio"]["kind=psum"] == \
+        pytest.approx(h["sum"])
 
 
 def test_cache_instrumentation_and_gauges():
@@ -242,34 +288,127 @@ def test_kernel_fallback_mirror():
     kernels.clear_fallbacks()
 
 
-def test_sync_engine_instrumentation():
+SYNC_DTYPES = [jnp.bfloat16, jnp.float16, jnp.float32]
+
+
+def _bucket_modes(*updates):
+    return collections.Counter(
+        f"mode={m}" for u in updates for _, _, m, _ in u.buckets)
+
+
+@pytest.mark.parametrize("force", [None, "full", "raw"])
+@pytest.mark.parametrize("dtype", SYNC_DTYPES)
+def test_sync_engine_instrumentation(dtype, force):
     from repro.core.policy import CompressionPolicy
     from repro.sync.engine import WeightSyncEngine, apply_update
 
-    params = {"w": jnp.asarray(np.linspace(0, 1, 4096), jnp.bfloat16)}
+    params = {"w": jnp.asarray(np.linspace(0, 1, 4096), dtype)}
     eng = WeightSyncEngine(policy=CompressionPolicy(min_bytes=0))
     v1 = eng.publish(params)
-    upd = eng.update_for("r0")
+    upd = eng.update_for("r0", force=force)
     apply_update(upd)
     eng.ack("r0", v1)
-    upd2 = eng.update_for("r0")  # base moved to v1: fresh (delta) encode
-    upd3 = eng.update_for("r0")  # same (version, base): memo hit
+    # unforced, the base moved to v1: a fresh (delta) encode; a forced
+    # update ignores the base, so it is the memo of the first
+    upd2 = eng.update_for("r0", force=force)
+    upd3 = eng.update_for("r0", force=force)  # same key: memo hit
     assert upd3 is upd2
+    encoded = [upd, upd2] if force is None else [upd]
+    assert (upd2 is upd) == (force is not None)
+    modes = _bucket_modes(*encoded)
+    if force == "raw":
+        assert set(modes) == {"mode=raw"}
+    elif force == "full":
+        assert "mode=delta" not in modes
+    else:
+        assert upd2.mode == "delta"
     snap = obs.snapshot()
     assert snap["counters"]["sync_publish_total"] == {"": 1}
-    assert sum(snap["counters"]["sync_updates_total"].values()) == 2
-    assert sum(snap["counters"]["sync_buckets_total"].values()) >= 2
-    assert snap["counters"]["sync_memo_hits_total"] == {"": 1}
+    assert sum(snap["counters"]["sync_updates_total"].values()) == \
+        len(encoded)
+    assert snap["counters"]["sync_buckets_total"] == dict(modes)
+    assert snap["counters"]["sync_memo_hits_total"] == {
+        "": 3 - len(encoded)}
     wire = sum(snap["counters"]["sync_update_wire_bytes_total"].values())
-    assert wire == upd.wire_bytes + upd2.wire_bytes  # exact, by mode
+    assert wire == sum(u.wire_bytes for u in encoded)  # exact, by mode
     assert snap["gauges"]["sync_replica_version_lag"] == {"replica=r0": 0}
     names = [s.name for s in obs.spans()]
     assert "sync:publish" in names and "sync:update" in names
     assert "sync:encode" in names
+    # obs:sample wraps each encoded bucket's counters, once per bucket
+    assert names.count("obs:sample") == sum(modes.values())
     assert any(s.name == "sync:memo_hit" and s.ph == "i"
                for s in obs.spans())
 
 
+def _flip_low_bits(x, rng):
+    """``x`` with a random low mantissa bit pattern XORed into 30% of its
+    elements: a small one-step delta of ``x``'s dtype."""
+    u = np.dtype(f"uint{8 * x.dtype.itemsize}")
+    flip = rng.integers(0, 8, x.size).astype(u)
+    flip[rng.random(x.size) > 0.3] = 0
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(x, u) ^ jnp.asarray(flip), x.dtype)
+
+
+def _wire_of(upd):
+    """Every byte an update ships, with its dtypes, in order, copied out
+    of the update (on the CPU a raw wire may view its device buffer)."""
+    arrays = [np.asarray(a) for _, _, _, msg in upd.buckets
+              for a in jax.tree_util.tree_leaves(msg)]
+    arrays += [np.asarray(a) for _, a in upd.raw_leaves]
+    return ([(a.dtype.str, a.shape, a.tobytes()) for a in arrays],
+            [(d, m, mode) for d, m, mode, _ in upd.buckets],
+            upd.wire_bytes, upd.raw_bytes, upd.checksum, upd.base_version)
+
+
+def _sync_rounds(dtype, force, n_rounds, *, on_round=None):
+    """``n_rounds`` publish/update/ack rounds of one 2**17-element leaf
+    through an engine that retains 2 versions; returns each round's
+    ``(mode, _wire_of(update))``.  ``on_round(i)`` runs after round ``i``
+    with no array or update of the test's own still referenced."""
+    from repro.core.policy import CompressionPolicy
+    from repro.sync.engine import WeightSyncEngine
+
+    rng = np.random.default_rng(3)
+    eng = WeightSyncEngine(policy=CompressionPolicy(min_bytes=0),
+                           history=2)
+    w = jnp.asarray(rng.normal(0, 0.02, 1 << 17), dtype)
+    rounds = []
+    for i in range(n_rounds):
+        if i:
+            w = _flip_low_bits(w, rng)
+        version = eng.publish({"w": w})
+        upd = eng.update_for("r0", force=force)
+        eng.ack("r0", version, upd.epoch)
+        rounds.append((upd.mode, _wire_of(upd)))
+        del upd
+        if on_round is not None:
+            on_round(i)
+    return rounds
+
+
+@pytest.mark.parametrize("force", [None, "full", "raw"])
+@pytest.mark.parametrize("dtype", SYNC_DTYPES)
+def test_observation_never_changes_or_keeps_the_wire(dtype, force):
+    """With obs on and off, every round ships the same bytes, sizes and
+    CRC; and once the store's history is full, more rounds leave the
+    count of live device arrays flat: the observation keeps nothing."""
+    n_rounds, settled = 6, 3  # history 2 is full, and warm, by round 3
+    live = {}
+
+    def count_live(i):
+        if i >= settled:
+            gc.collect()
+            live[i] = len(jax.live_arrays())
+
+    on = _sync_rounds(dtype, force, n_rounds, on_round=count_live)
+    obs.set_enabled(False)
+    off = _sync_rounds(dtype, force, n_rounds)
+    if force is None:
+        assert [m for m, _ in on[1:]] == ["delta"] * (n_rounds - 1)
+    assert on == off
+    assert len(set(live.values())) == 1, live
 def test_sync_delta_exception_counters():
     """After one delta update the exception counters read, per plane, the
     exceptions the delta really has and the lists' static capacity."""
@@ -419,3 +558,56 @@ def test_dump_cli_sync_target(tmp_path):
     assert md.startswith("| metric | type | labels | value |")
     with pytest.raises(KeyError):
         dump_mod.dump("no_such_target", str(tmp_path))
+
+
+# ---------------------------------------------------------------------------
+# static guard: every obs name literal in the runtime resolves
+# ---------------------------------------------------------------------------
+
+def test_every_obs_name_literal_resolves():
+    """Grep every string-literal obs.metric/span/instant call under
+    src/repro/ and resolve it against obs.names — an instrumented call
+    site cannot reference a name the registry does not declare.
+    (f-string call sites like plan:<kind> are covered by the span-name
+    table test instead.)"""
+    from repro.obs import names
+    from repro.sched.compile import PLAN_KINDS
+
+    span_names = {n for n, _, _ in names.SPANS}
+    # "plan:<kind>" is a templated family: accept its instantiations
+    span_names |= {f"plan:{k}" for k in PLAN_KINDS}
+    pat = re.compile(
+        r"""obs\s*\.\s*(metric|span|instant)\(\s*["']([^"']+)["']""")
+    src = os.path.join(os.path.dirname(__file__), "..", "src", "repro")
+    unknown, hits = [], 0
+    for root, _, files in os.walk(src):
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            with open(os.path.join(root, fn)) as f:
+                text = f.read()
+            for what, name in pat.findall(text):
+                hits += 1
+                table = names.SPECS if what == "metric" else span_names
+                if name not in table:
+                    unknown.append((fn, what, name))
+    assert hits > 30, "the grep found implausibly few call sites"
+    assert not unknown, f"unresolvable obs names: {unknown}"
+
+
+# ---------------------------------------------------------------------------
+# perf trajectory
+# ---------------------------------------------------------------------------
+
+def test_append_trajectory(tmp_path):
+    from benchmarks.common import append_trajectory
+
+    path = str(tmp_path / "traj.json")
+    append_trajectory({"date": "d1", "source": "s"}, path)
+    append_trajectory({"date": "d2", "source": "s"}, path)
+    recs = json.load(open(path))
+    assert [r["date"] for r in recs] == ["d1", "d2"]
+    with open(path, "w") as f:
+        f.write("not json{")
+    append_trajectory({"date": "d3", "source": "s"}, path)  # recovers
+    assert [r["date"] for r in json.load(open(path))] == ["d3"]
