@@ -129,6 +129,10 @@ METRICS = (
                "core/integrity.py",
                "bytes of arrays hashed by the wire CRC-32, by path (view: "
                "read in place; copy: copied once into C order)"),
+    MetricSpec("wire_crc_pooled_bytes_total", "counter", (),
+               "core/integrity.py",
+               "bytes of arrays hashed by the wire CRC-32 as whole chunks "
+               "on its thread pool"),
     # -- sync/fleet.py
     MetricSpec("sync_integrity_failures_total", "counter", ("reason",),
                "sync/fleet.py",

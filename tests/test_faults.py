@@ -10,8 +10,9 @@ Quick-gate coverage:
     survives the round trip and catches a single flipped bit;
   * forced full/raw escalation encodes remain bit-exact;
   * ``crc32_tree`` equals the copying formula (dtype, shape, C-order
-    bytes) for every dtype and layout, and reads C-contiguous arrays in
-    place (one copy for any other layout);
+    bytes) for every dtype and layout, and at every size around the
+    pooled chunks' boundaries; reads C-contiguous arrays in place (one
+    copy for any other layout); its pool stays under its thread cap;
   * KV wires (``pack_cache``) verify their checksum before decode;
     ``ServeEngine`` rejects corrupt ingests and retries corrupt KV
     shipments within a bounded budget;
@@ -23,7 +24,9 @@ Quick-gate coverage:
     corruptions.
 """
 import dataclasses
+import os
 import shutil
+import threading
 import tracemalloc
 import zlib
 
@@ -32,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import codec
+from repro.core import codec, integrity
 from repro.core.integrity import (WireIntegrityError, crc32_tree, flip_bit)
 from repro.core.policy import CompressionPolicy
 from repro.runtime.faults import (FaultConfig, FaultEvent, FaultPlan,
@@ -307,6 +310,130 @@ def test_crc32_tree_matches_copying_formula_delta_message():
                                               lo_width=3))
     assert crc32_tree(msg) == _crc32_tree_copying(msg)
     assert crc32_tree(msg, seed=11) == _crc32_tree_copying(msg, seed=11)
+
+
+SMALL_CHUNK = 4096
+
+CRC_CHUNKED = {
+    "0": lambda s: _random_array(np.uint8, (0,), s),
+    "1": lambda s: _random_array(np.uint8, (1,), s),
+    "chunk-1": lambda s: _random_array(np.uint8, (SMALL_CHUNK - 1,), s),
+    "chunk": lambda s: _random_array(np.uint8, (SMALL_CHUNK,), s),
+    "chunk+1": lambda s: _random_array(np.uint8, (SMALL_CHUNK + 1,), s),
+    "2chunk": lambda s: _random_array(np.uint8, (2 * SMALL_CHUNK,), s),
+    "2chunk+1": lambda s: _random_array(np.uint8, (2 * SMALL_CHUNK + 1,), s),
+    "7chunk+13": lambda s: _random_array(np.uint8, (7 * SMALL_CHUNK + 13,), s),
+    "bf16_2d": lambda s: _random_array(jnp.bfloat16, (96, 130), s),
+    "fortran": lambda s: np.asfortranarray(
+        _random_array(np.float32, (96, 130), s)),
+    "0d": lambda s: _random_array(np.float32, (), s),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CRC_CHUNKED))
+def test_crc32_tree_matches_copying_formula_chunked(case, monkeypatch):
+    """Arrays of two or more whole chunks are hashed on the pool and the
+    chunk CRCs folded: the value is the single call's, at every size
+    around the chunk boundaries, for every layout, and chained from any
+    seed."""
+    monkeypatch.setattr(integrity, "_CRC_CHUNK", SMALL_CHUNK)
+    a = CRC_CHUNKED[case](5)
+    b = CRC_CHUNKED[case](6)
+    assert crc32_tree(a) == _crc32_tree_copying(a)
+    chained = crc32_tree(b, seed=crc32_tree(a, seed=0xDEADBEEF))
+    assert chained == _crc32_tree_copying(
+        b, seed=_crc32_tree_copying(a, seed=0xDEADBEEF))
+    assert crc32_tree({"a": a, "b": (b, 1)}, seed=0x12345678) == \
+        _crc32_tree_copying({"a": a, "b": (b, 1)}, seed=0x12345678)
+
+
+def test_crc32_tree_matches_copying_formula_real_chunk():
+    a = _random_array(np.uint8, (3 * integrity._CRC_CHUNK + 5,), 8)
+    assert crc32_tree(a) == _crc32_tree_copying(a)
+    assert crc32_tree(a, seed=0xC0FFEE) == _crc32_tree_copying(
+        a, seed=0xC0FFEE)
+
+
+@pytest.mark.parametrize("nbytes", [1, 5, SMALL_CHUNK, SMALL_CHUNK + 3,
+                                    "real"])
+def test_crc32_append_zeros_combines_chunks(nbytes):
+    """``M_L(c) ^ crc(B) == crc(B, c)`` for ``len(B) == L``, over random
+    seeds and contents: the fold of chunk CRCs is exact."""
+    if nbytes == "real":
+        nbytes = integrity._CRC_CHUNK
+    rng = np.random.default_rng(nbytes)
+    for _ in range(4):
+        chunk = rng.integers(0, 256, nbytes, dtype=np.uint8)
+        for c in (0, 0xFFFFFFFF, *rng.integers(0, 1 << 32, 6).tolist()):
+            assert (integrity._append_zeros(c, nbytes) ^ zlib.crc32(chunk)
+                    == zlib.crc32(chunk, c))
+
+
+def test_crc32_pool_stays_under_its_cap(monkeypatch):
+    monkeypatch.setattr(integrity, "_CRC_CHUNK", SMALL_CHUNK)
+    cap = min(integrity._POOL_CAP, len(os.sched_getaffinity(0)))
+    assert integrity._POOL._max_workers == cap
+    a = _random_array(np.uint8, (64 * SMALL_CHUNK + 1,), 9)
+    for _ in range(3):
+        assert crc32_tree(a) == _crc32_tree_copying(a)
+    assert len(integrity._POOL._threads) <= cap
+    assert sum(t.name.startswith("wire-crc")
+               for t in threading.enumerate()) <= cap
+
+
+def test_crc32_tree_concurrent_callers_share_the_pool(monkeypatch):
+    """Callers on several threads at once share the one pool and each
+    gets its own array's single-call value."""
+    import sys
+
+    monkeypatch.setattr(integrity, "_CRC_CHUNK", SMALL_CHUNK)
+    arrays = [_random_array(np.uint8, ((9 + i) * SMALL_CHUNK + i,), 20 + i)
+              for i in range(12)]
+    want = [_crc32_tree_copying(a) for a in arrays]
+    got = [None] * len(arrays)
+
+    def hash_all(i):
+        for _ in range(5):
+            got[i] = crc32_tree(arrays[i])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=hash_all, args=(i,))
+                   for i in range(len(arrays))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == want
+
+
+def test_crc32_tree_counts_pooled_bytes(monkeypatch):
+    """Only the whole chunks of an array of two or more are hashed on the
+    pool; a smaller array takes the single call and counts nothing."""
+    from repro import obs
+
+    monkeypatch.setattr(integrity, "_CRC_CHUNK", SMALL_CHUNK)
+    obs.set_enabled(True)
+    obs.reset()
+    try:
+        crc32_tree(np.zeros(2 * SMALL_CHUNK - 1, np.uint8))
+        counters = obs.snapshot()["counters"]
+        assert "wire_crc_pooled_bytes_total" not in counters
+        assert counters["wire_crc_bytes_total"] == {
+            "path=view": 2 * SMALL_CHUNK - 1}
+        crc32_tree(np.zeros(3 * SMALL_CHUNK + 7, np.uint8))
+        counters = obs.snapshot()["counters"]
+        assert counters["wire_crc_pooled_bytes_total"] == {
+            "": 3 * SMALL_CHUNK}
+        assert counters["wire_crc_bytes_total"] == {
+            "path=view": 5 * SMALL_CHUNK + 6}
+    finally:
+        obs.set_enabled(None)
+        obs.reset()
 
 
 def test_crc32_tree_hashes_in_place():
