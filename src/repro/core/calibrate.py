@@ -11,6 +11,7 @@ the in-wire ``overflow`` flag catches violations exactly (packing.py).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +58,7 @@ def width_cost_curve(
     width).  :func:`choose_width` picks from this curve.
     """
     exp, _ = codec.split_planes(x)
-    return _exp_cost_curve(exp, codec.layout_of(x.dtype), block,
-                           max_exc_frac)
-
-
-def _exp_cost_curve(exp: jax.Array, lay: codec.FloatLayout, block: int,
-                    max_exc_frac: float) -> tuple:
-    """:func:`width_cost_curve` of a uint8 exponent plane."""
+    lay = codec.layout_of(x.dtype)
     rngs = np.asarray(_exp_range_stats(exp, block))
     ent = float(codec.exponent_entropy_bits(exp, lay.exp_bits))
     n_blocks = len(rngs)
@@ -101,15 +96,15 @@ def choose_width(
     on it for correctness, only for speed).
     """
     curve = width_cost_curve(x, block=block, max_exc_frac=max_exc_frac)
-    return _pick_width(curve, target_exc_rate, margin_bits)
-
-
-def _pick_width(curve: tuple, target_exc_rate: float,
-                margin_bits: int = 0) -> WidthChoice:
     for c in curve:
         if c.est_exc_rate <= target_exc_rate or c.width == curve[-1].width:
             return curve[min(c.width + margin_bits, curve[-1].width) - 1]
     raise AssertionError("unreachable: the last width always matches")
+
+
+# elements per step of choose_delta_widths' statistics: its temporaries
+# scale with this, not with the tree
+_CAL_SLICE = 1 << 24
 
 
 def choose_delta_widths(
@@ -119,27 +114,51 @@ def choose_delta_widths(
     """Calibrate the XOR-delta wire's (exp_width, lo_width) from live data.
 
     ``x``/``base`` are two consecutive weight versions (or representative
-    twins).  The exponent-delta width reuses :func:`choose_width` on the
-    delta bit pattern; the lo width is the smallest W whose per-ELEMENT
+    twins).  The exponent-delta width is :func:`choose_width`'s on the
+    delta bit pattern: the smallest W at which at most ``target_exc_rate``
+    of the blocks escape; the lo width is the smallest W whose per-ELEMENT
     escape rate stays under half the exception capacity (the lo packer
     escapes per element, not per block — the XOR carry tail is heavy but
-    element-local).  Store the result in
-    ``CompressionProfile.widths["delta"/"delta_lo"]`` to drive
+    element-local).  The escape counts at every width are summed over
+    slices of ``_CAL_SLICE`` elements, one jitted program each.  Store the
+    result in ``CompressionProfile.widths["delta"/"delta_lo"]`` to drive
     ``CompressionPolicy.delta_widths``."""
     lay = codec.layout_of(x.dtype)
-    # the delta stays a bit pattern: as a float it is mostly subnormal
-    exp, lo = codec.split_bits(
-        codec.xor_bits(x.reshape(-1), base.reshape(-1)), lay)
-    w_exp = _pick_width(_exp_cost_curve(exp, lay, block, max_exc_frac),
-                        target_exc_rate).width
-    lo = np.asarray(lo.astype(jnp.uint32))
+    x, base = x.reshape(-1), base.reshape(-1)
+    n = x.shape[0]
+    step = max(_CAL_SLICE // block, 1) * block  # slices hold whole blocks
+    parts = jax.device_get([
+        _delta_escapes(x, base, start, size=min(step, n - start), block=block)
+        for start in range(0, n, step)])
+    # summed on the host in int64: a slice's int32 counts cannot overflow,
+    # a whole tree's might
+    exp_esc, lo_esc = (np.sum([np.asarray(p[i], np.int64) for p in parts],
+                              axis=0) for i in (0, 1))
+    n_blocks = -(-n // block)
+    w_exp = next((w for w in range(1, lay.exp_bits + 1)
+                  if exp_esc[w - 1] / n_blocks <= target_exc_rate),
+                 lay.exp_bits)
     budget = max_exc_frac / 2  # leave half the capacity as drift headroom
-    w_lo = lay.lo_bits
-    for w in range(1, lay.lo_bits + 1):
-        if float(np.mean(lo >= (1 << w))) <= budget:
-            w_lo = w
-            break
+    w_lo = next((w for w in range(1, lay.lo_bits + 1)
+                 if lo_esc[w - 1] / n <= budget), lay.lo_bits)
     return int(w_exp), int(w_lo)
+
+
+@partial(jax.jit, static_argnames=("size", "block"))
+def _delta_escapes(x, base, start, *, size: int, block: int):
+    """Escapes of the delta of ``x[start:start + size]``: blocks whose
+    exponent range needs more than W bits, and elements whose lo plane
+    does, each for W = 1..bits of its plane (int32)."""
+    lay = codec.layout_of(x.dtype)
+    exp, lo = codec.split_bits(codec.xor_bits(
+        jax.lax.dynamic_slice_in_dim(x, start, size),
+        jax.lax.dynamic_slice_in_dim(base, start, size)), lay)
+    rng = _exp_range_stats(exp, block)
+    lo = lo.astype(jnp.uint32)
+    return (jnp.stack([jnp.sum(rng >= (1 << w), dtype=jnp.int32)
+                       for w in range(1, lay.exp_bits + 1)]),
+            jnp.stack([jnp.sum(lo >= jnp.uint32(1 << w), dtype=jnp.int32)
+                       for w in range(1, lay.lo_bits + 1)]))
 
 
 @dataclasses.dataclass(frozen=True)

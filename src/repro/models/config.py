@@ -16,10 +16,19 @@ from typing import Optional, Sequence
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int = 0  # routed experts
+    n_experts: int = 0  # routed experts: the router's width
     top_k: int = 0
     n_shared: int = 0  # always-on shared experts
     d_expert: int = 0  # expert FFN hidden size
+    # expert parallelism: the layer holds experts [first_held, first_held +
+    # n_held) of the n_experts it routes over (n_held 0: all of them)
+    n_held: int = 0
+    first_held: int = 0
+    norm_topk: bool = True  # renormalise the top-k gates to sum to 1
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +36,15 @@ class MLACfg:
     kv_lora: int = 512  # latent dim for compressed KV
     q_lora: int = 0  # 0 = full-rank queries
     rope_dim: int = 64  # decoupled RoPE sub-dim per head
+    kv_norm: bool = False  # RMSNorm on the latent before its up-projection
+    # YaRN frequencies on the rope dims (DeepSeek-V2's ``rope_scaling``);
+    # factor 1 is plain RoPE
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +99,17 @@ class ArchConfig:
     sub_quadratic: bool = False  # eligible for long_500k
     notes: str = ""
 
+    def __post_init__(self):
+        # a configuration file gives the layer specs and the nested
+        # configs as JSON lists and dicts
+        for name in ("prefix", "pattern"):
+            object.__setattr__(self, name, tuple(
+                s if isinstance(s, LayerSpec) else LayerSpec(**s)
+                for s in getattr(self, name)))
+        for name, cls in (("moe", MoECfg), ("mla", MLACfg)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, cls(**getattr(self, name)))
+
     @property
     def n_layers(self) -> int:
         return len(self.prefix) + len(self.pattern) * self.repeats
@@ -131,7 +160,8 @@ class ArchConfig:
             kv_down = d * (m.kv_lora + m.rope_dim)
             kv_up = m.kv_lora * self.n_heads * 2 * hd
             o = self.n_heads * hd * d
-            return q + kv_down + kv_up + o
+            norm = m.kv_lora if m.kv_norm else 0
+            return q + kv_down + kv_up + o + norm
         if mixer == "mamba":
             di = self.mamba.expand * d
             return (
@@ -156,7 +186,7 @@ class ArchConfig:
             return 3 * d * self.d_ff
         if ffn == "moe":
             m = self.moe
-            routed = m.n_experts * 3 * d * m.d_expert
+            routed = m.held * 3 * d * m.d_expert
             shared = m.n_shared * 3 * d * m.d_expert
             router = d * m.n_experts
             return routed + shared + router

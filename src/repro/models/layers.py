@@ -14,6 +14,7 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.models.config import ArchConfig, LayerSpec
+from repro.models.config import ArchConfig, LayerSpec, MLACfg
 
 # ---------------------------------------------------------------------------
 # init helpers
@@ -43,11 +44,36 @@ def rms_norm(x, w, eps):
 # sections are stubbed per the assignment: frontends provide embeddings).
 # ---------------------------------------------------------------------------
 
-def rope_table(positions, dim, theta):
-    """positions (..., S) -> cos/sin (..., S, dim//2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_table(positions, dim, theta, inv=None):
+    """positions (..., S) -> cos/sin (..., S, dim//2); ``inv`` replaces the
+    frequencies ``theta**(-2i/dim)``."""
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     ang = positions.astype(jnp.float32)[..., None] * inv
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-scale factor (``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, m: MLACfg) -> np.ndarray:
+    """DeepSeek-V2's YaRN frequencies (``DeepseekV2YarnRotaryEmbedding``):
+    each of the ``dim // 2`` frequencies blends ``theta**(-2i/dim)`` with
+    it divided by the factor, by a ramp clamped to [0, 1] that runs from
+    ``low`` to ``high``, the dims at which ``beta_fast`` and ``beta_slow``
+    rotations fit into the original context."""
+    def corr_dim(rotations):
+        return (dim * math.log(m.yarn_original_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(m.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(m.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2) / dim)
+    return (extra / m.yarn_factor * ramp + extra * (1 - ramp)).astype(
+        np.float32)
 
 
 def apply_rope(x, cos, sin, interleaved: bool = False):
@@ -253,18 +279,19 @@ def _make_flash(causal: bool, window, q_chunk: int, kv_chunk: int):
 
 
 def _attend_chunked(q, k, v, *, causal, window, q_offset=0, q_chunk=512,
-                    kv_chunk=1024):
+                    kv_chunk=1024, scale=None):
     """Double-chunked flash attention (pure jnp, custom tiled VJP).
 
     q (B,Sq,H,hd), k/v (B,Sk,Hkv,hd).  Memory in both directions is bounded
     by one (B,Hkv,G,q_chunk,kv_chunk) f32 score tile.  ``q_offset`` shifts
     query positions (must be a static int here; decode uses
-    ``_decode_attend``)."""
+    ``_decode_attend``); ``scale`` replaces the softmax scale
+    ``1/sqrt(hd)``."""
     assert q_offset == 0, "non-zero q_offset not used by current callers"
     B, Sq, H, hd = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
     if Sq % q_chunk:
@@ -346,16 +373,17 @@ def attention(params, x, cfg: ArchConfig, *, spec: LayerSpec, positions,
     return out.reshape(B, S, -1) @ params["wo"], new_cache
 
 
-def _decode_attend(q, ck, cv, cache_pos, window, cp_axis):
+def _decode_attend(q, ck, cv, cache_pos, window, cp_axis, scale=None):
     """Single-token decode attention over the cache (q (B,1,H,hd)).
 
     With cp_axis set, ck/cv hold only this shard's sequence slice; partial
     attention is combined across shards with a logsumexp reduction (the
-    sequence-parallel decode path for long_500k)."""
+    sequence-parallel decode path for long_500k).  ``scale`` replaces the
+    softmax scale ``1/sqrt(hd)``."""
     B, _, H, hd = q.shape
     Hkv = ck.shape[2]
     G = H // Hkv
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     s_loc = ck.shape[1]
     if cp_axis is None:
         kpos = jnp.arange(s_loc)
@@ -400,31 +428,57 @@ def init_mla(key, cfg: ArchConfig, dtype):
         "wq": _dense_init(ks[4], (d, cfg.n_heads * (hd + m.rope_dim)), dtype),
         "wo": _dense_init(ks[5], (cfg.n_heads * hd, d), dtype),
     }
+    if m.kv_norm:
+        p["kv_norm"] = jnp.ones((m.kv_lora,), dtype)
     return p
 
 
 def spec_mla(cfg: ArchConfig):
-    return {
+    s = {
         "w_dkv": P(None, None), "w_krope": P(None, None),
         "w_uk": P(None, "model"), "w_uv": P(None, "model"),
         "wq": P(None, "model"), "wo": P("model", None),
     }
+    if cfg.mla.kv_norm:
+        s["kv_norm"] = P(None)
+    return s
+
+
+def _mla_rope(positions, cfg: ArchConfig):
+    """cos/sin of the rope dims, YaRN's where ``cfg.mla`` asks for it, and
+    the softmax scale (None: ``1/sqrt(hd + rope_dim)``), which YaRN
+    multiplies by ``mscale**2`` of ``mscale_all_dim``."""
+    m = cfg.mla
+    if m.yarn_factor == 1.0:
+        cos, sin = rope_table(positions, m.rope_dim, cfg.rope_theta)
+        return cos, sin, None
+    cos, sin = rope_table(positions, m.rope_dim, cfg.rope_theta,
+                          inv=yarn_inv_freq(m.rope_dim, cfg.rope_theta, m))
+    r = (yarn_mscale(m.yarn_factor, m.yarn_mscale)
+         / yarn_mscale(m.yarn_factor, m.yarn_mscale_all_dim))
+    scale = (yarn_mscale(m.yarn_factor, m.yarn_mscale_all_dim) ** 2
+             / np.sqrt(cfg.hd + m.rope_dim))
+    return cos * r, sin * r, scale
 
 
 def mla_attention(params, x, cfg: ArchConfig, *, spec: LayerSpec, positions,
                   cache=None, cache_pos=None, cp_axis=None, prefill=False):
     """Latent attention: the cache stores (c_kv, k_rope) — the MLA memory
-    saving — and per-head K/V are reconstructed from the latent."""
+    saving, the latent after its RMSNorm where ``cfg.mla.kv_norm`` — and
+    per-head K/V are reconstructed from the latent."""
     B, S, D = x.shape
     hd, m = cfg.hd, cfg.mla
     H = cfg.n_heads
     c_kv = x @ params["w_dkv"]  # (B,S,r)
+    if m.kv_norm:
+        c_kv = rms_norm(c_kv, params["kv_norm"], cfg.norm_eps)
     k_rope = x @ params["w_krope"]  # (B,S,rope)
-    cos, sin = rope_table(positions, m.rope_dim, cfg.rope_theta)
-    k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0]
+    cos, sin, scale = _mla_rope(positions, cfg)
+    k_rope = apply_rope(k_rope[:, :, None, :], cos, sin,
+                        cfg.rope_interleaved)[:, :, 0]
     q = (x @ params["wq"]).reshape(B, S, H, hd + m.rope_dim)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
-    q_rope = apply_rope(q_rope, cos, sin)
+    q_rope = apply_rope(q_rope, cos, sin, cfg.rope_interleaved)
 
     new_cache = None
     if cache is not None and prefill:
@@ -456,9 +510,11 @@ def mla_attention(params, x, cfg: ArchConfig, *, spec: LayerSpec, positions,
     )
     q_aug = jnp.concatenate([q_nope, q_rope], axis=-1)
     if cache is not None and not prefill:
-        out = _decode_attend(q_aug, k_aug, v, cache_pos, None, cp_axis)
+        out = _decode_attend(q_aug, k_aug, v, cache_pos, None, cp_axis,
+                             scale)
     else:
-        out = _attend_chunked(q_aug, k_aug, v, causal=True, window=spec.window)
+        out = _attend_chunked(q_aug, k_aug, v, causal=True, window=spec.window,
+                              scale=scale)
     return out.reshape(B, S, -1) @ params["wo"], new_cache
 
 
@@ -488,9 +544,9 @@ def init_moe(key, cfg: ArchConfig, dtype):
     ks = jax.random.split(key, 5)
     p = {
         "router": _dense_init(ks[0], (d, m.n_experts), dtype, scale=0.02),
-        "we1": _dense_init(ks[1], (m.n_experts, d, m.d_expert), dtype),
-        "we3": _dense_init(ks[2], (m.n_experts, d, m.d_expert), dtype),
-        "we2": _dense_init(ks[3], (m.n_experts, m.d_expert, d), dtype),
+        "we1": _dense_init(ks[1], (m.held, d, m.d_expert), dtype),
+        "we3": _dense_init(ks[2], (m.held, d, m.d_expert), dtype),
+        "we2": _dense_init(ks[3], (m.held, m.d_expert, d), dtype),
     }
     if m.n_shared:
         p["shared"] = init_swiglu(ks[4], d, m.n_shared * m.d_expert, dtype)
@@ -537,14 +593,25 @@ def moe(params, x, cfg: ArchConfig, *, capacity_factor: float = 1.25,
 
     Decode regime (T <= dropless_below): capacity is set to T, which is
     provably dropless (an expert can receive at most one slot per token), so
-    single-token decode agrees exactly with prefill."""
+    single-token decode agrees exactly with prefill.
+
+    Expert share (``MoECfg.n_held``): tokens are routed over all
+    ``n_experts``, and the layer computes the part of the result that its
+    held experts give; the shared experts are added whole."""
     B, S, D = x.shape
     m = cfg.moe
+    E = m.held
     T = B * S
     xt = x.reshape(T, D)
     logits = (xt @ params["router"]).astype(jnp.float32)
     gates, eids = jax.lax.top_k(jax.nn.softmax(logits, -1), m.top_k)  # (T,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if E < m.n_experts:
+        # assignments to experts held elsewhere go to row E, which the
+        # slot table drops
+        eids = eids - m.first_held
+        eids = jnp.where((eids >= 0) & (eids < E), eids, E)
 
     if T <= dropless_below:
         C = T
@@ -553,21 +620,21 @@ def moe(params, x, cfg: ArchConfig, *, capacity_factor: float = 1.25,
     flat_e = eids.reshape(-1)  # (T*k,)
     order = jnp.argsort(flat_e)  # stable
     sorted_e = flat_e[order]
-    grp_start = jnp.searchsorted(sorted_e, jnp.arange(m.n_experts))
-    within = jnp.arange(T * m.top_k) - grp_start[sorted_e]
+    grp_start = jnp.searchsorted(sorted_e, jnp.arange(E))
+    within = jnp.arange(T * m.top_k) - grp_start[jnp.minimum(sorted_e, E - 1)]
     keep = within < C
     # slot table: (E, C) -> index into the flat (token, k) assignment list
-    slot = jnp.full((m.n_experts, C), T * m.top_k, jnp.int32)
+    slot = jnp.full((E, C), T * m.top_k, jnp.int32)
     slot = slot.at[sorted_e, jnp.clip(within, 0, C - 1)].set(
         jnp.where(keep, order, T * m.top_k).astype(jnp.int32), mode="drop"
     )
     tok_of_slot = jnp.where(slot < T * m.top_k, slot // m.top_k, T)  # sentinel T
-    tok_of_slot = _expert_sharding_hint(tok_of_slot, m.n_experts)
+    tok_of_slot = _expert_sharding_hint(tok_of_slot, E)
     xg = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[tok_of_slot]  # (E,C,D)
-    xg = _expert_sharding_hint(xg, m.n_experts)
+    xg = _expert_sharding_hint(xg, E)
     h = jnp.einsum("ecd,edf->ecf", jax.nn.silu(jnp.einsum("ecd,edf->ecf", xg, params["we1"])) *
                    jnp.einsum("ecd,edf->ecf", xg, params["we3"]), params["we2"])
-    h = _expert_sharding_hint(h, m.n_experts)
+    h = _expert_sharding_hint(h, E)
     # combine: scatter expert outputs back, weighted by gates
     gate_of_slot = jnp.where(
         slot < T * m.top_k,

@@ -90,6 +90,10 @@ METRICS = (
     MetricSpec("sync_buckets_total", "counter", ("mode",),
                "sync/engine.py",
                "per-bucket wire routing decisions (delta/full/raw)"),
+    MetricSpec("sync_bucket_raw_bytes_total", "counter", ("mode",),
+               "sync/engine.py",
+               "raw bytes of the buckets encoded, by wire mode "
+               "(delta/full/raw)"),
     MetricSpec("sync_memo_hits_total", "counter", (),
                "sync/engine.py",
                "update_for served from the per-(version, base) memo"),
@@ -201,6 +205,9 @@ SPANS = (
      "instant: update served from the per-base memo"),
     ("sync:encode", "sync/engine.py",
      "encoding an update (delta/full/raw per bucket)"),
+    ("sync:bucket", "sync/engine.py",
+     "one plan bucket of an encode: its codec, wire copy and counters "
+     "(args: bucket index, raw bytes, mode)"),
     ("sync:codec", "sync/engine.py",
      "one bucket's device codec, from the encode dispatch through the "
      "host's read of its overflow flag"),

@@ -83,6 +83,42 @@ def _group_leaves(leaves):
     return groups, tuple(raw_ix)
 
 
+# Largest raw bytes of one wsync bucket.  A round's device transients
+# scale with its bucket: the fused bucket and its base, the codec's planes,
+# the replica's decode and split.  Measured on a TPU v5e (16.91 GB
+# usable) at this size: a round takes 11-12 bytes per byte of its largest
+# bucket over what the weight sync holds between rounds (the window's
+# peak less the memory in use after the warm-up rounds).  DeepSeek-V2-
+# Lite's 1.07 GB stage (largest bucket 536 MB) peaks at 13.12 GB, leaving
+# 3.79 GB free (at 1 GiB it peaks at 15.60 GB); GLM-4's 718 MB stage
+# (largest bucket 408 MB) at 8.94 GB, where whole-tree calibration before
+# one bucket per dtype had peaked at 11.86 GB.
+WSYNC_BUCKET_BYTES = 512 << 20
+
+
+def wsync_buckets(leaves) -> tuple:
+    """The wsync schedule's buckets: :func:`_group_leaves`' dtype groups,
+    each cut at leaf boundaries, in leaf order, into runs of at most
+    ``WSYNC_BUCKET_BYTES`` raw bytes; a leaf larger than that is a bucket
+    of its own.  So a round's transients scale with one bucket, not with
+    the tree.  Returns (((dtype_name, members), ...), raw_leaf_indices),
+    ``members`` in the plan IR's ``((leaf_index, shape, size), ...)``."""
+    groups, raw_ix = _group_leaves(leaves)
+    buckets = []
+    for name in sorted(groups):
+        itemsize = jnp.dtype(name).itemsize
+        run, nbytes = [], 0
+        for member in groups[name]:
+            size = member[2] * itemsize
+            if run and nbytes + size > WSYNC_BUCKET_BYTES:
+                buckets.append((name, tuple(run)))
+                run, nbytes = [], 0
+            run.append(member)
+            nbytes += size
+        buckets.append((name, tuple(run)))
+    return tuple(buckets), raw_ix
+
+
 def _probe_bucket(sample_parts, block: int):
     """Compressibility probe on live bucket data -> (width_choice or None)."""
     if sample_parts is None:
@@ -592,9 +628,10 @@ def compile_wsync_plan(tree, axis_name, *, policy, n_dev: int,
     """Compile a weight-sync broadcast schedule (kind "wsync").
 
     Mirrors ``sync/wire.sync_weights`` bit-for-bit: codec-supported leaves
-    fuse into one flat bucket per dtype (``_group_leaves``, the psum rule),
-    each gated/width'd like a ``p2p_send`` of the concatenated bucket at
-    tensor_class "weight", PLUS the XOR-delta schedule — the delta codec
+    fuse into flat buckets of one dtype, cut at leaf boundaries at
+    ``WSYNC_BUCKET_BYTES`` (:func:`wsync_buckets`), each gated/width'd
+    like a ``p2p_send`` of the concatenated bucket at tensor_class
+    "weight", PLUS the XOR-delta schedule — the delta codec
     widths (``policy.delta_widths``) and the expected delta wire bytes —
     recorded per compressed bucket.  Delta-vs-full is a RUNTIME choice per
     receiver (does the receiver hold an acked, epoch-current base
@@ -618,10 +655,9 @@ def compile_wsync_plan(tree, axis_name, *, policy, n_dev: int,
     backend, use_pallas = probe_backend()
     axis = axis_tuple(axis_name)
     leaves, _ = jax.tree_util.tree_flatten(tree)
-    groups, raw_ix = _group_leaves(leaves)
+    groups, raw_ix = wsync_buckets(leaves)
     buckets = []
-    for name in sorted(groups):
-        members = tuple(groups[name])
+    for name, members in groups:
         L = sum(m[2] for m in members)
         bucket = _p2p_bucket(L, name, axis_name, policy=policy, n_dev=n_dev,
                              tensor_class="weight", strategy=strategy)
@@ -657,7 +693,7 @@ def wsync_plan_key(tree, axis_name, policy, strategy: str, n_dev: int,
     return ("wsync", tree_signature(tree), str(strategy),
             axis_tuple(axis_name), int(n_dev),
             policy_fingerprint(policy, "weight"), probe_backend(),
-            sched_key)
+            sched_key, WSYNC_BUCKET_BYTES)
 
 
 # ---------------------------------------------------------------------------

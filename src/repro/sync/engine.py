@@ -7,7 +7,8 @@ workload end to end on the host path (out-of-band, separate-process
 replicas; the in-mesh twin is ``sync/wire.sync_weights`` /
 ``sched.sync_weights_with_plan``):
 
-  * the schedule — per-dtype leaf buckets, compress-vs-raw gates, full and
+  * the schedule — per-dtype leaf buckets of at most
+    ``sched.compile.WSYNC_BUCKET_BYTES``, compress-vs-raw gates, full and
     XOR-delta codec widths, expected wire bytes — comes from a compiled
     kind-"wsync" ``CommPlan`` cached on the weight tree's signature: the
     first publish compiles it, every later publish is a plan-cache hit
@@ -133,16 +134,20 @@ def apply_update(update: SyncUpdate, base_params=None):
                     f"update v{update.version} deltas against "
                     f"v{update.base_version}; apply_update needs "
                     f"base_params")
-            base_bucket = codec.pad_flat_bits(
+            got = packing.decode_delta(msg, codec.pad_flat_bits(
                 codec.concat_members(base_leaves, members),
-                int(np.prod(msg.shape)))
-            got = packing.decode_delta(msg, base_bucket)
+                int(np.prod(msg.shape))))
         elif mode == MODE_FULL:
             got = packing.decode_message(msg)
         else:
             got = _raw_unwire(msg, dtype_name)
         for i, leaf in codec.split_members(got, members):
             leaves[i] = leaf
+        # one bucket's transients at a time: the host would otherwise
+        # enqueue every bucket's decode before the device ran the first,
+        # and the fused bucket would live on through the next decode
+        jax.block_until_ready(got)
+        del got
     for i, arr in update.raw_leaves:
         leaves[i] = jnp.asarray(arr)
     return jax.tree_util.tree_unflatten(update.treedef, leaves)
@@ -166,6 +171,64 @@ def verify_update(update: SyncUpdate) -> bool:
     apply."""
     return (update.checksum is not None
             and update_checksum(update) == update.checksum)
+
+
+def _encode_bucket(k: int, b, leaves, base_leaves, force):
+    """Encode plan bucket ``k`` (``b``): an XOR delta against
+    ``base_leaves`` where it has them and the delta fits, else full, else
+    raw.  Returns (dtype_name, members, mode, host message, wire bytes)."""
+    raw_bytes = sum(m[2] for m in b.members) * jnp.dtype(b.dtype_name).itemsize
+    with obs.span("sync:bucket", bucket=k, raw_bytes=raw_bytes) as sp:
+        bucket = codec.concat_members(leaves, b.members)
+        mode, msg = MODE_RAW, None
+        if b.path == PATH_COMPRESSED and force != MODE_RAW:
+            # pad to the block grid like the in-mesh wire, so the plan's
+            # eval_shape accounting IS this wire's size (and overflow
+            # thresholds match delta_send exactly)
+            bucket = codec.pad_flat_bits(bucket, b.block)
+            if base_leaves is not None and b.delta_width:
+                base_bucket = codec.pad_flat_bits(
+                    codec.concat_members(base_leaves, b.members), b.block)
+                with obs.span("sync:codec", mode=MODE_DELTA):
+                    m = packing.encode_delta(
+                        bucket, base_bucket, width=b.delta_width,
+                        lo_width=b.delta_lo_width, block=b.block,
+                        exc_frac=b.exc_frac)
+                    overflow = int(m.overflow)
+                del base_bucket
+                if not overflow:  # else: fall through to full
+                    with obs.span("sync:d2h", mode=MODE_DELTA):
+                        mode, msg = MODE_DELTA, jax.device_get(m)
+                    wire = m.wire_bytes()
+            if msg is None:
+                with obs.span("sync:codec", mode=MODE_FULL):
+                    m = packing.encode_message(
+                        bucket, width=b.width, block=b.block,
+                        exc_frac=b.exc_frac, fused=b.encode_fused)
+                    overflow = int(m.exp.overflow)
+                if overflow:
+                    # even the full wire's exceptions overflowed
+                    # (pathological exponent spread): ship the bucket raw —
+                    # the host twin of the runtime's retry-uncompressed
+                    # guard.  Never corrupt.
+                    with obs.span("sync:d2h", mode=MODE_RAW):
+                        mode, msg = MODE_RAW, _raw_wire(bucket, b.dtype_name)
+                    wire = msg.nbytes
+                else:
+                    with obs.span("sync:d2h", mode=MODE_FULL):
+                        mode, msg = MODE_FULL, jax.device_get(m)
+                    wire = m.wire_bytes()
+        else:
+            with obs.span("sync:d2h", mode=MODE_RAW):
+                msg = _raw_wire(bucket, b.dtype_name)
+            wire = msg.nbytes
+        sp.args["mode"] = mode
+        with obs.span("obs:sample"):
+            obs.metric("sync_buckets_total").inc(mode=mode)
+            obs.metric("sync_bucket_raw_bytes_total").inc(raw_bytes, mode=mode)
+            if mode == MODE_DELTA:
+                _count_exceptions(msg)
+    return b.dtype_name, b.members, mode, msg, wire
 
 
 class WeightSyncEngine:
@@ -272,73 +335,24 @@ class WeightSyncEngine:
         base_leaves = (jax.tree_util.tree_flatten(base)[0]
                        if base is not None else None)
         buckets = []
-        wire = 0
-        used_delta = False
-        bucket_counter = obs.metric("sync_buckets_total")
         with obs.span("sync:encode", version=version,
                       base=base_version if base_version is not None else -1):
-            for b in plan.buckets:
-                bucket = codec.concat_members(leaves, b.members)
-                mode, msg = MODE_RAW, None
-                if b.path == PATH_COMPRESSED and force != MODE_RAW:
-                    # pad to the block grid like the in-mesh wire, so the
-                    # plan's eval_shape accounting IS this wire's size (and
-                    # overflow thresholds match delta_send exactly)
-                    bucket = codec.pad_flat_bits(bucket, b.block)
-                    if base_leaves is not None and b.delta_width:
-                        base_bucket = codec.pad_flat_bits(
-                            codec.concat_members(base_leaves, b.members),
-                            b.block)
-                        with obs.span("sync:codec", mode=MODE_DELTA):
-                            m = packing.encode_delta(
-                                bucket, base_bucket, width=b.delta_width,
-                                lo_width=b.delta_lo_width, block=b.block,
-                                exc_frac=b.exc_frac)
-                            overflow = int(m.overflow)
-                        if not overflow:  # else: fall through to full
-                            with obs.span("sync:d2h", mode=MODE_DELTA):
-                                mode, msg = MODE_DELTA, jax.device_get(m)
-                            wire += m.wire_bytes()
-                            used_delta = True
-                    if msg is None:
-                        with obs.span("sync:codec", mode=MODE_FULL):
-                            m = packing.encode_message(
-                                bucket, width=b.width, block=b.block,
-                                exc_frac=b.exc_frac, fused=b.encode_fused)
-                            overflow = int(m.exp.overflow)
-                        if overflow:
-                            # even the full wire's exceptions overflowed
-                            # (pathological exponent spread): ship the bucket
-                            # raw — the host twin of the runtime's
-                            # retry-uncompressed guard.  Never corrupt.
-                            with obs.span("sync:d2h", mode=MODE_RAW):
-                                mode, msg = (MODE_RAW,
-                                             _raw_wire(bucket, b.dtype_name))
-                            wire += msg.nbytes
-                        else:
-                            with obs.span("sync:d2h", mode=MODE_FULL):
-                                mode, msg = MODE_FULL, jax.device_get(m)
-                            wire += m.wire_bytes()
-                else:
-                    with obs.span("sync:d2h", mode=MODE_RAW):
-                        msg = _raw_wire(bucket, b.dtype_name)
-                    wire += msg.nbytes
-                with obs.span("obs:sample"):
-                    bucket_counter.inc(mode=mode)
-                    if mode == MODE_DELTA:
-                        _count_exceptions(msg)
-                buckets.append((b.dtype_name, b.members, mode, msg))
+            for k, b in enumerate(plan.buckets):
+                buckets.append(_encode_bucket(k, b, leaves, base_leaves,
+                                              force))
             with obs.span("sync:d2h", mode="leaves"):
                 raw_leaves = tuple((i, np.asarray(leaves[i]))
                                    for i in plan.raw_leaf_ix)
-        wire += sum(arr.nbytes for _, arr in raw_leaves)
+        wire = sum(w for *_, w in buckets) + sum(
+            arr.nbytes for _, arr in raw_leaves)
         raw_total = sum(l.size * jnp.dtype(l.dtype).itemsize
                         for l in leaves if hasattr(l, "dtype"))
         update = SyncUpdate(
             version=version, epoch=self.store.epoch,
-            base_version=base_version if used_delta else None,
+            base_version=(base_version if any(
+                b[2] == MODE_DELTA for b in buckets) else None),
             treedef=jax.tree_util.tree_structure(params),
-            n_leaves=len(leaves), buckets=tuple(buckets),
+            n_leaves=len(leaves), buckets=tuple(b[:4] for b in buckets),
             raw_leaves=raw_leaves, wire_bytes=int(wire),
             raw_bytes=int(raw_total))
         with obs.span("sync:checksum"):

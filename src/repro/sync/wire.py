@@ -1,10 +1,11 @@
 """Planless in-mesh weight broadcast (the kind-"wsync" reference path).
 
 One trainer rank ships its weight pytree to inference replicas across a
-mesh axis permutation.  Codec-supported leaves fuse into one flat bucket
-per dtype (the psum grouping rule — paper Property 1: large blocks keep
-the codec efficient); each bucket is gated/width'd like a ``p2p_send`` at
-tensor_class "weight", and — when both ends hold a shared ``base``
+mesh axis permutation.  Codec-supported leaves fuse into flat buckets of
+one dtype, cut at leaf boundaries at ``sched.compile.WSYNC_BUCKET_BYTES``
+(``sched.compile.wsync_buckets``, shared with the plan — paper Property
+1: large blocks keep the codec efficient); each bucket is gated/width'd
+like a ``p2p_send`` at tensor_class "weight", and — when both ends hold a shared ``base``
 version — ships a bitwise XOR delta instead of the full tensor
 (``core/split_send.delta_send``), which is dramatically more compressible
 for consecutive optimizer steps while staying exactly lossless.
@@ -44,7 +45,7 @@ def sync_weights(tree, axis_name, perm, *, policy: CompressionPolicy,
     (tree_at_dest, flag)."""
     from repro.core import codec
     from repro.core.compressed_collectives import raw_ppermute
-    from repro.sched.compile import _group_leaves
+    from repro.sched.compile import wsync_buckets
 
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     base_leaves = None
@@ -52,11 +53,10 @@ def sync_weights(tree, axis_name, perm, *, policy: CompressionPolicy,
         base_leaves, base_def = jax.tree_util.tree_flatten(base)
         if base_def != treedef:
             raise ValueError("base tree structure != weight tree")
-    groups, raw_ix = _group_leaves(leaves)
+    groups, raw_ix = wsync_buckets(leaves)
     out = list(leaves)
     flag = jnp.int32(0)
-    for name in sorted(groups):
-        members = tuple(groups[name])
+    for name, members in groups:
         L = sum(m[2] for m in members)
         bucket = codec.concat_members(leaves, members)
         bucket_base = (codec.concat_members(base_leaves, members)

@@ -77,3 +77,43 @@ def test_profile_defaults_cover_all_dtypes():
                  "float8_e5m2"]:
         prof = CompressionProfile.default(name)
         assert 1 <= prof.width_for("gradient") <= 8
+
+
+def _delta_widths_whole_tree(x, base, block=512, target=1e-3, exc=0.02):
+    """``choose_delta_widths`` as one eager pass over the whole tree: the
+    block-escape curve of the delta's exponent plane and the lo plane's
+    per-element escape rate at every width."""
+    from repro.core import calibrate
+
+    lay = codec.layout_of(x.dtype)
+    exp, lo = codec.split_bits(codec.xor_bits(x.reshape(-1),
+                                              base.reshape(-1)), lay)
+    rngs = np.asarray(calibrate._exp_range_stats(exp, block))
+    w_exp = next((w for w in range(1, lay.exp_bits + 1)
+                  if float(np.mean(rngs >= (1 << w))) <= target),
+                 lay.exp_bits)
+    lo = np.asarray(lo.astype(jnp.uint32))
+    w_lo = next((w for w in range(1, lay.lo_bits + 1)
+                 if float(np.mean(lo >= (1 << w))) <= exc / 2), lay.lo_bits)
+    return w_exp, w_lo
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "float16",
+                                   "float8_e4m3fn"])
+@pytest.mark.parametrize("n", [100, 512, 70_001])
+def test_sliced_delta_widths_match_the_whole_tree(monkeypatch, dtype, n):
+    """``choose_delta_widths`` sums its escape counts over slices: the
+    widths are the whole-tree pass's, for warm, mid and cold deltas, a
+    ragged tail and slices of one block up to more than the tree."""
+    from repro.core import calibrate
+
+    rng = np.random.default_rng(n)
+    b = rng.standard_normal(n).astype(np.float32) * 0.02
+    for step in (1e-6, 1e-3, 1.0):
+        x = b - step * rng.standard_normal(n).astype(np.float32) * (
+            rng.random(n) < 0.5)
+        x, base = jnp.asarray(x, dtype), jnp.asarray(b, dtype)
+        want = _delta_widths_whole_tree(x, base)
+        for sl in (512, 4096, 1 << 24):
+            monkeypatch.setattr(calibrate, "_CAL_SLICE", sl)
+            assert calibrate.choose_delta_widths(x, base) == want, (step, sl)

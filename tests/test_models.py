@@ -171,3 +171,89 @@ def test_rope_whole_head_in_halves_is_unchanged(dtype, batched):
     u = jnp.uint32 if dtype == "float32" else jnp.uint16
     assert (jax.lax.bitcast_convert_type(got, u)
             == jax.lax.bitcast_convert_type(want, u)).all()
+
+
+def _moe_before(params, x, cfg, *, capacity_factor=1.25,
+                dropless_below=512):
+    """``layers.moe`` as it was before ``MoECfg``'s expert share and
+    ``norm_topk``: every expert held, the top-k gates renormalised."""
+    from repro.models import layers as L
+
+    B, S, D = x.shape
+    m = cfg.moe
+    T, E, K = B * S, m.n_experts, m.top_k
+    xt = x.reshape(T, D)
+    logits = (xt @ params["router"]).astype(jnp.float32)
+    gates, eids = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    C = T if T <= dropless_below else max(
+        1, int(T * K / E * capacity_factor))
+    flat_e = eids.reshape(-1)
+    order = jnp.argsort(flat_e)
+    sorted_e = flat_e[order]
+    grp_start = jnp.searchsorted(sorted_e, jnp.arange(E))
+    within = jnp.arange(T * K) - grp_start[sorted_e]
+    slot = jnp.full((E, C), T * K, jnp.int32).at[
+        sorted_e, jnp.clip(within, 0, C - 1)].set(
+        jnp.where(within < C, order, T * K).astype(jnp.int32), mode="drop")
+    tok_of_slot = jnp.where(slot < T * K, slot // K, T)
+    tok_of_slot = L._expert_sharding_hint(tok_of_slot, E)
+    xg = L._expert_sharding_hint(
+        jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[tok_of_slot], E)
+    h = jnp.einsum("ecd,edf->ecf", jax.nn.silu(jnp.einsum(
+        "ecd,edf->ecf", xg, params["we1"])) * jnp.einsum(
+        "ecd,edf->ecf", xg, params["we3"]), params["we2"])
+    h = L._expert_sharding_hint(h, E)
+    gate_of_slot = jnp.where(slot < T * K, jnp.concatenate(
+        [gates.reshape(-1), jnp.zeros((1,), gates.dtype)])[
+        jnp.minimum(slot, T * K)], 0.0)
+    out = jnp.zeros((T + 1, D), jnp.float32).at[tok_of_slot.reshape(-1)].add(
+        (h * gate_of_slot[..., None]).reshape(-1, D).astype(jnp.float32),
+        mode="drop")
+    y = out[:T].astype(x.dtype)
+    if m.n_shared:
+        y = y + L.swiglu(params["shared"], xt)
+    return y.reshape(B, S, D)
+
+
+def _mla_rope_before(positions, cfg):
+    """MLA's rope table and softmax scale before ``MLACfg``'s YaRN: plain
+    frequencies, ``1/sqrt(hd + rope_dim)``."""
+    from repro.models import layers as L
+
+    cos, sin = L.rope_table(positions, cfg.mla.rope_dim, cfg.rope_theta)
+    return cos, sin, None
+
+
+@pytest.mark.parametrize("name", ["deepseek_v3_671b", "jamba_v0_1_52b"])
+def test_default_moe_and_mla_fields_keep_the_model(setup, monkeypatch, name):
+    """The MoE and MLA configurations other than DeepSeek-V2-Lite leave the
+    expert share, ``norm_topk``, the latent norm and YaRN at their
+    defaults: their trees hold what they held before those fields, and
+    their forward, prefill and decode are bit-identical to the layers as
+    they were."""
+    from repro.models import layers as L
+
+    cfg, params = setup[name]
+    assert (cfg.moe.held, cfg.moe.norm_topk) == (cfg.moe.n_experts, True)
+    assert not cfg.mla.kv_norm and cfg.mla.yarn_factor == 1.0
+    assert not any("kv_norm" in str(path) for path, _ in
+                   jax.tree_util.tree_leaves_with_path(params))
+    batch = registry.make_batch(cfg, 2, 16)
+
+    def outputs():
+        h = transformer.forward(params, batch, cfg, remat=False)
+        cache = transformer.init_cache(cfg, 2, 24)
+        lg_p, cache = transformer.prefill(params, batch, cfg, cache)
+        lg_d, _ = transformer.decode_step(params, batch["tokens"][:, :1],
+                                          cache, cfg)
+        return [transformer.logits_from_hidden(params, h, cfg), lg_p, lg_d]
+
+    got = outputs()
+    monkeypatch.setattr(L, "moe", _moe_before)
+    monkeypatch.setattr(L, "_mla_rope", _mla_rope_before)
+    want = outputs()
+    for a, b in zip(got, want):
+        u = jnp.uint32 if a.dtype == jnp.float32 else jnp.uint16
+        assert (jax.lax.bitcast_convert_type(a, u)
+                == jax.lax.bitcast_convert_type(b, u)).all(), name
